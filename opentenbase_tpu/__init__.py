@@ -32,10 +32,23 @@ Top-level layout (mirrors SURVEY.md section 2's component inventory):
 __version__ = "0.1.0"
 
 
+def host_side_role() -> None:
+    """One process per chip. The process that owns the device mesh is
+    the coordinator (``otb_server``, or whatever embeds ``Cluster``); a
+    chip belongs to one process at a time. DN servers, hot standbys and
+    peer coordinators are host-side roles (they run the host executor),
+    so their entry points call this before JAX initialises: it selects
+    the CPU backend unless ``JAX_PLATFORMS`` in their environment
+    already says otherwise."""
+    import os
+
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+
 def __getattr__(name):
     # Lazy: importing the package must not pull in jax/the server stack.
-    if name in ("Coordinator", "connect"):
-        from opentenbase_tpu.server import coordinator
+    if name == "connect":
+        from opentenbase_tpu.net.client import connect_tcp
 
-        return getattr(coordinator, name)
+        return connect_tcp
     raise AttributeError(name)

@@ -6,7 +6,7 @@ warnings promoted to errors and a family of src/tools passes
 catalogs). This reproduction kept paying for the absence of that
 layer: an unread GUC shipped for four PRs (``log_min_messages``), a
 removed jax API silently demoted every Pallas kernel to XLA for two
-(``jax.enable_x64``), 31 socket ``close()``s without ``shutdown()``
+(the ``enable_x64`` context manager), 31 socket ``close()``s without ``shutdown()``
 cost ~155 s of every run, an int32 cumsum wrapped past 2^31 pairs.
 Each of those is mechanically detectable — so this package detects
 them.

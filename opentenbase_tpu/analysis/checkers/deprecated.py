@@ -1,12 +1,14 @@
-"""Deprecated / removed-API denylist — the ``jax.enable_x64`` class.
+"""Deprecated / removed-API denylist — the ``enable_x64`` class.
 
-PR 3's post-mortem: ``jax.enable_x64`` was removed from the jax
-namespace in 0.4.x, the AttributeError was swallowed by a broad guard,
-and every Pallas kernel silently demoted to XLA for two whole PRs —
-the bench ran 7x slower and nothing failed. The denylist names the
-allowed replacement in the message so the fix is in the finding.
+PR 3's post-mortem: the x64 context manager moved between the jax and
+jax.experimental namespaces across releases, the AttributeError was
+swallowed by a broad guard, and every Pallas kernel silently demoted
+to XLA for two whole PRs — the bench ran 7x slower and nothing failed.
+The denylist tracks the INSTALLED jax (0.9: ``jax.enable_x64`` is the
+one name) and names the allowed replacement in the message so the fix
+is in the finding.
 
-Matches dotted attribute chains (``jax.enable_x64``) and the
+Matches dotted attribute chains (``jax.experimental.enable_x64``) and the
 string-knob form (``jax.config.update("enable_x64", ...)`` — the knob
 is ``jax_enable_x64``; the unprefixed name raises nothing and sets
 nothing on old jax versions).
@@ -21,9 +23,8 @@ from opentenbase_tpu.analysis.core import Finding, Project, dotted_name
 
 # dotted path -> replacement named in the message
 DENYLIST: dict[str, str] = {
-    "jax.enable_x64": (
-        "removed from the jax namespace in 0.4.x; use "
-        "jax.experimental.enable_x64 (context manager) or "
+    "jax.experimental.enable_x64": (
+        "removed in jax 0.9; use jax.enable_x64 (context manager) or "
         "jax.config.update('jax_enable_x64', ...)"
     ),
     "jax.experimental.host_callback": (

@@ -83,10 +83,19 @@ def _read_pid(data_dir: str):
         return None
 
 
-def _spawn(cmd: list[str], data_dir: str, ready_marker: str) -> int:
+def _host_role_env() -> dict:
+    """Environment for standbys and peer coordinators: host-side roles
+    on the CPU backend whatever the caller's environment says — the
+    coordinator process owns the chip (one process per chip)."""
+    return dict(os.environ, JAX_PLATFORMS="cpu")
+
+
+def _spawn(
+    cmd: list[str], data_dir: str, ready_marker: str, env=None
+) -> int:
     os.makedirs(data_dir, exist_ok=True)
     log = open(os.path.join(data_dir, "server.log"), "ab")
-    proc = subprocess.Popen(cmd, stdout=log, stderr=log)
+    proc = subprocess.Popen(cmd, stdout=log, stderr=log, env=env)
     with open(_pid_path(data_dir), "w") as f:
         f.write(str(proc.pid))
     # wait for the ready banner in the log (pg_ctl -w behavior)
@@ -165,7 +174,9 @@ def cmd_start(cfg: dict) -> None:
             "--serve-port", str(sb.get("serve_port", 0)),
             "--control-port", str(sb.get("control_port", 0)),
         ]
-        pid = _spawn(cmd, sb["data_dir"], "standby ready")
+        pid = _spawn(
+            cmd, sb["data_dir"], "standby ready", env=_host_role_env()
+        )
         print(f"{sb['name']}: started (pid {pid}, sql port {sb.get('serve_port')})")
 
 
@@ -269,7 +280,9 @@ def cmd_add_coordinator(cfg: dict, name: str) -> None:
             "--serve-port", str(cn["serve_port"]),
             "--control-port", str(cn["control_port"]),
         ]
-        pid = _spawn(cmd, cn["data_dir"], "peer ready")
+        pid = _spawn(
+            cmd, cn["data_dir"], "peer ready", env=_host_role_env()
+        )
         print(f"{name}: started (pid {pid}, sql port {cn['serve_port']})")
     with _sql(cfg) as s:
         s.query(

@@ -39,6 +39,9 @@ def main(argv=None) -> int:
     ap.add_argument("--control-port", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from opentenbase_tpu import host_side_role
+
+    host_side_role()
     from opentenbase_tpu.coord.peer import PeerCoordinator
     from opentenbase_tpu.net.server import ClusterServer
 

@@ -34,6 +34,9 @@ def main(argv=None) -> int:
     ap.add_argument("--control-port", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from opentenbase_tpu import host_side_role
+
+    host_side_role()
     from opentenbase_tpu.net.server import ClusterServer
     from opentenbase_tpu.storage.replication import StandbyCluster
 

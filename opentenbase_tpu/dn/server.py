@@ -30,6 +30,7 @@ import time
 from typing import Optional
 
 from opentenbase_tpu import fault as _fault
+from opentenbase_tpu import host_side_role
 from opentenbase_tpu.fault import FAULT, FaultDropConnection
 from opentenbase_tpu.net.protocol import (
     recv_frame,
@@ -1349,6 +1350,7 @@ def main(argv=None) -> None:
         help="OpenMetrics exporter port (0 = no listener)",
     )
     args = ap.parse_args(argv)
+    host_side_role()
     srv = DNServer(
         args.data_dir, args.wal_host, args.wal_port,
         args.num_datanodes, args.shard_groups, port=args.listen_port,

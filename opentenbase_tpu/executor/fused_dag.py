@@ -31,6 +31,7 @@ flips the build side or gives up so the host path answers instead.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, Optional
 
 import numpy as np
@@ -38,12 +39,8 @@ import numpy as np
 import opentenbase_tpu.ops  # noqa: F401  (x64)
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 from opentenbase_tpu import types as t
 from opentenbase_tpu.ops import agg as agg_ops
@@ -637,45 +634,8 @@ def _radix_gate(
     return best * 2 <= pest
 
 
-# (P, B) -> did the MXU bucket-probe kernel lower AND run on this
-# process's devices? Probed once per shape with a tiny eager self-test;
-# a failure demotes to the XLA probe for THAT shape only — loudly, via
-# the pallas-demotion telemetry — instead of poisoning the whole DAG
-# program and demoting the entire query to the host executor.
-_PALLAS_JOIN_OK: dict = {}
-
-
-def _pallas_join_ok(P: int, B: int, note=None) -> bool:
-    ok = _PALLAS_JOIN_OK.get((P, B))
-    if ok is None:
-        try:
-            from opentenbase_tpu.ops import pallas_join as pj
-
-            m, _bi = pj.probe_radix_pallas(
-                jnp.zeros(P * B + 1, jnp.int64),
-                jnp.zeros(P * B + 1, jnp.bool_),
-                jnp.zeros(P * B + 1, jnp.int32),
-                jnp.zeros(8, jnp.int64),
-                jnp.zeros(8, jnp.bool_),
-                P, B,
-            )
-            jax.device_get(m)  # force real execution, not a lazy handle
-            ok = True
-        except Exception:
-            ok = False
-            if note is not None:
-                try:
-                    note(("pallas_join", P, B))
-                except Exception:
-                    pass
-        _PALLAS_JOIN_OK[(P, B)] = ok
-        while len(_PALLAS_JOIN_OK) > 64:
-            _PALLAS_JOIN_OK.pop(next(iter(_PALLAS_JOIN_OK)))
-    return ok
-
-
 def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
-                  pallas_probe: bool = False, pallas_note=None):
+                  pallas_probe: bool = False, note_pallas=None):
     """Equi-join primitive over the bucket-padded radix hash table
     (ops/join.py): ONE small build-side sort + a log2(bucket)-deep
     bucket search per probe row, instead of sort-merge's full
@@ -712,6 +672,8 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
     bidx = jnp.zeros(npr, jnp.int32)
     flag = jnp.asarray(False)
     chunk = -(-nb // plan.passes)
+    from opentenbase_tpu.ops import pallas_join as pj
+
     for p in range(plan.passes):
         s = p * chunk
         e = min(s + chunk, nb)
@@ -720,18 +682,17 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
         tkeys, tvalid, tbidx, dup, ovf = join_ops.build_radix_table(
             bd[s:e], breal[s:e], P, B
         )
-        probed = False
-        if pallas_probe:
-            from opentenbase_tpu.ops import pallas_join as pj
-
-            if pj.eligible(e - s, P, B) and _pallas_join_ok(
-                P, B, note=pallas_note
-            ):
-                m, bi = pj.probe_radix_pallas(
-                    tkeys, tvalid, tbidx, pd, preal, P, B
-                )
-                probed = True
-        if not probed:
+        if pallas_probe and pj.eligible(e - s, P, B):
+            # compiled by Mosaic with the rest of the program: a
+            # lowering failure fails the program (a counted, logged
+            # fused->host demotion, engine._try_fused_inner), never a
+            # quiet switch to the XLA probe
+            m, bi = pj.probe_radix_pallas(
+                tkeys, tvalid, tbidx, pd, preal, P, B
+            )
+            if note_pallas is not None:
+                note_pallas()
+        else:
             m, bi = join_ops.probe_radix_first(
                 tkeys, tvalid, tbidx, pd, preal, P, B
             )
@@ -1016,16 +977,10 @@ class _Builder:
         self.captured = None
         # join primitive: double-sort merge on TPU (searchsorted is a
         # serial binary search there), sorted binary search elsewhere
-        platform_fn = getattr(fx, "platform", None)
-        if callable(platform_fn):
-            plat = platform_fn()  # FusedExecutor's one detector
-        else:  # test stubs without the method
-            try:
-                plat = str(fx.mesh.devices.flat[0].platform)
-            except Exception:
-                plat = "cpu"
-        self.platform = plat
-        self.lookup = _lookup_sortmerge if plat == "tpu" else _lookup
+        self.platform = fx.platform()  # FusedExecutor's one detector
+        self.lookup = (
+            _lookup_sortmerge if self.platform == "tpu" else _lookup
+        )
 
     def jinfo(self) -> tuple:
         """(folded, radixed) join-index sets for THIS compile — cached
@@ -1318,14 +1273,19 @@ class _Builder:
         lookup = self.lookup
         radix_budget = self.radix_budget
         # the MXU one-hot bucket probe (ops/pallas_join.py) rides only
-        # on real TPU backends; elsewhere interpret mode would measure
-        # the emulator (the enable_pallas_scan convention)
+        # on a TPU mesh; elsewhere interpret mode would measure the
+        # emulator (the enable_pallas_scan convention)
         pallas_probe = (
             use_radix
             and self.platform == "tpu"
             and getattr(self.fx, "enable_pallas_join", True) is not False
         )
-        pallas_note = getattr(self.fx, "_note_pallas_failure", None)
+        # 'pallas' joins the run's join modes (EXPLAIN ANALYZE,
+        # pg_stat_fused last_join_modes) when the probe is traced in
+        note_pallas = (
+            partial(self.runner.note_join_mode, ji, "pallas")
+            if pallas_probe and self.runner is not None else None
+        )
 
         def run(blocks, params, snap):
             if fold:
@@ -1396,7 +1356,7 @@ class _Builder:
                     matched, bidx, dup = _lookup_radix(
                         pk, pmask, bk, bmask, radix_budget, lookup,
                         pallas_probe=pallas_probe,
-                        pallas_note=pallas_note,
+                        note_pallas=note_pallas,
                     )
                 else:
                     matched, bidx, dup = lookup(

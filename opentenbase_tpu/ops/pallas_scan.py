@@ -39,11 +39,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:  # removed from the jax namespace in 0.4.x
-    _enable_x64 = jax.enable_x64  # otb_lint: ignore[deprecated-api] -- probed under except AttributeError; the 0.4.x location is the fallback below
-except AttributeError:
-    from jax.experimental import enable_x64 as _enable_x64
-
 from opentenbase_tpu import types as t
 from opentenbase_tpu.plan import texpr as E
 
@@ -394,7 +389,7 @@ def build_partials(
         # the engine runs in global x64 mode, but Mosaic cannot legalize
         # the i64 grid/index scalars that mode produces — this kernel is
         # pure f32/i32, so trace it with x64 off
-        with _enable_x64(False):
+        with jax.enable_x64(False):
             return pl.pallas_call(
                 kernel,
                 grid=(grid,),

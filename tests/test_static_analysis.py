@@ -2,8 +2,8 @@
 that motivated it, seeded back into a copy of the real tree.
 
 The five seeds mirror the incidents in ISSUE 8 / the analysis package
-docstring: an unread GUC (log_min_messages, PR 5), ``jax.enable_x64``
-(PR 3), close-without-shutdown (PR 3), a socket-I/O function with no
+docstring: an unread GUC (log_min_messages, PR 5), a removed jax API
+(``enable_x64``, PR 3), close-without-shutdown (PR 3), a socket-I/O function with no
 FAULT site (PR 4's thesis), and an int32 cumsum offset (PR 6). Each
 test copies the package, applies one seed, and asserts ``otb_lint
 --check`` against the COMMITTED baseline goes red — which is exactly
@@ -94,7 +94,7 @@ def test_seed_jax_enable_x64_fails(tmp_path, capsys):
     """The silent-Pallas-demotion class: a removed jax API, unguarded."""
     root = _copy_tree(tmp_path)
     _append(root, "opentenbase_tpu/ops/sort.py",
-            "_lint_seed_x64 = jax.enable_x64")
+            "_lint_seed_x64 = jax.experimental.enable_x64")
     assert _check(root) != 0
     assert "deprecated-api" in capsys.readouterr().out
 
@@ -189,7 +189,7 @@ def test_baseline_key_survives_line_drift(tmp_path):
 def test_pragma_with_reason_suppresses(tmp_path):
     root = _copy_tree(tmp_path)
     _append(root, "opentenbase_tpu/ops/sort.py", (
-        "_lint_seed_x64 = jax.enable_x64"
+        "_lint_seed_x64 = jax.experimental.enable_x64"
         "  # otb_lint: ignore[deprecated-api] -- seeded for the test\n"
     ))
     assert _check(root) == 0
@@ -201,7 +201,7 @@ def test_pragma_without_reason_rejected(tmp_path, capsys):
     root = _copy_tree(tmp_path)
     baseline = os.path.join(root, "tools", "lint_baseline.json")
     _append(root, "opentenbase_tpu/ops/sort.py", (
-        "_lint_seed_x64 = jax.enable_x64"
+        "_lint_seed_x64 = jax.experimental.enable_x64"
         "  # otb_lint: ignore[deprecated-api]\n"
     ))
     assert _check(root) != 0
@@ -230,7 +230,7 @@ def test_pragma_previous_line_covers(tmp_path):
     _append(root, "opentenbase_tpu/ops/sort.py", (
         "# otb_lint: ignore[deprecated-api] -- seeded; pragma sits on "
         "the line above\n"
-        "_lint_seed_x64 = jax.enable_x64\n"
+        "_lint_seed_x64 = jax.experimental.enable_x64\n"
     ))
     assert _check(root) == 0
 

@@ -583,14 +583,14 @@ assert hostrows == res["radix"], "host radix parity broke"
 assert any(ln.strip().startswith("Join") and "(radix)" in ln
            for ln in lines), lines
 doc = bench_gate.load_floors()  # raises on schema errors
-green = {"platform": "default"}
+green = {"platform": "tpu"}
 for m, spec in doc["floors"].items():
     green[m] = spec["floor"] * 2
 assert bench_gate.check_record(green, doc) == []
 bad = dict(green); bad["q3_rows_per_sec"] = 1
 assert any("q3_rows_per_sec" in v
            for v in bench_gate.check_record(bad, doc))
-dem = dict(green); dem["tunnel_down"] = True
+dem = dict(green); dem["platform"] = "cpu"
 assert any("demotion" in v for v in bench_gate.check_record(dem, doc))
 print("join smoke OK: radix == sortmerge (fused+host), EXPLAIN shows "
       "mode, floors validate, gate fails violation+demotion")
