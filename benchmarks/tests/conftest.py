@@ -1,0 +1,14 @@
+"""Tests of the benchmark's own yardstick. Run by hand:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q
+
+They are not part of the repository's tier-1 tests."""
+
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+for p in (BENCH, os.path.dirname(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
