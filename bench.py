@@ -307,15 +307,10 @@ def _phase_breakdown(cluster) -> dict:
     instead of reporting only end-to-end ratios. Disable with
     BENCH_PHASES=0."""
     out = {}
-    fx = getattr(cluster, "_fused", None)
-    for k, v in (getattr(fx, "phase_totals", None) or {}).items():
-        out[k] = round(v, 3)
     metrics = getattr(cluster, "metrics", None)
     if metrics is not None:
-        h = metrics.histograms.get("phase.motion")
-        if h is not None and h.count:
-            out["motion_ms"] = round(h.total, 3)
-        for name in ("execute", "plan"):
+        for name in ("compile", "device", "host", "motion", "execute",
+                     "plan"):
             h = metrics.histograms.get(f"phase.{name}")
             if h is not None and h.count:
                 out[f"{name}_ms"] = round(h.total, 3)

@@ -15,6 +15,15 @@ the wire and writes the document to ``--out``.
 
 Exit code 0 on success (even when the ring is empty — an empty trace is
 a valid trace), 1 when the coordinator is unreachable.
+
+    python -m opentenbase_tpu.cli.otb_trace --xplane DIR [--json]
+
+reads a JAX profiler trace instead (the directory given to
+``jax.profiler.start_trace``, or one ``.xplane.pb``) and prints where
+each statement class spent its time: the ``otb:`` spans every timed
+site writes into the profiler's trace, device time by program and by
+``otb/`` scope, idle gaps by cause (obs/profile.py). No coordinator is
+contacted.
 """
 
 from __future__ import annotations
@@ -40,14 +49,37 @@ def fetch_traces(
     return json.loads(rows[0][0])
 
 
+def reduce_xplane(where: str, as_json: bool) -> int:
+    import os
+
+    from opentenbase_tpu.obs import profile
+
+    try:
+        path = where if os.path.isfile(where) else profile.find_xplane(where)
+        report = profile.reduce(profile.load(path))
+    except (OSError, ValueError) as e:
+        print(f"otb_trace: {where}: {e}", file=sys.stderr)
+        return 1
+    print(json.dumps(report) if as_json else profile.render(report))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="otb_trace",
         description="Export recent query traces as Chrome trace JSON",
     )
     ap.add_argument(
-        "--cn", required=True, metavar="HOST:PORT",
-        help="coordinator wire endpoint",
+        "--cn", metavar="HOST:PORT", help="coordinator wire endpoint",
+    )
+    ap.add_argument(
+        "--xplane", metavar="DIR",
+        help="reduce a JAX profiler trace (its directory or .xplane.pb) "
+        "instead of contacting a coordinator",
+    )
+    ap.add_argument(
+        "--json", action="store_true",
+        help="with --xplane: print the reduction as JSON",
     )
     ap.add_argument(
         "--last", type=int, default=20,
@@ -60,6 +92,10 @@ def main(argv=None) -> int:
     ap.add_argument("--user", default=None)
     ap.add_argument("--password", default=None)
     args = ap.parse_args(argv)
+    if args.xplane:
+        return reduce_xplane(args.xplane, args.json)
+    if not args.cn:
+        ap.error("one of --cn or --xplane is required")
 
     host, _, port = args.cn.rpartition(":")
     try:

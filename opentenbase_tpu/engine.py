@@ -45,6 +45,7 @@ from opentenbase_tpu.executor.dist import DistExecutor, concat_batches
 from opentenbase_tpu.executor.local import LocalExecutor
 from opentenbase_tpu.gtm import GTSServer
 from opentenbase_tpu.obs import statements as _stmtobs
+from opentenbase_tpu.obs.trace import span as _span
 from opentenbase_tpu.obs import tracectx as _tctx
 from opentenbase_tpu.lmgr import (
     DeadlockError,
@@ -82,29 +83,16 @@ class Result:
         return self.rows[0][0] if self.rows else None
 
 
-class _PhaseTimer:
-    """Times one query phase for a Session (see Session._phased)."""
+class _PhaseTimer(_span):
+    """Times one query phase for a Session (see Session._phased): the
+    shared span helper, plus the per-statement phase accumulator."""
 
-    __slots__ = ("_session", "_name", "_t0")
-
-    def __init__(self, session, name):
-        self._session = session
-        self._name = name
-
-    def __enter__(self):
-        import time as _time
-
-        self._t0 = _time.perf_counter()
-        return self
+    def __init__(self, session, name, **args):
+        super().__init__(session, name, cat="phase", **args)
 
     def __exit__(self, *exc):
-        import time as _time
-
-        t1 = _time.perf_counter()
-        s = self._session
-        s._note_phase(self._name, (t1 - self._t0) * 1000.0)
-        if s._trace is not None:
-            s._trace.record(self._name, "phase", self._t0, t1)
+        super().__exit__(*exc)
+        self._session._note_phase(self.name, self.ms)
         return False
 
 
@@ -1843,12 +1831,23 @@ class Session:
             # context for the statement so every wire client on this
             # thread — DN channels, the GTM client — propagates it
             prev_ctx = _tctx.bind(trace.ctx)
+        # the statement's ``query`` span (top-level strings only). A
+        # trace this call owns gets its root from Tracer.finish, so the
+        # span stays out of the QueryTrace but lends its id to its
+        # children; an adopted trace (the wire server's) records it
+        qspan = None
+        if self._phase_acc is None:
+            qspan = _span(
+                self, "query", cat="query",
+                span_id=None if trace is None else trace.ctx.span_id,
+                record=trace is None,
+            )
+            qspan.__enter__()
         try:
             results = []
-            t_p0 = _time.perf_counter()
-            stmts = parse(sql)
-            t_p1 = _time.perf_counter()
-            parse_ms = (t_p1 - t_p0) * 1000
+            with _span(self, "parse", cat="phase") as psp:
+                stmts = parse(sql)
+            parse_ms = psp.ms
             self._stmt_count = len(stmts)
             # peer CN (coord/session.py): statements that could write
             # ship to the primary verbatim; the primary does the
@@ -1870,8 +1869,6 @@ class Session:
                 # (one fold at outer statement end), keeping per-phase
                 # statement counts comparable
                 self._note_phase("parse", parse_ms)
-            if self._trace is not None:
-                self._trace.record("parse", "phase", t_p0, t_p1)
             parse_share = parse_ms / len(stmts) if stmts else 0.0
             for i, s in enumerate(stmts):
                 t0 = _time.perf_counter()
@@ -1937,11 +1934,18 @@ class Session:
                             s, self.last_query, pos, ms, r.rowcount,
                             ledger,
                         )
+                        if qspan is not None:
+                            # the statement's class key rides the
+                            # ``query`` TraceMe always: the profile
+                            # reduction (obs/profile.py) groups by it
+                            qspan.set(queryid=str(qid))
                     self._maybe_log_slow(s, ms, ledger, qid,
                                          len(stmts), i)
                 results.append(r)
             return results[-1] if results else Result("EMPTY")
         finally:
+            if qspan is not None:
+                qspan.__exit__(None, None, None)
             self._trace = prev_trace
             if trace is not None:
                 _tctx.bind(prev_ctx)
@@ -1969,7 +1973,7 @@ class Session:
         """Context manager timing one query phase (plan / queue /
         execute / ...): accumulates into the per-statement phase dict
         (folded into cluster metrics + pg_stat_statements at statement
-        end) and emits a trace span when a trace is active."""
+        end) and is a span of the shared helper (obs/trace.span)."""
         return _PhaseTimer(self, name)
 
     def _note_phase(self, name: str, ms: float) -> None:
@@ -4914,10 +4918,11 @@ class Session:
             # — take the replan path, whose gate prunes per shard
             and not self.cluster.shard_barrier.active()
         ):
-            with self._phased("plan"):
+            with self._phased("plan") as sp:
                 entry = sv.plan_cache.lookup(
                     key, self.cluster.catalog_epoch
                 )
+                sp.set(plan_cache="miss" if entry is None else "hit")
             if entry is not None:
                 self._last_plan_cache = "hit"
                 self._last_plan_tables = set(entry.tables)
@@ -5200,11 +5205,8 @@ class Session:
         travel by value (the FusedExecutor copy is shared cluster
         state a concurrent session may overwrite) — or None when the
         plan is outside the fused subset."""
-        import time as _time
-
         from opentenbase_tpu.obs.trace import compile_window
 
-        t0 = _time.perf_counter()
         self._fused_host_ms = 0.0
         # watchdog bookkeeping: _try_fused_inner records which path
         # produced the output (the DAG runner stamps its own runs; the
@@ -5220,52 +5222,39 @@ class Session:
         self._fused_tail1 = None
         self._fused_h2d0 = None
         self._fused_h2d1 = None
-        with compile_window() as cw:
-            out = self._try_fused_inner(dplan, snapshot)
+        # what the runners report of THIS statement, captured under the
+        # fused gate (a concurrent session overwrites the runner's copy)
+        self._fused_join_modes = ()
+        led = _stmtobs.current()
+        launches0 = led.device_launches if led is not None else 0
+        fx = None
+        with _span(self, "fused", cat="fused") as fsp:
+            with compile_window() as cw:
+                out = self._try_fused_inner(dplan, snapshot)
+            if out is not None:
+                fx = self.cluster._fused
+            if fsp.listening:
+                fsp.set(
+                    path=(
+                        "none" if out is None
+                        else "dag" if self._fused_via_dag else "scan"
+                    ),
+                    platform=fx.platform() if fx is not None else None,
+                    attempts=(
+                        led.device_launches - launches0
+                        if led is not None else None
+                    ),
+                    compile_ms=round(cw.ms, 3) if cw.ms else None,
+                )
         if out is None:
             return None
-        t1 = _time.perf_counter()
-        total_ms = (t1 - t0) * 1000.0
-        host_ms = self._fused_host_ms
-        compile_ms = cw.ms
-        device_ms = max(total_ms - compile_ms - host_ms, 0.0)
-        phases = {
-            "compile_ms": compile_ms,
-            "device_ms": device_ms,
-            "host_ms": host_ms,
-        }
-        fx = self.cluster._fused
         run_platform = None
         if fx is not None:
             # shared executor state: concurrent sessions finish fused
-            # queries in parallel, so totals accumulate under the
-            # fused lock (same lock the device caches use); the
-            # per-fragment device breakdown is snapshotted under it
-            # too so this query's EXPLAIN never shows another's
+            # queries in parallel, so the count moves under the fused
+            # lock (same lock the device caches use)
             with self.cluster._fused_lock:
                 fx.fused_statements += 1
-                fx.last_phases = dict(phases)
-                for k, v in phases.items():
-                    fx.phase_totals[k] = fx.phase_totals.get(k, 0.0) + v
-                dag = fx._dag
-                if dag is not None and dag.last_frag_ms:
-                    phases["frag_ms"] = dict(dag.last_frag_ms)
-                if dag is not None and dag.last_join_modes:
-                    phases["join_modes"] = ",".join(
-                        dag.last_join_modes
-                    )
-                # added AFTER the phase_totals accumulation above:
-                # attribution metadata, not a timing phase
-                tail0, tail1 = self._fused_tail0, self._fused_tail1
-                if (tail0 is not None and tail1 is not None
-                        and tail1 > tail0):
-                    phases["delta_tail_rows"] = tail1 - tail0
-                # h2d transfer attribution, same before/after-counter
-                # scheme: only THIS statement's uploads land here
-                h2d0, h2d1 = self._fused_h2d0, self._fused_h2d1
-                if (h2d0 is not None and h2d1 is not None
-                        and h2d1 > h2d0):
-                    phases["h2d_bytes"] = h2d1 - h2d0
                 # device-platform watchdog: the DAG runner stamped its
                 # own run; the single-fragment path stamps here — one
                 # note per successful fused statement either way
@@ -5274,12 +5263,31 @@ class Session:
                     else fx.note_run_platform()
                 )
             self.cluster._last_device_platform = run_platform
+        total_ms = fsp.ms
+        host_ms = self._fused_host_ms
+        compile_ms = cw.ms
+        device_ms = max(total_ms - compile_ms - host_ms, 0.0)
+        phases = {
+            "compile_ms": compile_ms,
+            "device_ms": device_ms,
+            "host_ms": host_ms,
+        }
+        if self._fused_join_modes:
+            phases["join_modes"] = ",".join(self._fused_join_modes)
+        # attribution metadata, not timing phases
+        tail0, tail1 = self._fused_tail0, self._fused_tail1
+        if tail0 is not None and tail1 is not None and tail1 > tail0:
+            phases["delta_tail_rows"] = tail1 - tail0
+        # h2d transfer attribution, same before/after-counter scheme:
+        # only THIS statement's uploads land here
+        h2d0, h2d1 = self._fused_h2d0, self._fused_h2d1
+        if h2d0 is not None and h2d1 is not None and h2d1 > h2d0:
+            phases["h2d_bytes"] = h2d1 - h2d0
         # phase metrics flow through the per-statement accumulator only
         # (folded into the histograms once, at statement end)
         self._note_phase("compile", compile_ms)
         self._note_phase("device", device_ms)
         self._note_phase("host", host_ms)
-        led = _stmtobs.current()
         if led is not None:
             # ledger device/compile come from here, NOT the phase fold
             # — finalize() derives host_ms as the execute remainder so
@@ -5291,17 +5299,6 @@ class Session:
             led.d2h_bytes += _stmtobs.batch_nbytes(out)
             if run_platform:
                 led.run_platform = str(run_platform)
-        if self._trace is not None:
-            # the platform this run ACTUALLY executed on rides the
-            # trace (the r04/r05 forensics that used to need a bench
-            # JSON post-mortem)
-            self._trace.record(
-                "fused device execution", "fused", t0, t1,
-                compile_ms=round(compile_ms, 3),
-                device_ms=round(device_ms, 3),
-                host_ms=round(host_ms, 3),
-                platform=run_platform,
-            )
         return out, phases
 
     def _try_fused_inner(self, dplan, snapshot) -> Optional[ColumnBatch]:
@@ -5354,7 +5351,11 @@ class Session:
             dplan.root.child, L.Sort
         )
         try:
-            with fused_gate:
+            # the gate is held for the whole device attempt: time the
+            # acquire alone, then hold it through a plain try/finally
+            with _span(self, "fused.gate_wait", "gate_ms", cat="fused"):
+                fused_gate.acquire()
+            try:
                 # before-counter for the EXPLAIN delta-tail attribution
                 # — under the gate, so only THIS statement's refresh
                 # lands in the delta
@@ -5392,6 +5393,11 @@ class Session:
                     self._fused_via_dag = True
                 if out is None:
                     return None
+                if self._fused_via_dag:
+                    # the join formulations of the programs that RAN
+                    # (kept with their cache entries), read under the
+                    # gate: a concurrent session overwrites the runner's
+                    self._fused_join_modes = fx._dag.last_join_modes
                 # after-counters captured under the SAME gate hold: a
                 # concurrent session's upload between here and the
                 # accounting block in _try_fused must not bill us
@@ -5401,6 +5407,8 @@ class Session:
                 self._fused_h2d1 = int(
                     fx.cache.stats.get("h2d_bytes", 0)
                 )
+            finally:
+                fused_gate.release()
         except FusedUnsupported:
             return None
         except Exception as e:
@@ -5436,13 +5444,15 @@ class Session:
         # the merge input is tiny (S * group-cap rows at most) and runs
         # where every host-side op runs: the CPU backend (the one
         # placement decision, ops/__init__.py)
-        import time as _time
-
-        t_h0 = _time.perf_counter()
+        msp = _span(
+            self, "fused.merge", "merge_ms", cat="fused",
+            rows_in=out.nrows,
+        )
         try:
-            return ex.run_plan(dplan.root)
+            with msp:
+                return ex.run_plan(dplan.root)
         finally:
-            self._fused_host_ms = (_time.perf_counter() - t_h0) * 1000.0
+            self._fused_host_ms = msp.ms
 
     def _dicts_view(self):
         session = self
@@ -7876,6 +7886,7 @@ class Session:
                 )
                 self._trace = own_trace
                 own_prev_ctx = _tctx.bind(own_trace.ctx)
+            run_trace = self._trace
             # child ledger around the instrumented run: the Resources
             # footer is the same bill a real execution of this statement
             # accrues in pg_stat_statements, itemized for one run; it is
@@ -7920,8 +7931,21 @@ class Session:
                         f"{ph['delta_tail_rows']} delta-resident rows "
                         "tail-uploaded"
                     )
-                frag_ms = ph.get("frag_ms")
-                if stmt.verbose and frag_ms:
+                if stmt.verbose:
+                    # per-fragment device time from the statement's own
+                    # spans: each fragment's launches and the waits on
+                    # them (EXPLAIN ANALYZE always traces itself)
+                    frag_ms: dict = {}
+                    with run_trace._mu:
+                        spans = list(run_trace.spans)
+                    for sp in spans:
+                        k = (sp.args or {}).get("frag")
+                        if k is not None and sp.name in (
+                            "fused.launch", "fused.wait"
+                        ):
+                            frag_ms[k] = (
+                                frag_ms.get(k, 0.0) + sp.dur_us / 1000.0
+                            )
                     for k in sorted(frag_ms, key=str):
                         lines.append(
                             f"  device fragment {k}: "
@@ -8395,6 +8419,10 @@ def _sv_stat_statements(c: Cluster):
             int(ent.plan_cache_hits), int(ent.result_cache_hits),
             ent.platform,
             reset,
+            *(round(float(getattr(ent, f)), 3)
+              for f in _stmtobs.DEVICE_SPLIT_FIELDS),
+            round(float(ent.merge_ms), 3),
+            *(int(getattr(ent, f)) for f in _stmtobs.FUSED_COUNT_FIELDS),
         ))
     return rows
 
@@ -8576,6 +8604,11 @@ def _sv_fused(c: Cluster):
             rows.append(
                 ("last_join_modes", ",".join(dag.last_join_modes))
             )
+        if dag.last_programs:
+            # the device programs the last DAG run launched, in order
+            rows.append(
+                ("last_programs", ",".join(dag.last_programs))
+            )
         for r in dag.unsupported:
             rows.append(("unsupported", r))
     for d in fx.dag_demotions:
@@ -8594,17 +8627,6 @@ def _sv_fused(c: Cluster):
     if zs and zs.get("total_blocks"):
         rows.append(("zone_pruned_blocks", str(zs["pruned_blocks"])))
         rows.append(("zone_total_blocks", str(zs["total_blocks"])))
-    # phase attribution of the last fused query + lifetime totals
-    # (obs/: compile vs device vs host — the split VERDICT r5 asked for)
-    for k in sorted(getattr(fx, "last_phases", None) or {}):
-        rows.append((f"last_{k}", f"{fx.last_phases[k]:.3f}"))
-    for k in sorted(getattr(fx, "phase_totals", None) or {}):
-        rows.append((f"total_{k}", f"{fx.phase_totals[k]:.3f}"))
-    if dag is not None and getattr(dag, "last_frag_ms", None):
-        for k in sorted(dag.last_frag_ms, key=str):
-            rows.append(
-                (f"last_frag_ms[{k}]", f"{dag.last_frag_ms[k]:.3f}")
-            )
     return rows
 
 
@@ -9225,6 +9247,11 @@ _SYSTEM_VIEWS: dict[str, tuple] = {
             "result_cache_hits": t.INT8,
             "platform": t.TEXT,
             "stats_reset": t.FLOAT8,
+            # the fused path's split of device_ms, then its counts
+            # (appended: positions of the older columns stay)
+            **{f: t.FLOAT8 for f in _stmtobs.DEVICE_SPLIT_FIELDS},
+            "merge_ms": t.FLOAT8,
+            **{f: t.INT8 for f in _stmtobs.FUSED_COUNT_FIELDS},
         },
         _sv_stat_statements,
     ),

@@ -39,6 +39,8 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from opentenbase_tpu.fault import FAULT
+from opentenbase_tpu.obs import statements as _stmtobs
+from opentenbase_tpu.obs.trace import compile_window, scope, span as _span
 from opentenbase_tpu.ops import agg as agg_ops
 from opentenbase_tpu.ops import filter as filt_ops
 from opentenbase_tpu.ops.expr import ExprCompiler, resolve_param
@@ -53,6 +55,124 @@ DEFAULT_GROUP_CAP = 1024
 import logging
 
 _log = logging.getLogger("opentenbase_tpu.fused")
+
+
+# ---------------------------------------------------------------------------
+# The statement path's device calls: named programs, one launch helper,
+# one fetch helper (spans + ledger counters, obs/trace.span)
+# ---------------------------------------------------------------------------
+
+
+def named_program(fn, name: str):
+    """``jax.jit(fn)`` under a static name: the XLA module is
+    ``jit_<name>``, so a device trace says which program ran. Names
+    start with ``program`` (the benchmark's rooflines match
+    ``^jit_program``) and carry no literal or hash."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def fetch(tree, what: str, **args):
+    """THE host wait on the device for the statement path: every
+    device->host read goes through here, so no sync exists without its
+    ``fused.wait`` span and its ``device_syncs`` count. One batched
+    ``jax.device_get`` of the whole tree, never per-array reads."""
+    with _span(
+        None, "fused.wait", "device_wait_ms", "device_syncs",
+        cat="fused", what=what, **args,
+    ) as sp:
+        out = jax.device_get(tree)
+        if sp.listening:
+            sp.set(d2h_bytes=sum(
+                int(getattr(x, "nbytes", 0)) for x in jax.tree.leaves(out)
+            ))
+    return out
+
+
+class _CacheSpan(_span):
+    """The ``fused.cache`` span of one DeviceCache lookup (``cache_ms``):
+    hit or refresh, and what the refresh shipped, read from the cache's
+    counters across it."""
+
+    def __init__(self, cache, table: str):
+        super().__init__(
+            None, "fused.cache", "cache_ms", cat="fused", table=table
+        )
+        self._cache = cache
+
+    def _marks(self) -> tuple:
+        st = self._cache.stats
+        return (
+            st["full_uploads"] + st["delta_uploads"]
+            + st.get("window_uploads", 0),
+            st["h2d_bytes"], st["delta_tail_rows"],
+        )
+
+    def __enter__(self):
+        super().__enter__()
+        if self.listening:
+            self._before = self._marks()
+        return self
+
+    def __exit__(self, *exc):
+        if self.listening:
+            now = self._marks()
+            before = self._before
+            self.set(
+                hit=now[0] == before[0],
+                h2d_bytes=now[1] - before[1],
+                delta_tail_rows=now[2] - before[2],
+            )
+        return super().__exit__(*exc)
+
+
+class Launcher:
+    """Calls jitted programs for one FusedExecutor: a ``fused.launch``
+    span from the call to its return (the enqueue, argument uploads
+    included), ``device_launches``/``launch_ms`` on the ledger (compile
+    time, which a first call spends inside, stays ``compile_ms``'s),
+    and the retry note a flagged run leaves for the launch that
+    re-answers it."""
+
+    def __init__(self):
+        self.attempt = 0  # launches of the current statement
+        self.programs: list[str] = []  # their names, in order
+        self._retry = None  # (program that raised the flag, reason)
+
+    def begin(self) -> None:
+        """A statement enters the device path."""
+        self.attempt = 0
+        self.programs = []
+        self._retry = None
+
+    def note_retry(self, reason: str) -> None:
+        """The last launch's answer was refused (a flag, an overflow):
+        the next launch carries ``retry_of``/``reason``."""
+        self._retry = (self.programs[-1] if self.programs else "", reason)
+        led = _stmtobs.current()
+        if led is not None:
+            led.fused_retries += 1
+
+    def __call__(self, program, build_args, **args):
+        """``program(*build_args())``; ``build_args`` makes the small
+        uploads (``jnp.asarray`` of row counts, the snapshot) inside
+        the span."""
+        name = program.__name__
+        self.attempt += 1
+        self.programs.append(name)
+        retry, self._retry = self._retry, None
+        if retry is not None:
+            args["retry_of"], args["reason"] = retry
+        with _span(
+            None, "fused.launch", "launch_ms", "device_launches",
+            cat="fused", program=name, attempt=self.attempt, **args,
+        ) as sp:
+            with compile_window() as cw:
+                outs = program(*build_args())
+            if cw.ms:
+                sp.set(compile_ms=round(cw.ms, 3))
+                sp.exclude(cw.ms)
+        return outs
 
 
 # ---------------------------------------------------------------------------
@@ -151,7 +271,7 @@ class DeviceCache:
         want = tuple(columns) if columns is not None else tuple(meta.schema)
         stores = [node_stores[n][name] for n in nodes]
         versions = tuple(s.version for s in stores)
-        with self._mu:
+        with _CacheSpan(self, name), self._mu:
             return self._get_locked(
                 name, meta, stores, nodes, want, versions
             )
@@ -307,7 +427,7 @@ class DeviceCache:
                     maxs[cname] = jnp.max(
                         jnp.where(live, cols[cname], info.min)
                     )
-            fetched = jax.device_get((mins, maxs))
+            fetched = fetch((mins, maxs), "column stats")
         for cname in columns:
             if cname in fetched[0]:
                 lo = int(fetched[0][cname])
@@ -354,14 +474,11 @@ class DeviceCache:
         stores = [node_stores[n][name] for n in nodes]
         versions = tuple(s.version for s in stores)
         wkey = (name, nodes, "win", start, length, want)
-        self._mu.acquire()
-        try:
+        with _CacheSpan(self, name), self._mu:
             return self._get_window_locked(
                 wkey, name, meta, stores, nodes, want, versions,
                 start, length,
             )
-        finally:
-            self._mu.release()
 
     def _get_window_locked(
         self, wkey, name, meta, stores, nodes, want, versions,
@@ -992,13 +1109,10 @@ class FusedExecutor:
         # zone-map pruning on the DEVICE path (VERDICT r2 missing-5):
         # blocks excluded from the scanned window per fused query
         self.zone_stats = {"pruned_blocks": 0, "total_blocks": 0}
-        # per-query phase attribution (obs/): the engine's fused wrapper
-        # fills these after every successful device run — compile (XLA,
-        # via jax.monitoring) vs device execute vs host merge. Surfaced
-        # in EXPLAIN ANALYZE and pg_stat_fused; VERDICT r5 called the
-        # compile-vs-execute split unprovable, this is the proof.
-        self.last_phases: dict[str, float] = {}
-        self.phase_totals: dict[str, float] = {}
+        # the statement path's one way to call a jitted program
+        # (fused.launch span, launch/retry accounting); the DAG runner
+        # shares it
+        self.launch = Launcher()
 
     def dag_output(self, dplan, snapshot_ts, dicts_view, subquery_values):
         """Run a whole multi-fragment plan (joins + exchanges + partial
@@ -1082,6 +1196,7 @@ class FusedExecutor:
         overflow (caller falls back)."""
         if frag.motion != "gather":
             return None
+        self.launch.begin()
         # hash-slot grouping addresses by hash & (cap-1)
         group_cap = 1 << max(group_cap - 1, 1).bit_length()
         m = _match_partial_fragment(frag.root)
@@ -1131,61 +1246,65 @@ class FusedExecutor:
         has_valid = tuple(
             dtab.validity[c] is not None for c in m.scan.columns
         )
-        # structural key: literals are lifted to params, so queries
-        # differing only in constants reuse the compiled program
-        # (m.agg IS the fragment root — the match requires it topmost)
-        try:
-            skey = plan_skey(m.agg)
-        except NotImplementedError:
-            skey = m.agg.key()
 
         def run_mode(grouping: str, cap: int = group_cap):
             win = zone[1] if zone is not None else None
-            key = (
-                skey, dtab.rmax, len(dtab.nrows), cap, has_valid,
-                grouping, win,
-            )
-            # the structural key masks literal values; the compile-time
-            # param specs BAKE them. Rebuild the (lazily-jitted, cheap)
-            # compile output for THIS query and pair the cached
-            # executable with the fresh specs — otherwise 'x = 1'
-            # silently reuses 'x = 7''s parameter
-            fresh = self._compile(
-                m, meta, dtab, cap, has_valid, grouping, win=win
-            )
-            cached = self._programs.get(key)
-            if cached is None:
-                self._programs[key] = fresh
-                cached = fresh
-            program = cached[0]
-            _prog_unused, param_specs, out_info = fresh
-            params = tuple(
-                resolve_param(s, dicts_view, subquery_values)
-                for s in param_specs
-            )
-            snap = jnp.int64(
-                snapshot_ts if snapshot_ts is not None else 2**61
-            )
-            col_args = tuple(dtab.columns[c] for c in m.scan.columns)
-            # only pass validity arrays that exist; presence is static
-            # in the compiled program (materializing all-ones masks for
-            # every all-valid column would stream megabytes per call)
-            val_args = tuple(
-                dtab.validity[c]
-                for c in m.scan.columns
-                if dtab.validity[c] is not None
-            )
-            nrows_dev = jnp.asarray(dtab.nrows)
-            if zone is not None:
-                outs = program(
-                    col_args, val_args, dtab.xmin, dtab.xmax, nrows_dev,
-                    jnp.asarray(zone[0]), snap, params,
+            with _span(None, "fused.bind", "bind_ms", cat="fused") as bsp:
+                # structural key: literals are lifted to params, so
+                # queries differing only in constants reuse the
+                # compiled program (m.agg IS the fragment root — the
+                # match requires it topmost)
+                try:
+                    skey = plan_skey(m.agg)
+                except NotImplementedError:
+                    skey = m.agg.key()
+                key = (
+                    skey, dtab.rmax, len(dtab.nrows), cap, has_valid,
+                    grouping, win,
                 )
-            else:
-                outs = program(
-                    col_args, val_args, dtab.xmin, dtab.xmax, nrows_dev,
-                    snap, params,
+                # the structural key masks literal values; the
+                # compile-time param specs BAKE them. Rebuild the
+                # (lazily-jitted, cheap) compile output for THIS query
+                # and pair the cached executable with the fresh specs —
+                # otherwise 'x = 1' silently reuses 'x = 7''s parameter
+                fresh = self._compile(
+                    m, meta, dtab, cap, has_valid, grouping, win=win
                 )
+                cached = self._programs.get(key)
+                bsp.set(cache="miss" if cached is None else "hit")
+                if cached is None:
+                    self._programs[key] = fresh
+                    cached = fresh
+                program = cached[0]
+                bsp.set(program=program.__name__)
+                _prog_unused, param_specs, out_info = fresh
+                params = tuple(
+                    resolve_param(s, dicts_view, subquery_values)
+                    for s in param_specs
+                )
+                col_args = tuple(dtab.columns[c] for c in m.scan.columns)
+                # only pass validity arrays that exist; presence is
+                # static in the compiled program (materializing
+                # all-ones masks for every all-valid column would
+                # stream megabytes per call)
+                val_args = tuple(
+                    dtab.validity[c]
+                    for c in m.scan.columns
+                    if dtab.validity[c] is not None
+                )
+
+            def args():
+                snap = jnp.int64(
+                    snapshot_ts if snapshot_ts is not None else 2**61
+                )
+                nrows_dev = jnp.asarray(dtab.nrows)
+                extra = () if zone is None else (jnp.asarray(zone[0]),)
+                return (
+                    col_args, val_args, dtab.xmin, dtab.xmax, nrows_dev,
+                    *extra, snap, params,
+                )
+
+            outs = self.launch(program, args, mode=f"{grouping}/{cap}")
             return self._collect(m, outs, out_info, cap, dtab)
 
         def is_collision(e):
@@ -1199,11 +1318,13 @@ class FusedExecutor:
         except FusedUnsupported as e:
             if not is_collision(e):
                 raise
+            self.launch.note_retry("hash collision, bigger cap")
         try:
             return run_mode("hash", group_cap)
         except FusedUnsupported as e:
             if not is_collision(e):
                 raise
+            self.launch.note_retry("hash collision, sort grouping")
             return run_mode("sort", group_cap)
 
     def _scan_footprint(self, meta, columns) -> tuple[int, int, int, int]:
@@ -1378,57 +1499,75 @@ class FusedExecutor:
             return None
         if any(dtab.validity[c] is not None for c in m.scan.columns):
             return None
-        # re-certify against CURRENT column stats on every call: data
-        # growth can push values past the f32-exactness bound, and a
-        # previously-compiled program must not keep running then. The
-        # certification outcome (incl. which products limb-split and the
-        # group-key domain) is part of the cache key, so a bound change
-        # recompiles or falls back rather than reusing a stale program.
-        col_bounds = [dtab.col_maxabs.get(c) for c in m.scan.columns]
-        col_ranges = [dtab.col_range.get(c) for c in m.scan.columns]
-        try:
-            preds, agg_args, group_plan, sig = self._pallas_plan(
-                m, col_bounds, col_ranges
-            )
-        except ps.PallasUnsupported:
-            return None
-        key = ("pallas", m.agg.key(), dtab.rmax, S, sig)
-        cached = self._programs.get(key)
-        if cached is None:
+        with _span(None, "fused.bind", "bind_ms", cat="fused") as bsp:
+            # re-certify against CURRENT column stats on every call:
+            # data growth can push values past the f32-exactness bound,
+            # and a previously-compiled program must not keep running
+            # then. The certification outcome (incl. which products
+            # limb-split and the group-key domain) is part of the cache
+            # key, so a bound change recompiles or falls back rather
+            # than reusing a stale program.
+            col_bounds = [dtab.col_maxabs.get(c) for c in m.scan.columns]
+            col_ranges = [dtab.col_range.get(c) for c in m.scan.columns]
             try:
-                cached = self._compile_pallas(
-                    m, dtab, preds, agg_args, group_plan
+                preds, agg_args, group_plan, sig = self._pallas_plan(
+                    m, col_bounds, col_ranges
                 )
             except ps.PallasUnsupported:
-                cached = False
-            self._programs[key] = cached
-        if cached is False:
-            return None
-        program, layout, n_exprs, specs = cached
-        decoders, n_groups = (
-            (group_plan[1], group_plan[2]) if group_plan else (None, 1)
-        )
-        snap = jnp.int64(
-            snapshot_ts if snapshot_ts is not None else 2**61
-        )
-        cols = tuple(dtab.columns[c] for c in m.scan.columns)
-        try:
-            partials = program(
-                cols, dtab.xmin, dtab.xmax, jnp.asarray(dtab.nrows), snap
+                bsp.set(cache="unsupported")
+                return None
+            key = ("pallas", m.agg.key(), dtab.rmax, S, sig)
+            cached = self._programs.get(key)
+            bsp.set(cache="miss" if cached is None else "hit")
+            if cached is None:
+                try:
+                    cached = self._compile_pallas(
+                        m, dtab, preds, agg_args, group_plan
+                    )
+                except ps.PallasUnsupported:
+                    cached = False
+                self._programs[key] = cached
+            if cached is False:
+                return None
+            program, layout, n_exprs, specs = cached
+            bsp.set(program=program.__name__)
+            decoders, n_groups = (
+                (group_plan[1], group_plan[2]) if group_plan
+                else (None, 1)
             )
-            sums, counts = ps.combine_partials(
-                jax.device_get(partials), layout, n_exprs, n_groups
+            cols = tuple(dtab.columns[c] for c in m.scan.columns)
+
+        def args():
+            snap = jnp.int64(
+                snapshot_ts if snapshot_ts is not None else 2**61
+            )
+            return cols, dtab.xmin, dtab.xmax, jnp.asarray(dtab.nrows), snap
+
+        try:
+            partials = fetch(
+                self.launch(program, args, mode=f"pallas/{n_groups}"),
+                "result",
             )
         except Exception:
             # pallas lowering/runtime failure: XLA path takes over
             self._programs[key] = False
             self._note_pallas_failure(key)
+            self.launch.note_retry("pallas failed, xla program")
             return None
-        if decoders is None:
-            return self._pallas_scalar_batch(m, sums[:, 0], counts[:, 0], specs, S)
-        return self._pallas_grouped_batch(
-            m, sums, counts, specs, decoders, S, n_groups
-        )
+        with _span(None, "fused.collect", "collect_ms", cat="fused") as csp:
+            sums, counts = ps.combine_partials(
+                partials, layout, n_exprs, n_groups
+            )
+            if decoders is None:
+                out = self._pallas_scalar_batch(
+                    m, sums[:, 0], counts[:, 0], specs, S
+                )
+            else:
+                out = self._pallas_grouped_batch(
+                    m, sums, counts, specs, decoders, S, n_groups
+                )
+            csp.set(rows=out.nrows)
+        return out
 
     def _pallas_scalar_batch(self, m, sums, counts, specs, S) -> ColumnBatch:
         # per-shard partial rows, matching the XLA scalar path's output
@@ -1580,24 +1719,25 @@ class FusedExecutor:
         mesh = self.mesh
         rmax = dtab.rmax
 
-        @jax.jit
         def program(cols, xmin, xmax, nrows, snap):
             # visibility in XLA (int64 timestamps are not pallas
             # material); the kernel consumes it as an f32 column
-            live = (
-                (jnp.arange(rmax)[None, :] < nrows[:, None])
-                & (xmin <= snap)
-                & (snap < xmax)
-            ).astype(jnp.float32)
+            with scope("scan/mvcc"):
+                live = (
+                    (jnp.arange(rmax)[None, :] < nrows[:, None])
+                    & (xmin <= snap)
+                    & (snap < xmax)
+                ).astype(jnp.float32)
 
             def block(cols, live):
                 # [k, Rmax] per device (k shards per device): flatten
                 # the local shards into one row axis — one pallas grid
                 # per device, no vmap-of-pallas composition
-                blk = [
-                    c.reshape(-1).astype(jnp.float32) for c in cols
-                ]
-                blk.append(live.reshape(-1))
+                with scope("scan/decode"):
+                    blk = [
+                        c.reshape(-1).astype(jnp.float32) for c in cols
+                    ]
+                    blk.append(live.reshape(-1))
                 return run(blk)[None]
 
             return shard_map(
@@ -1608,7 +1748,10 @@ class FusedExecutor:
                 check_vma=False,  # pallas_call carries no vma info
             )(cols, live)
 
-        return program, layout, n_exprs, specs
+        return (
+            named_program(program, "program_scan_pallas"), layout,
+            n_exprs, specs,
+        )
 
     # -- compilation -----------------------------------------------------
     def _compile(
@@ -1683,20 +1826,22 @@ class FusedExecutor:
                         )
                     )(a2d, starts)
 
-                cols = [sl(c) for c in cols]
-                valids = [sl(v) for v in valids]
-                if not compact:
-                    xmin = sl(xmin)
-                    xmax = sl(xmax)
+                with scope("scan/decode"):
+                    cols = [sl(c) for c in cols]
+                    valids = [sl(v) for v in valids]
+                    if not compact:
+                        xmin = sl(xmin)
+                        xmax = sl(xmax)
                 nrows = jnp.clip(
                     nrows - starts.astype(nrows.dtype), 0, win
                 )
                 rmax = win
             n = k * rmax
-            live = (
-                (jnp.arange(rmax)[None, :] < nrows[:, None])
-                & (xmin <= snap) & (snap < xmax)
-            ).reshape(n)
+            with scope("scan/mvcc"):
+                live = (
+                    (jnp.arange(rmax)[None, :] < nrows[:, None])
+                    & (xmin <= snap) & (snap < xmax)
+                ).reshape(n)
             cols = [c.reshape(n) for c in cols]
             valids = [v.reshape(n) for v in valids]
             env = []
@@ -1710,20 +1855,26 @@ class FusedExecutor:
             mask = live
             for kind, fn in step_fns:
                 if kind == "filter":
-                    d, v = fn(env, params)
-                    keep = d if v is None else (d & v)
-                    mask = mask & jnp.broadcast_to(keep, (n,))
+                    with scope("scan/predicate"):
+                        d, v = fn(env, params)
+                        keep = d if v is None else (d & v)
+                        mask = mask & jnp.broadcast_to(keep, (n,))
                 else:
-                    env = [
-                        _bcast(f(env, params), n) for f in fn
-                    ]
-            keys = [_bcast(fn(env, params), n) for fn in gfns]
-            vals = [
-                None if fn is None else _bcast(fn(env, params), n)
-                for fn in afns
-            ]
+                    with scope("scan/project"):
+                        env = [
+                            _bcast(f(env, params), n) for f in fn
+                        ]
+            with scope("agg/args"):
+                keys = [_bcast(fn(env, params), n) for fn in gfns]
+                vals = [
+                    None if fn is None else _bcast(fn(env, params), n)
+                    for fn in afns
+                ]
             if not grouped:
-                outs = agg_ops._scalar_reduce_impl(vals, mask, tuple(specs))
+                with scope("agg/scalar"):
+                    outs = agg_ops._scalar_reduce_impl(
+                        vals, mask, tuple(specs)
+                    )
                 return (
                     [],
                     [(jnp.reshape(d, (1,)), jnp.reshape(v, (1,))) for d, v in outs],
@@ -1738,32 +1889,39 @@ class FusedExecutor:
                 # the sort variant
                 if agg_ops.mxu_group_eligible(keys, vals, specs):
                     # scatter-free: one-hot matmuls on the MXU (TPU
-                    # scatter/sort are orders of magnitude slower)
-                    slot, _p64, _vis = agg_ops._hash_slot_ids(
-                        keys, mask, group_cap
-                    )
+                    # scatter/sort are orders of magnitude slower);
+                    # its limb and one-hot stages carry their own
+                    # scopes (ops/agg.py)
+                    with scope("agg/hashslot"):
+                        slot, _p64, _vis = agg_ops._hash_slot_ids(
+                            keys, mask, group_cap
+                        )
                     return agg_ops._mxu_group_reduce_impl(
                         keys, vals, slot, group_cap, tuple(specs)
                     )
-                slot, ngroups, collision = agg_ops._hash_slots_impl(
-                    keys, mask, group_cap
-                )
-                out_keys, out_vals, gvalid = agg_ops._group_reduce_impl(
-                    keys, vals, jnp.arange(n, dtype=jnp.int32), slot,
-                    group_cap, tuple(specs),
-                )
+                with scope("agg/hashslot"):
+                    slot, ngroups, collision = agg_ops._hash_slots_impl(
+                        keys, mask, group_cap
+                    )
+                with scope("agg/segreduce"):
+                    out_keys, out_vals, gvalid = (
+                        agg_ops._group_reduce_impl(
+                            keys, vals, jnp.arange(n, dtype=jnp.int32),
+                            slot, group_cap, tuple(specs),
+                        )
+                    )
                 return out_keys, out_vals, gvalid, ngroups, collision
-            perm, seg, ngroups = agg_ops._group_ids_impl(keys, mask)
-            out_keys, out_vals, gvalid = agg_ops._group_reduce_impl(
-                keys, vals, perm, seg, group_cap, tuple(specs)
-            )
+            with scope("agg/sortgroup"):
+                perm, seg, ngroups = agg_ops._group_ids_impl(keys, mask)
+                out_keys, out_vals, gvalid = agg_ops._group_reduce_impl(
+                    keys, vals, perm, seg, group_cap, tuple(specs)
+                )
             return out_keys, out_vals, gvalid, ngroups, jnp.asarray(False)
 
         mesh = self.mesh
 
         # ONE program definition; the zone-window variant simply carries
         # one extra sharded operand (per-shard slice starts)
-        @partial(jax.jit, static_argnums=())
         def program(cols, valids, xmin, xmax, nrows, *rest):
             if win is not None:
                 starts, snap, params = rest
@@ -1798,41 +1956,49 @@ class FusedExecutor:
             "grouped": grouped, "nkeys": nkeys, "specs": specs,
             "grouping": grouping,
         }
-        return program, comp.params, out_info
+        return (
+            named_program(program, f"program_scan_xla_{grouping}"),
+            comp.params, out_info,
+        )
 
     # -- output collection ------------------------------------------------
     def _collect(self, m, outs, out_info, group_cap, dtab) -> ColumnBatch:
         # ONE batched device->host fetch: per-array np.asarray would
         # sync with the device and pay a transfer once per array
-        outs = jax.device_get(outs)
-        out_keys, out_vals, gvalid, ngroups, collision = outs
-        grouped = out_info["grouped"]
-        if grouped and bool(np.asarray(collision).any()):
-            raise FusedUnsupported("group hash collision")
-        if grouped and out_info.get("grouping") == "sort" and (
-            int(np.asarray(ngroups).max()) >= group_cap
-        ):
-            # sort mode can exceed the static capacity: the general
-            # executor (dynamic group count) recomputes
-            raise FusedUnsupported("group capacity overflow")
-        # flatten [S, cap] -> rows, keeping only valid groups
-        gv = np.asarray(gvalid).reshape(-1)
-        agg_plan = m.agg
-        cols: dict[str, Column] = {}
-        keep = np.nonzero(gv)[0]
-        for i, oc in enumerate(agg_plan.schema):
-            if i < out_info["nkeys"]:
-                d, v = out_keys[i]
-            else:
-                d, v = out_vals[i - out_info["nkeys"]]
-            dd = np.asarray(d).reshape(-1)[keep]
-            vv = None if v is None else np.asarray(v).reshape(-1)[keep]
-            dic = self.catalog.dictionary(oc.dict_id) if oc.dict_id else None
-            ty = oc.type
-            if dd.dtype != ty.np_dtype:
-                dd = dd.astype(ty.np_dtype)
-            cols[oc.name] = Column(ty, dd, vv, dic)
-        return ColumnBatch(cols, len(keep))
+        outs = fetch(outs, "result")
+        with _span(None, "fused.collect", "collect_ms", cat="fused") as csp:
+            out_keys, out_vals, gvalid, ngroups, collision = outs
+            grouped = out_info["grouped"]
+            if grouped and bool(collision.any()):
+                raise FusedUnsupported("group hash collision")
+            if grouped and out_info.get("grouping") == "sort" and (
+                int(ngroups.max()) >= group_cap
+            ):
+                # sort mode can exceed the static capacity: the general
+                # executor (dynamic group count) recomputes
+                raise FusedUnsupported("group capacity overflow")
+            # flatten [S, cap] -> rows, keeping only valid groups
+            gv = gvalid.reshape(-1)
+            agg_plan = m.agg
+            cols: dict[str, Column] = {}
+            keep = np.nonzero(gv)[0]
+            for i, oc in enumerate(agg_plan.schema):
+                if i < out_info["nkeys"]:
+                    d, v = out_keys[i]
+                else:
+                    d, v = out_vals[i - out_info["nkeys"]]
+                dd = d.reshape(-1)[keep]
+                vv = None if v is None else v.reshape(-1)[keep]
+                dic = (
+                    self.catalog.dictionary(oc.dict_id)
+                    if oc.dict_id else None
+                )
+                ty = oc.type
+                if dd.dtype != ty.np_dtype:
+                    dd = dd.astype(ty.np_dtype)
+                cols[oc.name] = Column(ty, dd, vv, dic)
+            csp.set(rows=len(keep))
+            return ColumnBatch(cols, len(keep))
 
 
 def _bcast(kv, n):

@@ -31,7 +31,7 @@ flips the build side or gives up so the host path answers instead.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import wraps
 from typing import Callable, Optional
 
 import numpy as np
@@ -43,6 +43,8 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from opentenbase_tpu import types as t
+from opentenbase_tpu.executor.fused import fetch, named_program
+from opentenbase_tpu.obs.trace import scope, span as _span
 from opentenbase_tpu.ops import agg as agg_ops
 from opentenbase_tpu.ops import filter as filt_ops
 from opentenbase_tpu.ops.expr import ExprCompiler, resolve_param
@@ -85,6 +87,54 @@ EXCHANGE_HBM_BUDGET = int(
 DIMFOLD_MAX_BUILD = int(
     os.environ.get("OTB_DIMFOLD_MAX", 33_554_432)
 )
+
+
+class _Stage:
+    """Consecutive ``otb/`` scopes through one long traced body:
+    ``to(stage)`` leaves the current scope and enters the next."""
+
+    def __init__(self):
+        self._cm = None
+
+    def to(self, stage: str) -> None:
+        self.done()
+        self._cm = scope(stage)
+        self._cm.__enter__()
+
+    def done(self) -> None:
+        cm, self._cm = self._cm, None
+        if cm is not None:
+            cm.__exit__(None, None, None)
+
+
+def _staged(fn):
+    """``fn(*args, st)`` with a ``_Stage`` it may switch; the last
+    scope is left when ``fn`` returns or raises (an open named_scope
+    would rename every later trace on the thread)."""
+
+    @wraps(fn)
+    def block(*args):
+        st = _Stage()
+        try:
+            return fn(*args, st=st)
+        finally:
+            st.done()
+
+    return block
+
+
+def _collecting(fn):
+    """A ``_collect*`` method as the ``fused.collect`` span
+    (``collect_ms``): the fetched numpy arrays to a ColumnBatch."""
+
+    @wraps(fn)
+    def collect(self, *args):
+        with _span(None, "fused.collect", "collect_ms", cat="fused") as sp:
+            out = fn(self, *args)
+            sp.set(rows=out.nrows)
+        return out
+
+    return collect
 
 
 class DagUnsupported(Exception):
@@ -635,7 +685,8 @@ def _radix_gate(
 
 
 def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
-                  pallas_probe: bool = False, note_pallas=None):
+                  pallas_probe: bool = False, note_mode=None,
+                  tag: str = "join"):
     """Equi-join primitive over the bucket-padded radix hash table
     (ops/join.py): ONE small build-side sort + a log2(bucket)-deep
     bucket search per probe row, instead of sort-merge's full
@@ -664,7 +715,10 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
         )
     plan = batchplan.plan_radix_join(nb, npr, budget)
     if plan is None:
-        return fallback(pk, pmask, bk, bmask, check_dup=True)
+        if note_mode is not None:
+            note_mode("merge")
+        with scope(f"{tag}/merge"):
+            return fallback(pk, pmask, bk, bmask, check_dup=True)
     breal = bmask if bv is None else (bmask & bv)
     preal = pmask if pv is None else (pmask & pv)
     P, B = plan.partitions, plan.bucket
@@ -679,23 +733,26 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
         e = min(s + chunk, nb)
         if s >= e:
             break
-        tkeys, tvalid, tbidx, dup, ovf = join_ops.build_radix_table(
-            bd[s:e], breal[s:e], P, B
-        )
+        with scope(f"{tag}/radix/build"):
+            tkeys, tvalid, tbidx, dup, ovf = join_ops.build_radix_table(
+                bd[s:e], breal[s:e], P, B
+            )
         if pallas_probe and pj.eligible(e - s, P, B):
             # compiled by Mosaic with the rest of the program: a
             # lowering failure fails the program (a counted, logged
             # fused->host demotion, engine._try_fused_inner), never a
             # quiet switch to the XLA probe
-            m, bi = pj.probe_radix_pallas(
-                tkeys, tvalid, tbidx, pd, preal, P, B
-            )
-            if note_pallas is not None:
-                note_pallas()
+            with scope(f"{tag}/pallas/probe"):
+                m, bi = pj.probe_radix_pallas(
+                    tkeys, tvalid, tbidx, pd, preal, P, B
+                )
+            if note_mode is not None:
+                note_mode("pallas")
         else:
-            m, bi = join_ops.probe_radix_first(
-                tkeys, tvalid, tbidx, pd, preal, P, B
-            )
+            with scope(f"{tag}/radix/probe"):
+                m, bi = join_ops.probe_radix_first(
+                    tkeys, tvalid, tbidx, pd, preal, P, B
+                )
         # a probe key matching in two passes = build dup across chunks
         flag = flag | dup | ovf | jnp.any(m & matched)
         bidx = jnp.where(m & ~matched, bi + jnp.int32(s), bidx)
@@ -960,6 +1017,12 @@ class _Builder:
         self.folded: set = set()
         self.folded_ids: dict = {}  # id(join) -> build_right, folded
         self.radixed: set = set()  # joins THIS compile radix-hashed
+        # the join formulations of THIS compile: 'fold'/'radix'/'merge'
+        # chosen here, 'pallas' (and a radix plan's sort-merge
+        # fallback) added when the probe is traced in. The set rides
+        # the jitted program (DagRunner._program), so a run reports the
+        # modes of the cache entry that ran
+        self.modes: set = set()
         fx_h = runner.fx if runner is not None else fx
         self.join_mode = str(getattr(fx_h, "join_mode", "auto"))
         self.radix_budget = batchplan.resolve_budget(
@@ -1078,33 +1141,38 @@ class _Builder:
                         (k, W),
                     )
 
-                cols = [sl(c) for c in cols]
-                valids = [sl(v) for v in valids]
-                if xmin.shape[1] != 1:
-                    xmin, xmax = sl(xmin), sl(xmax)
+                with scope("scan/decode"):
+                    cols = [sl(c) for c in cols]
+                    valids = [sl(v) for v in valids]
+                    if xmin.shape[1] != 1:
+                        xmin, xmax = sl(xmin), sl(xmax)
                 n = k * W
-                live = (
-                    (wstart + jnp.arange(W)[None, :] < nrows[:, None])
-                    & (xmin <= snap) & (snap < xmax)
-                ).reshape(n)
+                with scope("scan/mvcc"):
+                    live = (
+                        (wstart + jnp.arange(W)[None, :]
+                         < nrows[:, None])
+                        & (xmin <= snap) & (snap < xmax)
+                    ).reshape(n)
             else:
                 cols, valids, xmin, xmax, nrows = blocks[idx]
                 k = xmin.shape[0]
                 rmax = rmax0
                 n = k * rmax
-                live = (
-                    (jnp.arange(rmax)[None, :] < nrows[:, None])
-                    & (xmin <= snap) & (snap < xmax)
-                ).reshape(n)
+                with scope("scan/mvcc"):
+                    live = (
+                        (jnp.arange(rmax)[None, :] < nrows[:, None])
+                        & (xmin <= snap) & (snap < xmax)
+                    ).reshape(n)
             env = []
             vi = 0
-            for ci in range(len(cols)):
-                d = cols[ci].reshape(n)
-                if has_valid[ci]:
-                    env.append((d, valids[vi].reshape(n)))
-                    vi += 1
-                else:
-                    env.append((d, None))
+            with scope("scan/decode"):
+                for ci in range(len(cols)):
+                    d = cols[ci].reshape(n)
+                    if has_valid[ci]:
+                        env.append((d, valids[vi].reshape(n)))
+                        vi += 1
+                    else:
+                        env.append((d, None))
             return env, live, n, []
 
         return run
@@ -1118,13 +1186,14 @@ class _Builder:
             cols, valids, counts = blocks[idx]
             dsrc, cap = cols[0].shape
             n = dsrc * cap
-            live = (
-                jnp.arange(cap)[None, :] < counts[:, None]
-            ).reshape(n)
-            env = [
-                (cols[i].reshape(n), valids[i].reshape(n))
-                for i in range(len(cols))
-            ]
+            with scope("exchange/read"):
+                live = (
+                    jnp.arange(cap)[None, :] < counts[:, None]
+                ).reshape(n)
+                env = [
+                    (cols[i].reshape(n), valids[i].reshape(n))
+                    for i in range(len(cols))
+                ]
             return env, live, n, []
 
         return run
@@ -1135,12 +1204,17 @@ class _Builder:
             child = self.build(node.child, exchanged, D)
             dids = [c.dict_id for c in node.child.schema]
             pred = self.comp.compile(node.predicate, dids)
+            stage = (
+                "filter" if _contains_join(node.child) else "scan"
+            ) + "/predicate"
 
             def run(blocks, params, snap):
                 env, mask, n, flags = child(blocks, params, snap)
-                d, v = pred(env, params)
-                keep = d if v is None else (d & v)
-                return env, mask & jnp.broadcast_to(keep, (n,)), n, flags
+                with scope(stage):
+                    d, v = pred(env, params)
+                    keep = d if v is None else (d & v)
+                    mask = mask & jnp.broadcast_to(keep, (n,))
+                return env, mask, n, flags
 
             return run
 
@@ -1155,9 +1229,14 @@ class _Builder:
                 for ex, oc in zip(node.exprs, node.schema)
             ]
 
+            stage = (
+                "project" if _contains_join(node.child) else "scan"
+            ) + "/project"
+
             def run(blocks, params, snap):
                 env, mask, n, flags = child(blocks, params, snap)
-                out = [_bcast(fn(env, params), n) for fn in fns]
+                with scope(stage):
+                    out = [_bcast(fn(env, params), n) for fn in fns]
                 return out, mask, n, flags
 
             return run
@@ -1229,11 +1308,9 @@ class _Builder:
                 )
                 if use_radix:
                     self.radixed.add(ji)
-            if self.runner is not None:
-                self.runner.note_join_mode(
-                    ji,
-                    "fold" if fold else ("radix" if use_radix else "merge"),
-                )
+            self.modes.add(
+                "fold" if fold else ("radix" if use_radix else "merge")
+            )
         if self.D > 1:
             # replicated tables scanned INSIDE a multi-device join
             # fragment hold their rows on one device — a build side
@@ -1280,12 +1357,12 @@ class _Builder:
             and self.platform == "tpu"
             and getattr(self.fx, "enable_pallas_join", True) is not False
         )
-        # 'pallas' joins the run's join modes (EXPLAIN ANALYZE,
+        # 'pallas' joins the program's join modes (EXPLAIN ANALYZE,
         # pg_stat_fused last_join_modes) when the probe is traced in
-        note_pallas = (
-            partial(self.runner.note_join_mode, ji, "pallas")
-            if pallas_probe and self.runner is not None else None
-        )
+        note_mode = self.modes.add
+        # scope of this join's lowering: join<i>/<formulation>
+        jtag = f"join{ji}" if jt == "inner" else f"join_{jt}"
+        jmode = "fold" if fold else ("radix" if use_radix else "merge")
 
         def run(blocks, params, snap):
             if fold:
@@ -1306,19 +1383,22 @@ class _Builder:
                 # the duplicate leaf read); slot validity: the full
                 # build mask (filters + nested fold matches)
                 _lenv, bvis, _bvn, _bf = bstrip_fn(blocks, params, snap)
-                matched, bidx, dup = _lookup_dense(
-                    pk, pmask, bk, bvis, bmask, presorted=presorted
-                )
+                with scope(f"{jtag}/fold"):
+                    matched, bidx, dup = _lookup_dense(
+                        pk, pmask, bk, bvis, bmask, presorted=presorted
+                    )
                 flags = flags + [dup]
                 if do_capture:
                     builder.captured = (bidx, benv, bn)
-                gathered = [
-                    (
-                        jnp.take(d, bidx, axis=0),
-                        None if v is None else jnp.take(v, bidx, axis=0),
-                    )
-                    for d, v in benv
-                ]
+                with scope(f"{jtag}/fold/gather"):
+                    gathered = [
+                        (
+                            jnp.take(d, bidx, axis=0),
+                            None if v is None
+                            else jnp.take(v, bidx, axis=0),
+                        )
+                        for d, v in benv
+                    ]
                 env = (
                     list(penv) + gathered
                     if build_right
@@ -1338,9 +1418,10 @@ class _Builder:
             rk = _bcast(rkfn(renv, params), rn)
             if jt in ("semi", "anti"):
                 # existence probe: build-side duplicates are harmless
-                matched, _bidx, _dup = lookup(
-                    lk, lmask, rk, rmask, check_dup=False
-                )
+                with scope(f"{jtag}/merge"):
+                    matched, _bidx, _dup = lookup(
+                        lk, lmask, rk, rmask, check_dup=False
+                    )
                 mask = lmask & (matched if jt == "semi" else ~matched)
                 env, n = lenv, ln
             else:
@@ -1356,22 +1437,25 @@ class _Builder:
                     matched, bidx, dup = _lookup_radix(
                         pk, pmask, bk, bmask, radix_budget, lookup,
                         pallas_probe=pallas_probe,
-                        note_pallas=note_pallas,
+                        note_mode=note_mode, tag=jtag,
                     )
                 else:
-                    matched, bidx, dup = lookup(
-                        pk, pmask, bk, bmask, check_dup=True
-                    )
+                    with scope(f"{jtag}/merge"):
+                        matched, bidx, dup = lookup(
+                            pk, pmask, bk, bmask, check_dup=True
+                        )
                 flags = flags + [dup]
                 if do_capture:
                     builder.captured = (bidx, benv, bn)
-                gathered = [
-                    (
-                        jnp.take(d, bidx, axis=0),
-                        None if v is None else jnp.take(v, bidx, axis=0),
-                    )
-                    for d, v in benv
-                ]
+                with scope(f"{jtag}/{jmode}/gather"):
+                    gathered = [
+                        (
+                            jnp.take(d, bidx, axis=0),
+                            None if v is None
+                            else jnp.take(v, bidx, axis=0),
+                        )
+                        for d, v in benv
+                    ]
                 env = (
                     list(penv) + gathered
                     if build_right
@@ -1418,16 +1502,19 @@ class DagRunner:
         self._caps: dict = {}
         self.completed = 0  # DAG runs that produced the final batch
         self.last_mode = None  # final-fragment mode of the last run
-        # per-fragment wall time of the last completed run (exchange
-        # programs + the final fragment, key "final") — the device-side
-        # breakdown EXPLAIN ANALYZE VERBOSE prints for fused plans
-        self.last_frag_ms: dict = {}
         self.last_folded = frozenset()  # joins dense-folded in last run
-        # join formulations the last run's programs compiled
-        # ('fold'/'radix'/'merge') — EXPLAIN and pg_stat_fused surface
-        # them so a mode-selection regression is visible per query
+        # join formulations of the programs whose answers the last run
+        # ACCEPTED ('fold'/'radix'/'pallas'/'merge'), read from the
+        # record each program-cache entry keeps of its own compile —
+        # so a cold run, a re-trace and a cached run say the same, and
+        # a formulation that was tried and refused shows as a retry,
+        # not as a mode. EXPLAIN, pg_stat_fused and the fused.launch
+        # span surface them.
         self.last_join_modes: tuple = ()
         self._mode_notes: set = set()
+        # the device programs the last run launched, in order
+        self.last_programs: tuple = ()
+        self._frag = None  # fragment the run is on (span arg)
         # bounded log of plans that fell back to the host path and why —
         # surfaced through pg_stat_fused so demotion is NEVER silent
         self.unsupported: list = []
@@ -1451,9 +1538,7 @@ class DagRunner:
             return None
 
     def _run(self, dplan, snapshot_ts, dicts_view, subquery_values):
-        from time import perf_counter as _perf_counter
-
-        frag_ms: dict = {}
+        self.fx.launch.begin()
         self._mode_notes = set()
         frags = dplan.fragments
         if not frags:
@@ -1508,20 +1593,18 @@ class DagRunner:
                     if f.motion == "broadcast"
                     else self._run_exchange
                 )
-                t_f0 = _perf_counter()
+                self._frag = f.index
                 exchanged[f.index] = run(
                     f, exchanged, snap, dicts_view, subquery_values, D,
                     versions,
                 )
-                frag_ms[f.index] = (_perf_counter() - t_f0) * 1000.0
-        t_f0 = _perf_counter()
+        self._frag = "final"
         batch = self._run_final(
             final, final_root, exchanged, snap, dicts_view,
             subquery_values, D, versions, dplan,
         )
-        frag_ms["final"] = (_perf_counter() - t_f0) * 1000.0
-        self.last_frag_ms = frag_ms
         self.last_join_modes = tuple(sorted(self._mode_notes))
+        self.last_programs = tuple(self.fx.launch.programs)
         self.completed += 1
         # device-platform watchdog: every completed DAG run stamps the
         # platform it actually executed on (executor/fused.py) — the
@@ -1530,9 +1613,37 @@ class DagRunner:
         self.fx.note_run_platform()
         return final.index, batch
 
-    def note_join_mode(self, ji: int, mode: str) -> None:
-        """Builder callback: join ``ji`` compiled with ``mode``."""
-        self._mode_notes.add(mode)
+    @staticmethod
+    def _program(fn, name: str, b=None):
+        """Jit ``fn`` as ``program_dag_<name>`` and hang the builder's
+        join-mode record on it: the record lives and dies with the
+        program-cache entry."""
+        prog = named_program(fn, "program_dag_" + name)
+        prog.join_modes = b.modes if b is not None else set()
+        return prog
+
+    def _launch(self, prog, arrays, params, snap, **args):
+        """Enqueue one DAG program (``fused.launch``): its name, the
+        fragment it serves and the join formulations it was compiled
+        with ride the span."""
+        return self.fx.launch(
+            prog, lambda: (tuple(arrays), params, snap),
+            frag=self._frag,
+            join_modes="+".join(sorted(prog.join_modes)) or None,
+            **args,
+        )
+
+    def _fetch(self, tree, what: str):
+        return fetch(tree, what, frag=self._frag)
+
+    def _accept(self, *progs) -> None:
+        """The run keeps these programs' answers: their join
+        formulations are the run's."""
+        for prog in progs:
+            self._mode_notes |= prog.join_modes
+
+    def _retry(self, reason: str) -> None:
+        self.fx.launch.note_retry(reason)
 
     def _data_versions(self, frags) -> tuple:
         """(table, version) for every scanned store — keys the cached
@@ -1614,6 +1725,25 @@ class DagRunner:
             resolve_param(s, dicts_view, subquery_values)
             for s in comp.params
         )
+
+    def _bind(self, key, compile_fn, dicts_view, subquery_values):
+        """``_cached_program`` + ``_resolve`` as the ``fused.bind`` span
+        (``bind_ms``): the fresh closure, the program lookup and the
+        literals resolved against it. Returns the entry with the
+        resolved params appended."""
+        with _span(None, "fused.bind", "bind_ms", cat="fused") as bsp:
+            # (looked up twice only when a sink will read the verdict)
+            hit = bsp.listening and key in self._programs
+            entry = self._cached_program(key, compile_fn)
+            np_ = self._NPROGS.get(key[0], 1)
+            params = self._resolve(
+                entry[np_], dicts_view, subquery_values
+            )
+            bsp.set(
+                program=entry[0].__name__,
+                cache="hit" if hit else "miss", frag=self._frag,
+            )
+        return entry + (params,)
 
     def _est_rows(self, node) -> int:
         """Rough output-width estimate for orientation seeding: the
@@ -1707,15 +1837,18 @@ class DagRunner:
         side (raises when both sides were tried)."""
         folded, radixed = jinfo
         if flip in folded:
+            self._retry(f"join{flip} fold density flag: fold off")
             self._fold_off.setdefault(skey, set()).add(flip)
             while len(self._fold_off) > 512:
                 self._fold_off.pop(next(iter(self._fold_off)))
             return orientation
         if flip in radixed:
+            self._retry(f"join{flip} radix overflow or dup: radix off")
             self._radix_off.setdefault(skey, set()).add(flip)
             while len(self._radix_off) > 512:
                 self._radix_off.pop(next(iter(self._radix_off)))
             return orientation
+        self._retry(f"join{flip} duplicate build keys: flip sides")
         return self._flip(orientation, flip)
 
     def _check_hbm_budget(self, cap: int, schema, D: int) -> None:
@@ -1760,49 +1893,51 @@ class DagRunner:
             # unchanged data (literals are lifted params, so the skey
             # alone would alias different constants).
             ckey = ("xcnt", skey, orientation, hashpos, D, sig, fo)
-            prog, comp, jinfo = self._cached_program(
+            prog, comp, jinfo, params = self._bind(
                 ckey,
                 lambda: self._compile_count(
                     frag.root, exchanged, orientation, hashpos, D, fo
                 ),
+                dicts_view, subquery_values,
             )
-            params = self._resolve(comp, dicts_view, subquery_values)
             capkey = (
                 "cap", skey, orientation, hashpos, D, sig, versions, fo,
                 _params_sig(params),
             )
             cap = self._caps.get(capkey)
             if cap is None:
-                counts, flags = prog(tuple(arrays), params, snap)
-                flags = [np.asarray(f) for f in flags]
+                counts, flags = self._fetch(
+                    self._launch(prog, arrays, params, snap),
+                    "count pass",
+                )
                 flip = _first_true(flags)
                 if flip is not None:
                     orientation = self._on_flag(
                         skey, orientation, flip, jinfo
                     )
                     continue
-                cap = filt_ops.bucket_size(
-                    max(int(np.asarray(counts).max()), 1)
-                )
+                cap = filt_ops.bucket_size(max(int(counts.max()), 1))
                 self._cap_store(capkey, cap)
             self._check_hbm_budget(cap, frag.root.schema, D)
 
             # pass 2: the bucketed all_to_all
             xkey = ("xchg", skey, orientation, hashpos, D, cap, sig, fo)
-            prog, comp, jinfo = self._cached_program(
+            prog, comp, jinfo, params = self._bind(
                 xkey,
                 lambda: self._compile_exchange(
                     frag.root, exchanged, orientation, hashpos, D, cap,
                     fo,
                 ),
+                dicts_view, subquery_values,
             )
-            params = self._resolve(comp, dicts_view, subquery_values)
-            cols, valids, rcounts, flags = prog(tuple(arrays), params, snap)
-            flags = [np.asarray(f) for f in flags]
-            flip = _first_true(flags)
+            cols, valids, rcounts, flags = self._launch(
+                prog, arrays, params, snap, mode=f"cap/{cap}"
+            )
+            flip = _first_true(self._fetch(flags, "join flags"))
             if flip is not None:
                 orientation = self._on_flag(skey, orientation, flip, jinfo)
                 continue
+            self._accept(prog)
             self._orientations[skey] = orientation
             return {
                 "cols": cols,
@@ -1828,47 +1963,49 @@ class DagRunner:
         while True:
             fo = self._offs(skey)
             ckey = ("bcnt", skey, orientation, D, sig, fo)
-            prog, comp, jinfo = self._cached_program(
+            prog, comp, jinfo, params = self._bind(
                 ckey,
                 lambda: self._compile_broadcast_count(
                     frag.root, exchanged, orientation, D, fo
                 ),
+                dicts_view, subquery_values,
             )
-            params = self._resolve(comp, dicts_view, subquery_values)
             capkey = (
                 "bcap", skey, orientation, D, sig, versions, fo,
                 _params_sig(params),
             )
             cap = self._caps.get(capkey)
             if cap is None:
-                counts, flags = prog(tuple(arrays), params, snap)
-                flags = [np.asarray(f) for f in flags]
+                counts, flags = self._fetch(
+                    self._launch(prog, arrays, params, snap),
+                    "count pass",
+                )
                 flip = _first_true(flags)
                 if flip is not None:
                     orientation = self._on_flag(
                         skey, orientation, flip, jinfo
                     )
                     continue
-                cap = filt_ops.bucket_size(
-                    max(int(np.asarray(counts).max()), 1)
-                )
+                cap = filt_ops.bucket_size(max(int(counts.max()), 1))
                 self._cap_store(capkey, cap)
             self._check_hbm_budget(cap, frag.root.schema, D)
 
             bkey = ("bcast", skey, orientation, D, cap, sig, fo)
-            prog, comp, jinfo = self._cached_program(
+            prog, comp, jinfo, params = self._bind(
                 bkey,
                 lambda: self._compile_broadcast(
                     frag.root, exchanged, orientation, D, cap, fo
                 ),
+                dicts_view, subquery_values,
             )
-            params = self._resolve(comp, dicts_view, subquery_values)
-            cols, valids, rcounts, flags = prog(tuple(arrays), params, snap)
-            flags = [np.asarray(f) for f in flags]
-            flip = _first_true(flags)
+            cols, valids, rcounts, flags = self._launch(
+                prog, arrays, params, snap, mode=f"cap/{cap}"
+            )
+            flip = _first_true(self._fetch(flags, "join flags"))
             if flip is not None:
                 orientation = self._on_flag(skey, orientation, flip, jinfo)
                 continue
+            self._accept(prog)
             self._orientations[skey] = orientation
             return {
                 "cols": cols,
@@ -1905,7 +2042,7 @@ class DagRunner:
                 out_specs=(P("dn"), [P("dn")] * nflags),
             )(arrays)
 
-        return jax.jit(program), comp, b.jinfo()
+        return self._program(program, "count", b), comp, b.jinfo()
 
     def _compile_broadcast(
         self, root, exchanged, orientation, D, cap, fo=frozenset()
@@ -1960,24 +2097,26 @@ class DagRunner:
                 ),
             )(arrays)
 
-        return jax.jit(program), comp, b.jinfo()
+        return self._program(program, "broadcast", b), comp, b.jinfo()
 
     def _routed_eval(self, ev, hashpos, D):
         def run(blocks, params, snap):
             env, mask, n, flags = ev(blocks, params, snap)
             hashes = []
-            for p in hashpos:
-                d, v = env[p]
-                h = hash32_jnp(d)
-                if v is not None:
-                    # NULL keys route to a deterministic bucket; the
-                    # join's matched-logic already excludes them, and
-                    # anti-join probes must SURVIVE, so never drop here
-                    h = jnp.where(v, h, jnp.uint32(0))
-                hashes.append(h)
-            dest = (
-                combine_hashes(hashes, jnp) % jnp.uint32(D)
-            ).astype(jnp.int32)
+            with scope("exchange/route"):
+                for p in hashpos:
+                    d, v = env[p]
+                    h = hash32_jnp(d)
+                    if v is not None:
+                        # NULL keys route to a deterministic bucket;
+                        # the join's matched-logic already excludes
+                        # them, and anti-join probes must SURVIVE, so
+                        # never drop here
+                        h = jnp.where(v, h, jnp.uint32(0))
+                    hashes.append(h)
+                dest = (
+                    combine_hashes(hashes, jnp) % jnp.uint32(D)
+                ).astype(jnp.int32)
             return env, mask, n, dest, flags
 
         return run
@@ -2010,7 +2149,7 @@ class DagRunner:
                 out_specs=(P("dn"), [P("dn")] * nflags),
             )(arrays)
 
-        return jax.jit(program), comp, b.jinfo()
+        return self._program(program, "count", b), comp, b.jinfo()
 
     def _compile_exchange(
         self, root, exchanged, orientation, hashpos, D, cap,
@@ -2028,8 +2167,10 @@ class DagRunner:
         nflags = _count_inner_joins(root)
 
         def program(arrays, params, snap):
-            def block(blocks):
+            @_staged
+            def block(blocks, st):
                 env, mask, n, dest, flags = routed(blocks, params, snap)
+                st.to("exchange/bucket")
                 dkey = jnp.where(mask, dest, D)
                 order = jnp.argsort(dkey, stable=True)
                 sdkey = jnp.take(dkey, order)
@@ -2044,9 +2185,10 @@ class DagRunner:
                     sd = jnp.take(jnp.broadcast_to(d, (n,)), order)
                     buck = jnp.zeros((D + 1, cap), dtype=sd.dtype)
                     buck = buck.at[sdkey, pos].set(sd)[:D]
-                    out_cols.append(jax.lax.all_to_all(
-                        buck, "dn", split_axis=0, concat_axis=0
-                    ))
+                    with jax.named_scope("all_to_all"):
+                        out_cols.append(jax.lax.all_to_all(
+                            buck, "dn", split_axis=0, concat_axis=0
+                        ))
                     # always exchange a validity plane: keeps the output
                     # pytree static regardless of input nullability
                     vv = (
@@ -2057,9 +2199,10 @@ class DagRunner:
                     sv = jnp.take(vv, order)
                     vb = jnp.zeros((D + 1, cap), dtype=jnp.bool_)
                     vb = vb.at[sdkey, pos].set(sv)[:D]
-                    out_valids.append(jax.lax.all_to_all(
-                        vb, "dn", split_axis=0, concat_axis=0
-                    ))
+                    with jax.named_scope("all_to_all"):
+                        out_valids.append(jax.lax.all_to_all(
+                            vb, "dn", split_axis=0, concat_axis=0
+                        ))
                 cnt = jax.ops.segment_sum(
                     mask.astype(jnp.int32), dest, num_segments=D
                 )
@@ -2085,7 +2228,7 @@ class DagRunner:
                 ),
             )(arrays)
 
-        return jax.jit(program), comp, b.jinfo()
+        return self._program(program, "exchange", b), comp, b.jinfo()
 
     # -- final fragment ----------------------------------------------------
     def _run_final(
@@ -2268,10 +2411,9 @@ class DagRunner:
                     fo=fo,
                 )
 
-            prog, comp, mode, jinfo = self._cached_program(
-                fkey, compile_final
+            prog, comp, mode, jinfo, params = self._bind(
+                fkey, compile_final, dicts_view, subquery_values
             )
-            params = self._resolve(comp, dicts_view, subquery_values)
             if gcapkey is None:
                 gcapkey = (
                     "gcap", skey, orientation, D, sig, versions,
@@ -2281,7 +2423,10 @@ class DagRunner:
                 if gcap_known is not None and gcap_known != gcap:
                     gcap = gcap_known
                     continue  # recompile/lookup at the exact capacity
-            outs = jax.device_get(prog(tuple(arrays), params, snap))
+            outs = self._fetch(
+                self._launch(prog, arrays, params, snap, mode=mode),
+                "result",
+            )
             self.last_mode = mode
             self.last_folded = jinfo[0]
             okf = None
@@ -2303,6 +2448,7 @@ class DagRunner:
                 if flip >= n_dup:
                     # the packed-key range overflowed int64: retry with
                     # per-key sorting (correctness never depended on it)
+                    self._retry("packed group key overflow: packing off")
                     packing = False
                     self._packing[skey] = False
                     continue
@@ -2313,6 +2459,7 @@ class DagRunner:
                 if mode in ("gsort", "gagg") and narrow:
                     # i32 operand range overflowed: retry the wide
                     # program before giving up on ranking entirely
+                    self._retry("i32 operands overflowed: narrow off")
                     self._narrow_off[skey] = True
                     while len(self._narrow_off) > 512:
                         self._narrow_off.pop(
@@ -2323,6 +2470,7 @@ class DagRunner:
                     # negative sum values (or a wrapping global prefix)
                     # broke the cumsum run base: retry with segmented
                     # add scans before giving up on ranking
+                    self._retry("cumsum run base broke: robust on")
                     self._robust_on[skey] = True
                     while len(self._robust_on) > 512:
                         self._robust_on.pop(
@@ -2332,11 +2480,16 @@ class DagRunner:
                 # ranking-key range overflowed int64 (data-dependent, so
                 # keyed by data version): remember and ship unranked
                 # (correct, just a bigger transfer)
+                self._retry("ranking key overflow: topk off")
                 self._topk_off[(skey, tk, versions)] = True
                 while len(self._topk_off) > 512:
                     self._topk_off.pop(next(iter(self._topk_off)))
                 tk = None
                 continue
+            # (the capacity checks below may still re-run the program
+            # at a bigger size: the same formulations, so accepting its
+            # join modes here is already what ran)
+            self._accept(prog)
             if mode in ("gseg", "gsort", "gagg"):
                 self._orientations[skey] = orientation
                 if not complete:
@@ -2353,6 +2506,7 @@ class DagRunner:
             if mode in ("grouped", "grouped_topk"):
                 actual = int(np.asarray(ngroups).max())
                 if actual >= gcap:
+                    self._retry(f"group capacity {gcap} < {actual + 1}")
                     gcap = filt_ops.bucket_size(actual + 1)
                     continue
                 self._cap_store(gcapkey, gcap)
@@ -2373,6 +2527,7 @@ class DagRunner:
             if mode == "rows":
                 actual = int(np.asarray(nrows_full).max())
                 if actual > gcap:  # a device overflowed the row capacity
+                    self._retry(f"row capacity {gcap} < {actual}")
                     gcap = filt_ops.bucket_size(actual)
                     continue
                 self._cap_store(gcapkey, gcap)
@@ -2413,8 +2568,10 @@ class DagRunner:
         mesh = self.fx.mesh
 
         def program(arrays, params, snap):
-            def block(blocks):
+            @_staged
+            def block(blocks, st):
                 env, mask, n, flags = ev(blocks, params, snap)
+                st.to("final/gseg/segreduce")
                 flags = [jnp.reshape(f, (1,)) for f in flags]
                 bidx, benv, bn = b.captured
                 seg = jnp.where(
@@ -2507,6 +2664,7 @@ class DagRunner:
                     for p, _d, _nf in sspecs
                 ]
                 packed, ok = _pack_sort_cols(sortcols, sspecs, gvalid)
+                st.to("final/gseg/topk")
                 idx, sel = _topk_idx(packed, gvalid, k)
 
                 def take(pair):
@@ -2536,7 +2694,7 @@ class DagRunner:
                 ),
             )(arrays)
 
-        return jax.jit(program), comp, "gseg"
+        return self._program(program, "gseg", b), comp, "gseg"
 
     def _compile_gagg(
         self, b, ev, comp, agg, root, topk, D, nflags,
@@ -2582,8 +2740,10 @@ class DagRunner:
         })
 
         def program(arrays, params, snap):
-            def block(blocks):
+            @_staged
+            def block(blocks, st):
                 env, mask, n, flags = ev(blocks, params, snap)
+                st.to("final/gagg/pack")
                 flags = [jnp.reshape(f, (1,)) for f in flags]
                 keys = [_bcast(fn(env, params), n) for fn in gfns]
                 ok = jnp.asarray(True)
@@ -2694,6 +2854,7 @@ class DagRunner:
                 if need_rid:
                     rid_i = len(operands)
                     operands.append(jnp.arange(n, dtype=jnp.int32))
+                st.to("final/gagg/sort")
                 sorted_ops = jax.lax.sort(
                     tuple(operands), num_keys=1, is_stable=False
                 )
@@ -2813,6 +2974,7 @@ class DagRunner:
                     ok = ok & okbit
                 ok = ok & (prod < jnp.float64(2**62))
 
+                st.to("final/gagg/topk")
                 idx, sel = _topk_idx(packed_rank, live_end, k)
                 row_k = (
                     None if rid_i is None
@@ -2863,7 +3025,7 @@ class DagRunner:
                 ),
             )(arrays)
 
-        return jax.jit(program), comp, "gagg"
+        return self._program(program, "gagg", b), comp, "gagg"
 
     # -- windowed grouped aggregation (bigger-than-HBM probes) -----------
     def _wgagg_leaf(self, root, agg, tk):
@@ -2964,15 +3126,15 @@ class DagRunner:
                 "wgagg", skey, orientation, D, sig, fo, cap, width,
                 robust, h is not None,
             )
-            wprog, mprog, comp, jinfo = self._cached_program(
+            wprog, mprog, comp, jinfo, params = self._bind(
                 ckey,
                 lambda rc=root_c, ec=exch_c, oc=ori_c, fc=fo_c, rb=robust:
                 self._compile_wgagg(
                     agg, rc, ec, tk, D, oc, fc, leaf, width, cap,
                     robust=rb,
                 ),
+                dicts_view, subquery_values,
             )
-            params = self._resolve(comp, dicts_view, subquery_values)
             arrays = _collect_arrays(self.fx, root_c, exch_c, D)
             lidx = self.leaf_index_of(root_c, leaf)
             wouts = []
@@ -2982,8 +3144,13 @@ class DagRunner:
                     jnp.int32(w * width),
                 )
                 # device handles only — nothing fetches until merge
-                wouts.append(wprog(tuple(arr_w), params, snap))
-            outs = jax.device_get(mprog(tuple(wouts), params, snap))
+                wouts.append(self._launch(
+                    wprog, arr_w, params, snap, mode=f"window/{w}"
+                ))
+            outs = self._fetch(
+                self._launch(mprog, wouts, params, snap, mode="merge"),
+                "result",
+            )
             (out_keys, out_vals, gvalid, novf, okf, flags) = outs
             gjinfo = (
                 jinfo if gmap is None
@@ -3002,6 +3169,7 @@ class DagRunner:
                 )
                 continue
             if bool(np.asarray(novf).any()):
+                self._retry(f"window partials exceed cap {cap}")
                 cap *= 2  # a window had more groups than the compact cap
                 if cap > width:
                     raise DagUnsupported("wgagg partials exceed window")
@@ -3009,10 +3177,12 @@ class DagRunner:
                 continue
             if not bool(np.asarray(okf).all()):
                 if not robust:
+                    self._retry("cumsum run base broke: robust on")
                     self._robust_on[skey] = True
                     continue
                 self._topk_off[(skey, tk, versions)] = True
                 raise DagUnsupported("wgagg ranking overflow")
+            self._accept(wprog, mprog)
             self._orientations[skey] = orientation
             out_keys = jax.tree.map(lambda x: x[:1], out_keys)
             out_vals = jax.tree.map(lambda x: x[:1], out_vals)
@@ -3085,17 +3255,19 @@ class DagRunner:
             "prep", skey, tuple(orientation), D, fo_local, sig,
             versions,
         )
-        prog, comp, jinfo_local = self._cached_program(
+        prog, comp, jinfo_local, params = self._bind(
             pkey,
             lambda: self._compile_fold_prep(
                 bnode, exchanged, ori_local, fo_local, D, bkey
             ),
+            dicts_view, subquery_values,
         )
-        params = self._resolve(comp, dicts_view, subquery_values)
         arrays = _collect_arrays(self.fx, bnode, exchanged, D)
-        cols, valids, counts, flags = prog(tuple(arrays), params, snap)
-        flags = jax.device_get(flags)  # tiny; build data stays on device
-        flip = _first_true(flags)
+        cols, valids, counts, flags = self._launch(
+            prog, arrays, params, snap
+        )
+        # tiny; build data stays on device
+        flip = _first_true(self._fetch(flags, "join flags"))
         if flip is not None:
             # map the prep-local join index back to the global space
             self._on_flag(
@@ -3105,6 +3277,7 @@ class DagRunner:
                 ),
             )
             return "retry"
+        self._accept(prog)
         schema2 = tuple(bnode.schema) + (
             L.OutCol("__match_ok", t.BOOL),
         )
@@ -3234,7 +3407,7 @@ class DagRunner:
                 ),
             )(arrays)
 
-        return jax.jit(program), comp, b.jinfo()
+        return self._program(program, "fold_prep", b), comp, b.jinfo()
 
     def _compile_wgagg(
         self, agg, root, exchanged, topk, D, orientation, fo, leaf,
@@ -3278,8 +3451,10 @@ class DagRunner:
         ]
 
         def window_program(arrays, params, snap):
-            def block(blocks):
+            @_staged
+            def block(blocks, st):
                 env, mask, n, flags = ev(blocks, params, snap)
+                st.to("final/wgagg/pack")
                 flags = [jnp.reshape(f, (1,)) for f in flags]
                 ok = jnp.asarray(True)
                 kd, kv = _bcast(gfns[kidx](env, params), n)
@@ -3338,6 +3513,7 @@ class DagRunner:
                         operands.append((mask & v).astype(jnp.int8))
                         vi = len(operands) - 1
                     carried_pos.append((ci, vi))
+                st.to("final/wgagg/sort")
                 sorted_ops = jax.lax.sort(
                     tuple(operands), num_keys=1, is_stable=False
                 )
@@ -3449,7 +3625,8 @@ class DagRunner:
         nwcols = 1 + 2 * naggs + 2 * len(dropped) + 1
 
         def merge_program(wouts, params, snap):
-            def block(*wcols_flat):
+            @_staged
+            def block(*wcols_flat, st):
                 # wcols_flat per window: nwcols columns + novf + ok
                 # + flags
                 per = nwcols + 2 + nflags
@@ -3481,6 +3658,7 @@ class DagRunner:
                     live_in, cols[0], DEADS
                 )
                 operands = [key_in] + list(cols[1:-1])
+                st.to("final/wgagg/mergesort")
                 sorted_ops = jax.lax.sort(
                     tuple(operands), num_keys=1, is_stable=False
                 )
@@ -3574,6 +3752,7 @@ class DagRunner:
                     ok = ok & okbit
                 ok = ok & (prod < jnp.float64(2**62))
 
+                st.to("final/wgagg/topk")
                 idx, sel = _topk_idx(packed_rank, live_end, k)
                 salk_k = jnp.take(salk, idx)
                 out_keys = []
@@ -3628,8 +3807,8 @@ class DagRunner:
             )(*flat)
 
         return (
-            jax.jit(window_program),
-            jax.jit(merge_program),
+            self._program(window_program, "wgagg_window", b),
+            self._program(merge_program, "wgagg_merge"),
             comp,
             b.jinfo(),
         )
@@ -3659,6 +3838,9 @@ class DagRunner:
         residual = gs.get("residual")
         left_fn = b.build(join.left, exchanged, D)
         right_fn = b.build(join.right, exchanged, D)
+        # the top join is this program's co-sort: a sort-merge
+        b.modes.add("merge")
+        jtag = f"join{b.njoin}/merge"  # children numbered first
         ldids = [c.dict_id for c in join.left.schema]
         rdids = [c.dict_id for c in join.right.schema]
         lkfn = comp.compile(join.left_keys[0], ldids)
@@ -3696,10 +3878,12 @@ class DagRunner:
         mesh = self.fx.mesh
 
         def program(arrays, params, snap):
-            def block(blocks):
+            @_staged
+            def block(blocks, st):
                 lenv, lmask, ln, lflags = left_fn(blocks, params, snap)
                 renv, rmask, rn, rflags = right_fn(blocks, params, snap)
                 flags = lflags + rflags
+                st.to(jtag + "/build")
                 lk = _bcast(lkfn(lenv, params), ln)
                 rk = _bcast(rkfn(renv, params), rn)
                 if build_right:
@@ -3891,9 +4075,11 @@ class DagRunner:
                     jnp.zeros(pn, jnp.int64),
                 ]))
 
+                st.to(jtag + "/sort")
                 sorted_ops = jax.lax.sort(
                     tuple(operands), num_keys=1, is_stable=False
                 )
+                st.to(jtag + "/prefix")
                 salk = sorted_ops[0]
                 # dead-row sentinel matches the key dtype (narrow keys
                 # compare in i32 — an i64 BIGK would never exclude them)
@@ -4090,7 +4276,9 @@ class DagRunner:
                     ok = ok & okbit
                 ok = ok & (prod < jnp.float64(2**62))
 
+                st.to("final/gsort/topk")
                 idx, sel = _topk_idx(packed, live, k)
+                st.to("final/gsort/gather")
                 brow_k = (
                     jnp.take(ssb, idx) % jnp.int64(max(bn, 1))
                 ).astype(jnp.int32)
@@ -4140,7 +4328,7 @@ class DagRunner:
                 ),
             )(arrays)
 
-        return jax.jit(program), comp, "gsort"
+        return self._program(program, "gsort", b), comp, "gsort"
 
     def _compile_final(
         self, frag, agg, root, exchanged, orientation, gcap, D,
@@ -4196,8 +4384,10 @@ class DagRunner:
             )
 
             def program(arrays, params, snap):
-                def block(blocks):
+                @_staged
+                def block(blocks, st):
                     env, mask, n, flags = ev(blocks, params, snap)
+                    st.to("final/grouped/reduce")
                     flags = [jnp.reshape(f, (1,)) for f in flags]
                     keys = [_bcast(fn(env, params), n) for fn in gfns]
                     vals = [
@@ -4234,6 +4424,7 @@ class DagRunner:
                         packed, ok = _pack_sort_cols(
                             sortcols, sspecs, gvalid
                         )
+                        st.to("final/grouped/topk")
                         idx, sel = _topk_idx(packed, gvalid, kk)
 
                         def take(pair):
@@ -4287,7 +4478,7 @@ class DagRunner:
                     out_specs=out_specs,
                 )(arrays)
 
-            return jax.jit(program), comp, mode, b.jinfo()
+            return self._program(program, mode, b), comp, mode, b.jinfo()
 
         # no aggregate: compact surviving rows on DEVICE to a static
         # per-device capacity before shipping — never transfer the padded
@@ -4302,8 +4493,10 @@ class DagRunner:
             kk, sspecs, _m = topk
 
             def program(arrays, params, snap):
-                def block(blocks):
+                @_staged
+                def block(blocks, st):
                     env, mask, n, flags = ev(blocks, params, snap)
+                    st.to("final/rows/pack")
                     cols = []
                     valids = []
                     for i in range(ncols):
@@ -4319,6 +4512,7 @@ class DagRunner:
                         (cols[p], valids[p]) for p, _d, _nf in sspecs
                     ]
                     packed, ok = _pack_sort_cols(sortcols, sspecs, mask)
+                    st.to("final/rows/topk")
                     idx, sel = _topk_idx(packed, mask, kk)
                     return (
                         [jnp.take(d, idx)[None] for d in cols],
@@ -4342,15 +4536,17 @@ class DagRunner:
                 )(arrays)
 
             return (
-                jax.jit(program), comp, "rows_topk",
+                self._program(program, "rows_topk", b), comp, "rows_topk",
                 b.jinfo(),
             )
 
         rowcap = gcap  # reused capacity slot for rows mode
 
         def program(arrays, params, snap):
-            def block(blocks):
+            @_staged
+            def block(blocks, st):
                 env, mask, n, flags = ev(blocks, params, snap)
+                st.to("final/rows/compact")
                 order = jnp.argsort(~mask, stable=True)[:rowcap]
                 cnt = jnp.minimum(
                     jnp.sum(mask, dtype=jnp.int32), rowcap
@@ -4386,7 +4582,7 @@ class DagRunner:
                 ),
             )(arrays)
 
-        return jax.jit(program), comp, "rows", b.jinfo()
+        return self._program(program, "rows", b), comp, "rows", b.jinfo()
 
     # -- output collection -------------------------------------------------
     def _apply_proj(self, batch, agg, out_proj):
@@ -4404,6 +4600,7 @@ class DagRunner:
     def _dic(self, oc):
         return self.fx.catalog.dictionary(oc.dict_id) if oc.dict_id else None
 
+    @_collecting
     def _collect_grouped(self, agg, out_keys, out_vals, gvalid):
         gv = np.asarray(gvalid).reshape(-1)
         keep = np.nonzero(gv)[0]
@@ -4421,6 +4618,7 @@ class DagRunner:
             cols[oc.name] = Column(oc.type, dd, vv, self._dic(oc))
         return ColumnBatch(cols, len(keep))
 
+    @_collecting
     def _collect_scalar(self, agg, out_vals):
         cols: dict[str, Column] = {}
         n = 0
@@ -4433,6 +4631,7 @@ class DagRunner:
             n = len(dd)
         return ColumnBatch(cols, n)
 
+    @_collecting
     def _collect_rows_live(self, schema, cols, valids, live):
         """Device top-k rows: [D, k] planes with a per-lane live mask
         (union of per-device top-k's; the coordinator re-sorts/limits)."""
@@ -4447,6 +4646,7 @@ class DagRunner:
             out[oc.name] = Column(oc.type, d, v, self._dic(oc))
         return ColumnBatch(out, len(keep))
 
+    @_collecting
     def _collect_rows(self, schema, cols, valids, cnt):
         """Device-compacted rows: per device, the first cnt[d] lanes of
         each [D, cap] column are live."""
@@ -4600,21 +4800,23 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, presorted=False):
         sk = bkey
         sidx = jnp.arange(nb, dtype=jnp.int32)
     else:
-        sk, sidx = jax.lax.sort(
-            (bkey, jnp.arange(nb, dtype=jnp.int32)), num_keys=1,
-            is_stable=False,
-        )
+        with jax.named_scope("build"):
+            sk, sidx = jax.lax.sort(
+                (bkey, jnp.arange(nb, dtype=jnp.int32)), num_keys=1,
+                is_stable=False,
+            )
     cnt = jnp.sum(breal, dtype=jnp.int32)
     iota = jnp.arange(nb, dtype=jnp.int64)
     base = sk[0]
     dense = jnp.all(
         jnp.where(iota < cnt, sk == base + iota, True)
     )
-    slot = pd.astype(jnp.int64) - base
-    inr = (slot >= 0) & (slot < cnt.astype(jnp.int64))
-    sloti = jnp.clip(slot, 0, max(nb - 1, 0)).astype(jnp.int32)
-    bidx = jnp.take(sidx, sloti)
-    matched = inr & preal & jnp.take(bfull, bidx)
+    with jax.named_scope("probe"):
+        slot = pd.astype(jnp.int64) - base
+        inr = (slot >= 0) & (slot < cnt.astype(jnp.int64))
+        sloti = jnp.clip(slot, 0, max(nb - 1, 0)).astype(jnp.int32)
+        bidx = jnp.take(sidx, sloti)
+        matched = inr & preal & jnp.take(bfull, bidx)
     return matched, bidx, ~dense
 
 
@@ -4650,9 +4852,10 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool):
         # can address both sides with one operand
         jnp.arange(nb, nb + npr, dtype=jnp.int32),
     ])
-    skey, sside, sokey = jax.lax.sort(
-        (key, side, okey), num_keys=2, is_stable=False
-    )
+    with jax.named_scope("sort"):
+        skey, sside, sokey = jax.lax.sort(
+            (key, side, okey), num_keys=2, is_stable=False
+        )
     M = nb + npr
     boundary = jnp.concatenate([
         jnp.ones(1, jnp.bool_), skey[1:] != skey[:-1]
@@ -4662,20 +4865,22 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool):
         dup = jnp.any(isb[1:] & isb[:-1] & ~boundary[1:])
     else:
         dup = jnp.asarray(False)
-    runid = jnp.cumsum(boundary.astype(jnp.int32))
-    iota = jnp.arange(M, dtype=jnp.int32)
-    pbpos = jax.lax.cummax(jnp.where(isb, iota, jnp.int32(-1)))
-    pbrun = jax.lax.cummax(jnp.where(isb, runid, jnp.int32(-1)))
-    isp = sside == 1
-    matched_s = (pbrun == runid) & isp
-    bidx_s = jnp.take(sokey, jnp.maximum(pbpos, 0))
+    with jax.named_scope("prefix"):
+        runid = jnp.cumsum(boundary.astype(jnp.int32))
+        iota = jnp.arange(M, dtype=jnp.int32)
+        pbpos = jax.lax.cummax(jnp.where(isb, iota, jnp.int32(-1)))
+        pbrun = jax.lax.cummax(jnp.where(isb, runid, jnp.int32(-1)))
+        isp = sside == 1
+        matched_s = (pbrun == runid) & isp
+        bidx_s = jnp.take(sokey, jnp.maximum(pbpos, 0))
     # restore probe-row order: probe original positions are unique keys;
     # dead probe rows restore too (they must land back in place)
     rkey = jnp.where(sokey >= nb, sokey - nb, jnp.int32(2**31 - 1))
-    _rk, m_p, b_p = jax.lax.sort(
-        (rkey, matched_s.astype(jnp.int8), bidx_s),
-        num_keys=1, is_stable=False,
-    )
+    with jax.named_scope("restore"):
+        _rk, m_p, b_p = jax.lax.sort(
+            (rkey, matched_s.astype(jnp.int8), bidx_s),
+            num_keys=1, is_stable=False,
+        )
     matched = (m_p[:npr] > 0) & pmask
     bidx = jnp.clip(b_p[:npr], 0, max(nb - 1, 0))
     return matched, bidx, dup

@@ -138,17 +138,35 @@ def encode_frame(obj: dict) -> bytes:
 
 
 def send_frame(sock: socket.socket, obj: dict) -> None:
+    send_encoded(sock, encode_frame(obj))
+
+
+def send_encoded(sock: socket.socket, data: bytes) -> None:
+    """Send an ``encode_frame`` result (the coordinator front end times
+    serialization and the send apart)."""
     # failpoint at the shared frame-send boundary: EVERY JSON-wire
     # peer (sessions, DN channels, GTM, log shipping) crosses it
     FAULT("net/protocol/send")
-    sock.sendall(encode_frame(obj))
+    sock.sendall(data)
 
 
 def recv_frame(sock: socket.socket) -> dict | None:
+    length = recv_frame_header(sock)
+    if length is None:
+        return None
+    return recv_frame_body(sock, length)
+
+
+def recv_frame_header(sock: socket.socket) -> int | None:
+    """The frame's length header alone: a server blocks HERE between
+    requests, so the wait for the next one stays outside its spans."""
     head = _recv_exact(sock, 4)
     if head is None:
         return None
-    (length,) = struct.unpack("<I", head)
+    return struct.unpack("<I", head)[0]
+
+
+def recv_frame_body(sock: socket.socket, length: int) -> dict | None:
     body = _recv_exact(sock, length)
     if body is None:
         return None

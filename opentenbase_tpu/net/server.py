@@ -22,10 +22,16 @@ from typing import Optional
 
 from opentenbase_tpu.fault import FAULT, FaultDropConnection
 from opentenbase_tpu.net.protocol import (
+    encode_frame,
     recv_frame,
+    recv_frame_body,
+    recv_frame_header,
+    send_encoded,
     send_frame,
     shutdown_and_close,
 )
+from opentenbase_tpu.obs import tracectx as _tctx
+from opentenbase_tpu.obs.trace import span as _span
 
 
 def _walk_ast(node):
@@ -196,144 +202,17 @@ class ClusterServer:
         authed = not self.cluster.users
         try:
             while not self._stop.is_set():
-                msg = recv_frame(conn)
-                if msg is None:
+                # the wait for the next request ends with its length
+                # header: ``wire.request`` runs from here to the
+                # reply's send returning (obs/trace.span)
+                length = recv_frame_header(conn)
+                if length is None:
                     break
-                if msg.get("op") == "close":
-                    send_frame(conn, {"ok": True})
-                    break
-                if msg.get("op") == "ping":
-                    # liveness probe (ha.py failure detector): answered
-                    # before auth — a heartbeat must not need
-                    # credentials — and carries the fencing generation
-                    # + live role so a probe doubles as a health row
-                    c = self.cluster
-                    if getattr(c, "ha_demoted", False):
-                        role = "fenced"
-                    elif c.read_only:
-                        # a streaming peer coordinator (coord/peer.py)
-                        # is read_only like a hot standby but serves a
-                        # different contract (local reads + forwarded
-                        # writes) — the probe must say which it is
-                        role = (
-                            getattr(c, "coordinator_role", "")
-                            or "standby"
-                        )
-                        if role == "coordinator":
-                            role = "standby"
-                    else:
-                        role = "coordinator"
-                    rec = getattr(c, "catalog_receiver", None)
-                    # serving lease (ha.ServingLease): validity rides
-                    # the probe so pg_cluster_health peer rows show a
-                    # self-demoted CN without extra protocol
-                    lease = getattr(c, "serving_lease", None)
-                    lease_ms = (
-                        lease.remaining_ms() if lease is not None else -1
-                    )
-                    send_frame(conn, {
-                        "ok": True,
-                        "role": role,
-                        "generation": int(
-                            getattr(c, "node_generation", 0)
-                        ),
-                        "lease_valid": (
-                            lease is None or lease_ms > 0
-                        ),
-                        "lease_remaining_ms": lease_ms,
-                        # multi-CN health surface: the probed node's
-                        # catalog epoch + stream-applied offset let the
-                        # primary render per-coordinator rows (and lag)
-                        # from one probe, no extra protocol
-                        "catalog_epoch": int(c.catalog_epoch),
-                        "applied": int(
-                            rec.applied if rec is not None
-                            else (
-                                c.persistence.wal.position
-                                if c.persistence else 0
-                            )
-                        ),
-                    })
-                    continue
-                if msg.get("op") == "auth":
-                    authed = self._scram_exchange(conn, msg)
-                    if authed:
-                        # the proven identity drives role-based WLM
-                        # bindings and audit attribution
-                        session.user = str(msg.get("user", session.user))
-                    continue
-                if not authed:
-                    send_frame(
-                        conn,
-                        {"error": "AuthError: authentication required"},
-                    )
-                    continue
-                sql = msg.get("q")
-                if sql is None:
-                    send_frame(conn, {"error": "malformed request"})
-                    continue
-                # cross-node tracing: a ``_trace`` header from the
-                # client binds for the statement (obs/tracectx.py), so
-                # work this server fans out parents to the caller's span
-                from opentenbase_tpu.obs import tracectx as _tctx
-
-                _hdr = msg.get("_trace")
-                _prev_ctx = (
-                    _tctx.bind(_tctx.from_header(_hdr))
-                    if _hdr else None
+                more, authed = self._serve_request(
+                    conn, session, length, authed
                 )
-                try:
-                    # failpoint: statement dispatch. drop_conn tears the
-                    # backend down mid-protocol (client sees a vanished
-                    # server); error surfaces as an 'E' frame like any
-                    # engine error
-                    FAULT("net/server/dispatch")
-                    # read-only statements share the data plane (MVCC
-                    # snapshots isolate them from each other); writes,
-                    # DDL, and anything uncertain take it exclusively —
-                    # the statement-level analog of the reference's
-                    # lock-free MVCC readers
-                    kind, wt = self._classify(sql, session)
-                    if kind == "read":
-                        with self._exec_lock.read():
-                            res = session.execute(sql)
-                    elif kind == "write":
-                        # plain autocommit DML: writers on DISJOINT
-                        # tables share the data plane (per-table
-                        # mutexes serialize same-table writers); DDL
-                        # and explicit transactions stay exclusive
-                        with self._exec_lock.write_tables(wt):
-                            res = session.execute(sql)
-                    else:
-                        with self._exec_lock:
-                            res = session.execute(sql)
-                    send_frame(
-                        conn,
-                        {
-                            "tag": res.command,
-                            "columns": res.columns,
-                            "rows": [list(r) for r in res.rows],
-                            "rowcount": res.rowcount,
-                            # WAL end after the statement: the causal
-                            # token a forwarding peer CN waits on so a
-                            # read after its own (forwarded) write is
-                            # never stale (read-your-writes across CNs)
-                            "wal_pos": int(
-                                self.cluster.persistence.wal.position
-                            ) if self.cluster.persistence else 0,
-                        },
-                    )
-                except FaultDropConnection:
-                    raise  # sever this backend like a real peer reset
-                except Exception as e:  # otb_lint: ignore[except-swallow] -- not a swallow: the error is delivered to the client as an error frame below, and Session.execute already elog'd it at level error
-                    frame = {"error": f"{type(e).__name__}: {e}"}
-                    sqlstate = getattr(e, "sqlstate", None)
-                    if sqlstate:  # 53xxx sheds, 57014 timeouts, ...
-                        frame["sqlstate"] = sqlstate
-                    send_frame(conn, frame)
-                finally:
-                    if _hdr:
-                        _tctx.bind(_prev_ctx)
+                if not more:
+                    break
         except OSError:
             # the socket died under us — client vanished mid-frame, or
             # stop() force-disconnected this backend while a statement
@@ -344,6 +223,207 @@ class ClusterServer:
             # (the backend-exit cleanup of the reference's tcop loop)
             self._conns.discard(raw)
             self._conn_cleanup(session, conn)
+
+    def _ping_reply(self) -> dict:
+        """Liveness probe (ha.py failure detector): answered before
+        auth — a heartbeat must not need credentials — and carries the
+        fencing generation + live role so a probe doubles as a health
+        row."""
+        c = self.cluster
+        if getattr(c, "ha_demoted", False):
+            role = "fenced"
+        elif c.read_only:
+            # a streaming peer coordinator (coord/peer.py)
+            # is read_only like a hot standby but serves a
+            # different contract (local reads + forwarded
+            # writes) — the probe must say which it is
+            role = (
+                getattr(c, "coordinator_role", "")
+                or "standby"
+            )
+            if role == "coordinator":
+                role = "standby"
+        else:
+            role = "coordinator"
+        rec = getattr(c, "catalog_receiver", None)
+        # serving lease (ha.ServingLease): validity rides
+        # the probe so pg_cluster_health peer rows show a
+        # self-demoted CN without extra protocol
+        lease = getattr(c, "serving_lease", None)
+        lease_ms = (
+            lease.remaining_ms() if lease is not None else -1
+        )
+        return {
+            "ok": True,
+            "role": role,
+            "generation": int(
+                getattr(c, "node_generation", 0)
+            ),
+            "lease_valid": (
+                lease is None or lease_ms > 0
+            ),
+            "lease_remaining_ms": lease_ms,
+            # multi-CN health surface: the probed node's
+            # catalog epoch + stream-applied offset let the
+            # primary render per-coordinator rows (and lag)
+            # from one probe, no extra protocol
+            "catalog_epoch": int(c.catalog_epoch),
+            "applied": int(
+                rec.applied if rec is not None
+                else (
+                    c.persistence.wal.position
+                    if c.persistence else 0
+                )
+            ),
+        }
+
+    def _serve_request(self, conn, session, length: int, authed: bool):
+        """One request, from its length header (already read) to the
+        reply's send returning: the ``wire.request`` span. Returns
+        (keep serving, authed). With ``trace_queries`` on the server
+        owns the statement's QueryTrace — ``wire.request`` is its root
+        and ``Session.execute`` adopts it (``query`` nests inside)."""
+        trace = None
+        if session.gucs.get("trace_queries") and session._trace is None:
+            trace = self.cluster.tracer.start("", session.session_id)
+            session._trace = trace
+        req = _span(
+            session, "wire.request", cat="wire", record=False,
+            span_id=None if trace is None else trace.ctx.span_id,
+            bytes_in=length + 4,
+        )
+        exec_ms = None  # set by a statement: the others publish nothing
+        req.__enter__()
+        try:
+            # failpoint: a request torn between its header and its body
+            FAULT("net/server/request")
+            with _span(session, "wire.decode", cat="wire"):
+                msg = recv_frame_body(conn, length)
+            if msg is None:
+                return False, authed
+            op = msg.get("op")
+            if op == "close":
+                send_frame(conn, {"ok": True})
+                return False, authed
+            if op == "ping":
+                send_frame(conn, self._ping_reply())
+            elif op == "auth":
+                authed = self._scram_exchange(conn, msg)
+                if authed:
+                    # the proven identity drives role-based WLM
+                    # bindings and audit attribution
+                    session.user = str(msg.get("user", session.user))
+            elif not authed:
+                send_frame(
+                    conn,
+                    {"error": "AuthError: authentication required"},
+                )
+            elif msg.get("q") is None:
+                send_frame(conn, {"error": "malformed request"})
+            else:
+                if trace is not None:
+                    trace.query = msg["q"].strip()
+                exec_ms = self._serve_statement(conn, session, msg, req)
+            return True, authed
+        finally:
+            req.__exit__(None, None, None)
+            if trace is not None:
+                session._trace = None
+            if exec_ms is not None:
+                # what the request took beyond Session.execute: the
+                # statement's ledger closed before the reply was sent,
+                # so the wire's share lives in pg_stat_query_phases
+                self.cluster.metrics.histogram("phase.wire").record(
+                    max(req.ms - exec_ms, 0.0)
+                )
+                if trace is not None:
+                    self.cluster.tracer.finish(
+                        trace, root="wire.request", **req.args()
+                    )
+
+    def _serve_statement(self, conn, session, msg: dict, req) -> float:
+        """Execute ``msg["q"]`` and send its reply, each leg a span:
+        ``wire.lock_wait`` (classify + acquire the statement lock),
+        the session's own ``query``, ``wire.encode``, ``wire.send``.
+        Returns the milliseconds ``Session.execute`` took."""
+        import contextlib
+        import time
+
+        sql = msg["q"]
+        # cross-node tracing: a ``_trace`` header from the client binds
+        # for the statement (obs/tracectx.py), so work this server fans
+        # out parents to the caller's span; a trace this server owns
+        # binds over it, as Session.execute does for its own
+        _hdr = msg.get("_trace")
+        own = req._trace
+        bound = own is not None or bool(_hdr)
+        _prev_ctx = None
+        if bound:
+            _prev_ctx = _tctx.bind(
+                own.ctx if own is not None else _tctx.from_header(_hdr)
+            )
+        exec_ms = 0.0
+        try:
+            # failpoint: statement dispatch. drop_conn tears the
+            # backend down mid-protocol (client sees a vanished
+            # server); error surfaces as an 'E' frame like any
+            # engine error
+            FAULT("net/server/dispatch")
+            with contextlib.ExitStack() as held:
+                # read-only statements share the data plane (MVCC
+                # snapshots isolate them from each other); writes,
+                # DDL, and anything uncertain take it exclusively —
+                # the statement-level analog of the reference's
+                # lock-free MVCC readers
+                with _span(session, "wire.lock_wait", cat="wire") as lsp:
+                    kind, wt = self._classify(sql, session)
+                    lsp.set(kind=kind)
+                    if kind == "read":
+                        guard = self._exec_lock.read()
+                    elif kind == "write":
+                        # plain autocommit DML: writers on DISJOINT
+                        # tables share the data plane (per-table
+                        # mutexes serialize same-table writers); DDL
+                        # and explicit transactions stay exclusive
+                        guard = self._exec_lock.write_tables(wt)
+                    else:
+                        guard = self._exec_lock
+                    held.enter_context(guard)
+                t0 = time.perf_counter()
+                try:
+                    res = session.execute(sql)
+                finally:
+                    exec_ms = (time.perf_counter() - t0) * 1000.0
+            reply = {
+                "tag": res.command,
+                "columns": res.columns,
+                "rows": [list(r) for r in res.rows],
+                "rowcount": res.rowcount,
+                # WAL end after the statement: the causal
+                # token a forwarding peer CN waits on so a
+                # read after its own (forwarded) write is
+                # never stale (read-your-writes across CNs)
+                "wal_pos": int(
+                    self.cluster.persistence.wal.position
+                ) if self.cluster.persistence else 0,
+            }
+            req.set(rows=len(res.rows))
+        except FaultDropConnection:
+            raise  # sever this backend like a real peer reset
+        except Exception as e:  # otb_lint: ignore[except-swallow] -- not a swallow: the error is delivered to the client as an error frame below, and Session.execute already elog'd it at level error
+            reply = {"error": f"{type(e).__name__}: {e}"}
+            sqlstate = getattr(e, "sqlstate", None)
+            if sqlstate:  # 53xxx sheds, 57014 timeouts, ...
+                reply["sqlstate"] = sqlstate
+        finally:
+            if bound:
+                _tctx.bind(_prev_ctx)
+        with _span(session, "wire.encode", cat="wire"):
+            data = encode_frame(reply)
+        req.set(bytes_out=len(data))
+        with _span(session, "wire.send", cat="wire"):
+            send_encoded(conn, data)
+        return exec_ms
 
     def _classify(self, sql: str, session, stmts=None):
         """ONE parse classifying the statement's lock class (callers

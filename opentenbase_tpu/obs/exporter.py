@@ -26,6 +26,7 @@ import time
 from typing import Callable, Optional
 
 from opentenbase_tpu.net.protocol import shutdown_and_close
+from opentenbase_tpu.obs import statements as _stmtobs
 
 
 def _esc(v) -> str:
@@ -591,6 +592,17 @@ def render_cluster_metrics(cluster) -> str:
                     "otb_stmt_device_ms", {"queryid": str(e.queryid)},
                     round(float(e.device_ms), 3),
                 ))
+            # the fused path's split of device_ms and its counts, one
+            # series a ledger field (obs/statements.py)
+            for f in (_stmtobs.DEVICE_SPLIT_FIELDS + ("merge_ms",)
+                      + _stmtobs.FUSED_COUNT_FIELDS):
+                _head(out, f"otb_stmt_{f}", "counter",
+                      f"Fused-path {f} per query fingerprint")
+                for e in top:
+                    out.append(_line(
+                        f"otb_stmt_{f}", {"queryid": str(e.queryid)},
+                        round(float(getattr(e, f)), 3),
+                    ))
             _head(out, "otb_stmt_transfer_bytes", "counter",
                   "h2d+d2h transfer bytes per query fingerprint")
             for e in top:

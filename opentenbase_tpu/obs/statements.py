@@ -67,7 +67,31 @@ LEDGER_FIELDS = (
     "wal_flushes",
     "gts_rpcs",
     "gts_ms",
+    # the fused path's decomposition of device_ms (obs/trace.span
+    # sites in engine._try_fused_inner, executor/fused.py and
+    # fused_dag.py): waiting for the fused gate, the device cache,
+    # binding literals to a program, enqueueing it (compile time
+    # excluded — that is compile_ms), host waits on the device, and
+    # decoding the fetched arrays; merge_ms is the coordinator merge
+    # (part of host_ms). The three counts are per statement.
+    "gate_ms",
+    "cache_ms",
+    "bind_ms",
+    "launch_ms",
+    "device_wait_ms",
+    "collect_ms",
+    "merge_ms",
+    "device_launches",
+    "device_syncs",
+    "fused_retries",
 )
+
+#: the six parts of device_ms (what is left is the fused span's own)
+DEVICE_SPLIT_FIELDS = (
+    "gate_ms", "cache_ms", "bind_ms", "launch_ms", "device_wait_ms",
+    "collect_ms",
+)
+FUSED_COUNT_FIELDS = ("device_launches", "device_syncs", "fused_retries")
 
 
 class ResourceLedger:
@@ -447,6 +471,16 @@ def resource_footer(ledger: ResourceLedger, total_ms: float) -> list[str]:
          f" gts_rpcs={int(ledger.gts_rpcs)}"
          f" gts={float(ledger.gts_ms):.3f} ms"),
     ]
+    if ledger.device_launches:
+        lines.append(
+            "  fused: " + " ".join(
+                f"{f[:-3]}={float(getattr(ledger, f)):.3f} ms"
+                for f in DEVICE_SPLIT_FIELDS + ("merge_ms",)
+            ) + "".join(
+                f" {f}={int(getattr(ledger, f))}"
+                for f in FUSED_COUNT_FIELDS
+            )
+        )
     if ledger.wait_ms:
         waits = " ".join(
             f"{k}={v:.3f} ms" for k, v in sorted(ledger.wait_ms.items())

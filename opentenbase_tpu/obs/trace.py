@@ -14,6 +14,15 @@ allocations on the untraced hot path (``Span.allocations`` is the test
 hook proving it).  EXPLAIN ANALYZE force-starts a trace for its one
 statement regardless of the GUC.
 
+``span`` is the ONE producer helper every timed site uses (the wire
+server, the session's phases, the fused path): a ``perf_counter`` pair
+feeding the statement's resource ledger, a ``jax.profiler``
+``TraceAnnotation`` named ``otb:<name>`` (recorded only while a
+profiler session runs, so the span sits on the device trace's own
+clock in the ``.xplane.pb``), and — only when the session is tracing —
+a ``QueryTrace`` record parented to the enclosing span.
+``obs/profile.py`` reduces the profiler's side.
+
 ``compile_window`` attributes XLA compilation time to the query that
 paid it: jax emits ``/jax/core/compile/*_duration`` monitoring events
 synchronously on the compiling thread, and the window accumulates them
@@ -28,6 +37,9 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+from opentenbase_tpu.obs import statements as _stmtobs
+from opentenbase_tpu.obs.tracectx import TraceContext, new_span_id
 
 
 class Span:
@@ -70,15 +82,13 @@ class QueryTrace:
     )
 
     def __init__(self, qid: int, query: str, session_id: int = 0):
-        from opentenbase_tpu.obs import tracectx as _tctx
-
         self.qid = qid
         self.query = query
         self.session_id = session_id
         self.started_s = time.perf_counter()
         # cross-node identity (obs/tracectx.py): the wire header minted
         # once per traced statement; ctx.span_id is the root span's id
-        self.ctx = _tctx.TraceContext.new()
+        self.ctx = TraceContext.new()
         # epoch offset: spans record on the perf_counter clock, remote
         # rings on the epoch clock — the export shifts CN spans by this
         # so one merged timeline needs no cross-process negotiation
@@ -126,13 +136,20 @@ class Tracer:
     def start(self, query: str, session_id: int = 0) -> QueryTrace:
         return QueryTrace(next(self._qids), query, session_id)
 
-    def finish(self, trace: QueryTrace) -> None:
-        """Close the root span and publish the trace into the ring."""
+    def finish(
+        self, trace: QueryTrace, root: str = "query", **args,
+    ) -> None:
+        """Close the root span and publish the trace into the ring.
+        The root is ``query`` for a session-owned trace and
+        ``wire.request`` when the wire server owns it (``query`` then
+        nests inside as an ordinary span)."""
         trace.finished_s = time.perf_counter()
+        args = {k: v for k, v in args.items() if v is not None}
+        args["query"] = trace.query[:200]
         root = Span(
-            "query", "query", trace.started_s * 1e6,
+            root, root.split(".")[0], trace.started_s * 1e6,
             (trace.finished_s - trace.started_s) * 1e6,
-            threading.get_ident(), {"query": trace.query[:200]},
+            threading.get_ident(), args,
             span_id=trace.ctx.span_id,
         )
         with trace._mu:
@@ -150,6 +167,205 @@ class Tracer:
     def __len__(self) -> int:
         with self._mu:
             return len(self._ring)
+
+
+# ---------------------------------------------------------------------------
+# the span helper: one timed site, three sinks
+# ---------------------------------------------------------------------------
+
+_span_tls = threading.local()
+_annotation = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    importing obs/ never imports JAX (host-side roles, the JAX-free
+    client)."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class span:
+    """``with span(session, name, ledger_field, count_field, **args)``.
+
+    - always: one ``perf_counter`` pair (``.ms`` after exit); with a
+      statement ledger active (obs/statements.current) the span's OWN
+      milliseconds — less what enclosed spans billed to fields of
+      their own, less what ``exclude`` took out — add to
+      ``ledger_field``, and 1 to ``count_field``: the fields partition
+      the time, they never count a millisecond twice;
+    - whenever a profiler session runs (``TraceMe``'s own test, no
+      switch of ours): a ``TraceAnnotation("otb:" + name, **args)``,
+      the span on the device trace's clock;
+      ``trace_id``/``span_id``/``parent_id`` ride it only when the
+      session is tracing;
+    - only when the session is tracing (``session._trace``): a
+      ``QueryTrace.record`` with its own ``span_id`` under the
+      enclosing span of the same trace (else the trace's root).
+
+    ``session`` None inherits the enclosing open span's trace — the
+    fused path's sites have no session in reach. ``set(**args)`` adds
+    args found out inside the span to both sinks; ``listening`` says
+    whether either sink will read them (a site with args that cost
+    something to compute asks first). ``record=False`` keeps a span out
+    of the QueryTrace (the session-owned ``query`` root, which
+    ``Tracer.finish`` builds) while children still parent to its
+    ``span_id``. With nothing listening a span is one small object, a
+    clock pair and a ledger add; no ``Span`` is built when tracing is
+    off."""
+
+    # per-instance state is set only where it differs from these (a
+    # span is made fifteen to twenty times a statement: this is the
+    # helper's whole cost when nothing is listening)
+    cat = "span"
+    ms = 0.0
+    span_id = None
+    listening = False
+    _record = True
+    _trace = None
+    _ann = None
+    _late = None
+    _parent = None
+    _billed = 0.0  # ms inside this span billed elsewhere
+
+    def __init__(
+        self, session, name: str, ledger_field: Optional[str] = None,
+        count_field: Optional[str] = None, cat: Optional[str] = None,
+        span_id: Optional[str] = None, record: bool = True, **args,
+    ):
+        self.name = name
+        self._session = session
+        self._field = ledger_field
+        self._count = count_field
+        self._args = args
+        if cat is not None:
+            self.cat = cat
+        if span_id is not None:
+            self.span_id = span_id
+        if not record:
+            self._record = False
+
+    def set(self, **args) -> None:
+        if self._late is None:
+            self._late = args
+        else:
+            self._late.update(args)
+
+    def exclude(self, ms: float) -> None:
+        """Keep ``ms`` of this span out of its ledger field (a launch's
+        compile time is ``compile_ms``'s, not ``launch_ms``'s)."""
+        self._billed += ms
+
+    def args(self) -> dict:
+        """Every arg given so far; None-valued ones are elided (the
+        elog contract: sites pass conditionals unconditionally)."""
+        args = self._args
+        if self._late:
+            args = {**args, **self._late}
+        if None in args.values():
+            args = {k: v for k, v in args.items() if v is not None}
+        return args
+
+    def __enter__(self) -> "span":
+        stack = getattr(_span_tls, "stack", None)
+        if stack is None:
+            stack = _span_tls.stack = []
+        self._stack = stack
+        session = self._session
+        if session is not None:
+            trace = session._trace
+        else:
+            trace = stack[-1]._trace if stack else None
+        annotation = _annotation or _trace_annotation()
+        if trace is not None:
+            self._trace = trace
+            self.listening = True
+            if self.span_id is None:
+                self.span_id = new_span_id()
+            for outer in reversed(stack):
+                if outer._trace is trace:
+                    self._parent = outer.span_id
+                    break
+            if annotation.is_enabled():
+                self._ann = ann = annotation(
+                    "otb:" + self.name, trace_id=trace.trace_id,
+                    span_id=self.span_id,
+                    parent_id=self._parent or trace.ctx.span_id,
+                    **self.args(),
+                )
+                ann.__enter__()
+        elif annotation.is_enabled():
+            self.listening = True
+            args = self._args
+            if None in args.values():
+                args = self.args()
+            self._ann = ann = annotation("otb:" + self.name, **args)
+            ann.__enter__()
+        stack.append(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        stack = self._stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:
+            stack.remove(self)
+        self.ms = ms = (t1 - self._t0) * 1000.0
+        field = self._field
+        if field is not None:
+            # the enclosing field-bearing span must not bill this again
+            for outer in reversed(stack):
+                if outer._field is not None:
+                    outer._billed += ms
+                    break
+        if field is not None or self._count is not None:
+            led = _stmtobs.current()
+            if led is not None:
+                if field is not None:
+                    setattr(
+                        led, field,
+                        getattr(led, field) + max(ms - self._billed, 0.0),
+                    )
+                if self._count is not None:
+                    setattr(
+                        led, self._count, getattr(led, self._count) + 1
+                    )
+        if self.listening:
+            ann = self._ann
+            if ann is not None:
+                late = self._late
+                if late:
+                    if None in late.values():
+                        late = {
+                            k: v for k, v in late.items() if v is not None
+                        }
+                    ann.set_metadata(**late)
+                ann.__exit__(*exc)
+            trace = self._trace
+            if trace is not None and self._record:
+                trace.record(
+                    self.name, self.cat, self._t0, t1,
+                    span_id=self.span_id, parent_id=self._parent,
+                    **self.args(),
+                )
+        return False
+
+
+def scope(stage: str):
+    """``jax.named_scope("otb/<stage>")`` round one plan operator's
+    lowering inside a program body: metadata on the HLO ops it emits
+    (obs/profile.py sums device time by it), nothing at run time.
+    Stage components are lower-case words, so the reduction can tell
+    them from JAX's own frames (``jit(..)``, ``while``, ``body``)."""
+    import jax
+
+    return jax.named_scope("otb/" + stage)
 
 
 # ---------------------------------------------------------------------------

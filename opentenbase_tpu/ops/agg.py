@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from opentenbase_tpu.obs.trace import scope
+
 _I64_MAX = np.int64(2**62)  # sentinels safely inside int64
 _I64_MIN = np.int64(-(2**62))
 
@@ -258,41 +260,43 @@ def _mxu_group_reduce_impl(keys, vals, slot, num_groups: int, specs: tuple):
         sl = xs[0].reshape(sb, _MXU_BLOCK)
         cols = xs[1:]
         lane_arrays = []
-        for kind, ri, nl, l in lanes:
-            if kind == "ones":
-                lane_arrays.append(
-                    jnp.ones((sb, _MXU_BLOCK), dtype=jnp.float32)
-                )
-            elif kind == "f32":
-                lane_arrays.append(
-                    cols[ri].reshape(sb, _MXU_BLOCK)
-                )
-            else:  # one limb of an int64 raw column
-                v = cols[ri].reshape(sb, _MXU_BLOCK)
-                if l == nl - 1:
+        with scope("agg/limbs"):
+            for kind, ri, nl, l in lanes:
+                if kind == "ones":
                     lane_arrays.append(
-                        jnp.right_shift(
-                            v, _LIMB_BITS * l
-                        ).astype(jnp.float32)
+                        jnp.ones((sb, _MXU_BLOCK), dtype=jnp.float32)
                     )
-                else:
+                elif kind == "f32":
                     lane_arrays.append(
-                        jnp.bitwise_and(
-                            jnp.right_shift(v, _LIMB_BITS * l),
-                            _LIMB_MASK,
-                        ).astype(jnp.float32)
+                        cols[ri].reshape(sb, _MXU_BLOCK)
                     )
-        lb = jnp.stack(lane_arrays, axis=-1)  # [sb, B, K]
+                else:  # one limb of an int64 raw column
+                    v = cols[ri].reshape(sb, _MXU_BLOCK)
+                    if l == nl - 1:
+                        lane_arrays.append(
+                            jnp.right_shift(
+                                v, _LIMB_BITS * l
+                            ).astype(jnp.float32)
+                        )
+                    else:
+                        lane_arrays.append(
+                            jnp.bitwise_and(
+                                jnp.right_shift(v, _LIMB_BITS * l),
+                                _LIMB_MASK,
+                            ).astype(jnp.float32)
+                        )
+            lb = jnp.stack(lane_arrays, axis=-1)  # [sb, B, K]
         # masked/invisible rows carry slot == cap: their one-hot row is
         # all zero, so they contribute nothing (incl. the count column)
-        onehot = (
-            sl[..., None] == jnp.arange(cap, dtype=slot.dtype)
-        ).astype(jnp.float32)
-        part = jnp.einsum(
-            "sbc,sbk->sck", onehot, lb,
-            preferred_element_type=jnp.float32,
-        )
-        return acc + jnp.sum(part.astype(jnp.int64), axis=0), None
+        with scope("agg/onehot"):
+            onehot = (
+                sl[..., None] == jnp.arange(cap, dtype=slot.dtype)
+            ).astype(jnp.float32)
+            part = jnp.einsum(
+                "sbc,sbk->sck", onehot, lb,
+                preferred_element_type=jnp.float32,
+            )
+            return acc + jnp.sum(part.astype(jnp.int64), axis=0), None
 
     # the init carry derives from ``slot`` so its varying-manual-axes
     # match inside shard_map (a plain zeros init is replicated and the
@@ -305,7 +309,19 @@ def _mxu_group_reduce_impl(keys, vals, slot, num_groups: int, specs: tuple):
         acc0,
         (slot_b, *raw),
     )  # [cap, K]
+    with scope("agg/recombine"):
+        return _mxu_recombine(
+            totals, cnt_idx, key_slices, kvalid_idx, keys, slot, cap,
+            pad0, specs, vals, val_slices,
+        )
 
+
+def _mxu_recombine(
+    totals, cnt_idx, key_slices, kvalid_idx, keys, slot, cap, pad0,
+    specs, vals, val_slices,
+):
+    """The limb totals of ``_mxu_group_reduce_impl`` back to keys, sums
+    and the exact collision verdict."""
     cnt = totals[:, cnt_idx]
     got = cnt > 0
     safe_cnt = jnp.maximum(cnt, 1)

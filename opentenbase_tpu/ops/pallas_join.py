@@ -36,6 +36,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from opentenbase_tpu.obs.trace import scope
+
 LIMB_BITS = 12
 LIMB_MASK = (1 << LIMB_BITS) - 1
 LIMBS = 6  # 6 x 12 = 72 bits >= the full int64 key domain
@@ -165,7 +167,7 @@ def build_probe(
         # the engine runs in global x64 mode; this kernel is pure
         # f32/i32 (see ops/pallas_scan.py for the Mosaic i64-scalar
         # rationale)
-        with jax.enable_x64(False):
+        with jax.enable_x64(False), scope("kernel"):
             matched, bidx = pl.pallas_call(
                 kernel,
                 grid=(grid,),

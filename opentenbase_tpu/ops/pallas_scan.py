@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from opentenbase_tpu import types as t
+from opentenbase_tpu.obs.trace import scope
 from opentenbase_tpu.plan import texpr as E
 
 BLOCK = 4096  # rows per grid step: limb block sums stay exact (< 2^24)
@@ -389,7 +390,7 @@ def build_partials(
         # the engine runs in global x64 mode, but Mosaic cannot legalize
         # the i64 grid/index scalars that mode produces — this kernel is
         # pure f32/i32, so trace it with x64 off
-        with jax.enable_x64(False):
+        with jax.enable_x64(False), scope("scan/kernel"):
             return pl.pallas_call(
                 kernel,
                 grid=(grid,),

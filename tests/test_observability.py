@@ -145,16 +145,30 @@ def test_explain_analyze_operator_tree(join_sess):
 
 def test_explain_analyze_fused_phases(sess):
     """Fused path: EXPLAIN ANALYZE reports compile vs device-execute
-    vs host-merge ms, and pg_stat_fused carries the same attribution."""
+    vs host-merge ms, and pg_stat_statements carries the same attribution."""
     res = sess.execute("explain analyze select count(*) from t")
     text = "\n".join(r[0] for r in res.rows)
     assert "Fused device execution:" in text, text
     assert "compile=" in text and "device=" in text
     assert "Total: rows=1" in text
-    rows = sess.query("select event, detail from pg_stat_fused")
-    events = {r[0] for r in rows}
-    assert "last_compile_ms" in events and "last_device_ms" in events
-    assert "total_device_ms" in events
+    # the same attribution, per statement class, in pg_stat_statements
+    # (the ledger columns took the place of pg_stat_fused's last_*_ms /
+    # total_*_ms rows): device time and its split by span
+    sess.query("select count(*) from t")
+    row = sess.query(
+        "select calls, device_ms, compile_ms, bind_ms, launch_ms, "
+        "device_wait_ms, device_launches, device_syncs "
+        "from pg_stat_statements where query = 'select count(*) from t'"
+    )[0]
+    assert row[0] >= 1 and row[1] > 0 and row[2] >= 0
+    assert row[3] > 0 and row[4] > 0 and row[5] > 0
+    assert row[6] >= 1 and row[7] >= 1
+    events = {
+        r[0] for r in sess.query("select event, detail from pg_stat_fused")
+    }
+    assert "fused_statements" in events
+    # no timing row is left in the view: the spans and columns carry them
+    assert not any(e.endswith(("_ms", "]")) for e in events)
 
 
 def test_explain_analyze_fused_join(join_sess):

@@ -1,0 +1,359 @@
+"""One statement, one timeline (obs/trace.span, obs/profile.py): the
+span tree of scan and join statements under ``trace_queries``, the same
+spans as ``otb:`` events in a JAX profiler trace, the ledger columns
+that decompose ``device_ms`` with tracing off, program names, join
+modes as run, scopes that change no output, and the reduction on a
+small recorded trace."""
+
+import json
+import os
+import re
+from contextlib import nullcontext
+
+import pytest
+
+from opentenbase_tpu.engine import Cluster
+from opentenbase_tpu.net.client import connect_tcp
+from opentenbase_tpu.net.server import ClusterServer
+from opentenbase_tpu.obs import profile
+from opentenbase_tpu.obs import statements as stmtobs
+
+Q6 = "select sum(p * q) from li where d >= 3 and d < 20 and q < 24"
+Q1 = (
+    "select f, sum(q), sum(p), count(*) from li where d <= 25 "
+    "group by f order by f"
+)
+QJ = (
+    "select o.c, sum(li.p) from li join o on li.k = o.k "
+    "where li.d < 20 group by o.c order by o.c"
+)
+STATEMENTS = {"q6_like": Q6, "q1_like": Q1, "join": QJ}
+SPLIT = ", ".join(stmtobs.DEVICE_SPLIT_FIELDS)
+
+
+def _load(execute):
+    execute(
+        "create table li (k bigint, q bigint, p bigint, d bigint, f text) "
+        "distribute by shard(k)"
+    )
+    execute("create table o (k bigint, c bigint) distribute by shard(k)")
+    execute("insert into li values " + ",".join(
+        f"({i},{i % 50},{100 + i % 7},{i % 30},'{'AB'[i % 2]}')"
+        for i in range(400)
+    ))
+    execute("insert into o values " + ",".join(
+        f"({i},{i % 5})" for i in range(0, 400, 2)
+    ))
+    execute("analyze")
+
+
+@pytest.fixture(scope="module")
+def wire():
+    """A cluster behind its wire server with the three statements warm."""
+    cluster = Cluster(num_datanodes=2, shard_groups=16)
+    server = ClusterServer(cluster).start()
+    client = connect_tcp(server.host, server.port)
+    _load(client.execute)
+    for q in STATEMENTS.values():
+        client.execute(q)
+    yield cluster, client
+    client.close()
+    server.stop()
+
+
+def _traced(cluster, client, sql):
+    client.execute("set trace_queries = on")
+    try:
+        client.execute(sql)
+    finally:
+        client.execute("set trace_queries = off")
+    return next(
+        tr for tr in reversed(cluster.tracer.last(4)) if tr.query == sql
+    )
+
+
+@pytest.mark.parametrize("kind", list(STATEMENTS))
+def test_span_tree_under_trace_queries(wire, kind):
+    cluster, client = wire
+    tr = _traced(cluster, client, STATEMENTS[kind])
+    by_id = {sp.span_id: sp for sp in tr.spans}
+    assert len(by_id) == len(tr.spans), "every span has its own id"
+    names = [sp.name for sp in tr.spans]
+    root = tr.spans[0]
+    assert root.name == "wire.request" and root.parent_id is None
+    for must in (
+        "wire.decode", "wire.lock_wait", "query", "parse", "plan",
+        "execute", "fused", "fused.gate_wait", "fused.cache",
+        "fused.bind", "fused.launch", "fused.wait", "fused.collect",
+        "fused.merge", "wire.encode", "wire.send",
+    ):
+        assert must in names, (must, names)
+    parent_of = {
+        "query": "wire.request", "execute": "query", "fused": "execute",
+        "fused.launch": "fused", "fused.wait": "fused",
+        "fused.merge": "fused", "wire.send": "wire.request",
+    }
+    kids_us: dict = {}
+    for sp in tr.spans[1:]:
+        parent = by_id[sp.parent_id]  # one trace: every edge resolves
+        if sp.name in parent_of:
+            assert parent.name == parent_of[sp.name], (sp.name, parent.name)
+        # every child inside its parent, on the one perf_counter clock
+        assert sp.ts_us >= parent.ts_us - 1e-3, (sp.name, parent.name)
+        assert (
+            sp.ts_us + sp.dur_us <= parent.ts_us + parent.dur_us + 1e-3
+        ), (sp.name, parent.name)
+        kids_us[parent.span_id] = kids_us.get(parent.span_id, 0.0) + sp.dur_us
+    # self times (a span less its children) sum to the root's duration
+    selfs = [sp.dur_us - kids_us.get(sp.span_id, 0.0) for sp in tr.spans]
+    assert min(selfs) >= -1e-3
+    assert sum(selfs) == pytest.approx(root.dur_us, rel=1e-9)
+    launch = next(sp for sp in tr.spans if sp.name == "fused.launch")
+    assert re.match(r"^program_[a-z_]+$", launch.args["program"])
+    assert root.args["rows"] >= 1 and root.args["bytes_out"] > 0
+    if kind == "join":
+        assert launch.args["join_modes"]
+
+
+@pytest.fixture(scope="module")
+def profiled(wire, tmp_path_factory):
+    """The three statements under ``jax.profiler.start_trace`` with
+    ``trace_queries`` on: (QueryTraces by kind, the loaded xplane)."""
+    import jax
+
+    cluster, client = wire
+    out = str(tmp_path_factory.mktemp("xplane"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(out, profiler_options=opts)
+    try:
+        traces = {
+            k: _traced(cluster, client, q) for k, q in STATEMENTS.items()
+        }
+    finally:
+        jax.profiler.stop_trace()
+    return traces, profile.load(profile.find_xplane(out))
+
+
+@pytest.mark.parametrize("kind", list(STATEMENTS))
+def test_profiler_events_equal_the_query_trace(profiled, kind):
+    traces, loaded = profiled
+    tr = traces[kind]
+    events = [
+        ev for p in loaded["planes"] for line in p["lines"]
+        for ev in line["events"]
+        if ev[3].get("trace_id") == tr.trace_id
+    ]
+    got = {
+        ev[3]["span_id"]: (ev[0], ev[3].get("parent_id")) for ev in events
+    }
+    # Tracer.finish gives the root no parent; its TraceMe names itself
+    want = {
+        sp.span_id: (
+            "otb:" + sp.name,
+            sp.parent_id or (sp.span_id if sp.name != "wire.request"
+                             else tr.ctx.span_id),
+        )
+        for sp in tr.spans
+    }
+    assert got == want
+    # and the reduction finds the statement with its class and counts
+    report = profile.reduce(loaded)
+    assert report["statements"] >= 3 + 2 * 3  # + the SET round trips
+    classed = [
+        c for k, c in report["classes"].items() if k != "unclassified"
+    ]
+    assert sum(c["launches"] for c in classed) >= 3
+    assert all(
+        c["spans"]["fused.launch"]["count"] == c["launches"]
+        for c in classed if c["launches"]
+    )
+
+
+def test_ledger_columns_filled_with_tracing_off(wire):
+    cluster, client = wire
+
+    def read():
+        return {
+            r[0]: [float(x) for x in r[1:]] for r in client.execute(
+                f"select query, calls, device_ms, {SPLIT}, merge_ms, "
+                "device_launches, device_syncs, fused_retries "
+                "from pg_stat_statements"
+            ).rows
+        }
+
+    before = read()
+    for q in STATEMENTS.values():
+        client.execute(q)
+    after = read()
+    moved = 0
+    for query, row in after.items():
+        if " pg_stat_" in query or query not in before:
+            continue
+        d = [a - b for a, b in zip(row, before[query])]
+        if d[0] != 1:
+            continue
+        moved += 1
+        device_ms, six = d[1], d[2:8]
+        launches, syncs = d[9], d[10]
+        assert launches >= 1 and syncs >= 1, query
+        assert all(x >= 0 for x in six), (query, six)
+        # the six parts never count a millisecond twice
+        assert sum(six) <= device_ms + 1e-6, (query, six, device_ms)
+        assert d[8] >= 0  # merge_ms
+    assert moved == 3
+    phases = {
+        r[0] for r in
+        client.execute("select phase from pg_stat_query_phases").rows
+    }
+    assert "wire" in phases
+
+
+def test_join_modes_same_cold_retraced_and_cached():
+    """One join statement cold, with new literals (a re-bind of the
+    cached program) and from the program cache reports one join_modes."""
+    s = Cluster(num_datanodes=2, shard_groups=16).session()
+    _load(s.execute)
+
+    def modes():
+        rows = dict(s.query("select event, detail from pg_stat_fused"))
+        # a cold run may launch a program twice (a capacity retry)
+        return (
+            rows["last_join_modes"],
+            set(rows["last_programs"].split(",")),
+        )
+
+    s.query(QJ)
+    cold = modes()
+    s.query(QJ.replace("< 20", "< 11"))
+    rebound = modes()
+    s.query(QJ)
+    cached = modes()
+    assert cold == rebound == cached
+    assert cold[0] and all(p.startswith("program_dag_") for p in cold[1])
+    lines = [r[0] for r in s.query("explain analyze " + QJ)]
+    assert f"Fused join modes: {cold[0]}" in lines, lines
+
+
+def test_every_compiled_program_is_named(wire):
+    """The XLA module of a program is ``jit_<its name>``: every program
+    the scan and DAG paths hold is ``program_<what>``, no hash, no
+    literal (the benchmark's rooflines match ``^jit_program``)."""
+    cluster, _client = wire
+    fx = cluster._fused
+    names = set()
+    for entry in fx._programs.values():
+        if entry:
+            names.add(entry[0].__name__)
+    for key, entry in fx._dag._programs.items():
+        for prog in entry[:fx._dag._NPROGS.get(key[0], 1)]:
+            names.add(prog.__name__)
+            assert isinstance(prog.join_modes, set)
+    assert names
+    for n in names:
+        assert re.match(r"^jit_program_[a-z_]+$", "jit_" + n), n
+    prog = next(iter(fx._dag._programs.values()))[0]
+    assert prog.__name__.startswith("program_dag_")
+
+
+def test_scopes_change_no_output(monkeypatch):
+    """``jax.named_scope`` is metadata: the three statements answer the
+    same with every ``otb/`` scope taken out."""
+    def answers():
+        s = Cluster(num_datanodes=2, shard_groups=16).session()
+        _load(s.execute)
+        return [s.query(q) for q in STATEMENTS.values()]
+
+    with_scopes = answers()
+    from opentenbase_tpu.executor import fused, fused_dag
+    from opentenbase_tpu.ops import agg, pallas_join, pallas_scan
+
+    for mod in (fused, fused_dag, agg, pallas_scan, pallas_join):
+        monkeypatch.setattr(mod, "scope", lambda stage: nullcontext())
+    assert answers() == with_scopes
+
+
+def test_explain_analyze_reports_fragments_from_spans():
+    s = Cluster(num_datanodes=2, shard_groups=16).session()
+    _load(s.execute)
+    s.query(QJ)
+    lines = [r[0] for r in s.query("explain (analyze, verbose) " + QJ)]
+    frag = [ln for ln in lines if ln.strip().startswith("device fragment")]
+    assert frag and "final" in frag[-1], lines
+    fused_line = next(ln for ln in lines if ln.startswith("  fused: "))
+    assert "device_launches=" in fused_line and "bind=" in fused_line
+
+
+def test_scope_of_reads_the_stage_words():
+    f = profile.scope_of
+    assert f("jit(program_dag_gsort)/shard_map/otb/join1/merge/sort/sort:") \
+        == "join1/merge/sort"
+    assert f("jit(p)/otb/agg/limbs/while/body/closed_call/shift:") \
+        == "agg/limbs"
+    assert f("jit(p)/otb/join0/pallas/probe/otb/kernel/pallas_call/x") \
+        == "join0/pallas/probe/kernel"
+    assert f("jit(p)/otb/scan/decode/jit(_where)/select_n") == "scan/decode"
+    assert f("reduce_window_sum:") == "" and f(None) == ""
+
+
+def test_profile_reduction_on_a_recorded_trace():
+    """tests/data/profile_trace.json: two statements of one class on
+    one chip. Statement 1 runs one program with an idle gap between two
+    of its ops (inside the module); statement 2 runs two programs with
+    a gap between them while the host sits in ``fused.wait``, and a gap
+    after the last one while no inner span is open; a fourth program
+    runs outside any statement."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "data", "profile_trace.json")) as f:
+        trace = json.load(f)
+    report = profile.reduce(trace)
+    assert report["statements"] == 2
+    (key, c), = report["classes"].items()
+    assert key == "77"
+    assert (c["launches"], c["syncs"], c["retries"]) == (3, 3, 1)
+    assert c["join_modes"] == {"merge": 1, "fold+merge": 1}
+    assert c["programs_ms"] == pytest.approx({
+        "jit_program_scan_pallas": 6.0, "jit_program_dag_count": 1.0,
+        "jit_program_dag_gsort": 4.0,
+    })
+    # op self time: the while keeps what its body does not cover
+    assert c["scopes_ms"] == pytest.approx({
+        "scan/decode": 2.0, "scan/kernel": 2.0, "agg/onehot": 0.5,
+        "(no scope)": 1.0, "exchange/count": 0.5,
+        "join0/merge/sort": 4.0,
+    })
+    assert c["device_busy_ms"] == pytest.approx(10.0)
+    assert c["unscoped_ops_ms"] == pytest.approx(
+        {"%while.1": 0.5, "%copy.9": 0.5}
+    )
+    # each gap split over the innermost spans open across it; the part
+    # a running program covers is the program's
+    assert c["idle_ms"] == pytest.approx({
+        "in_program:jit_program_scan_pallas": 1.0,  # inside a module
+        "fused.wait": 0.5 + 1.7 + 2.5,  # between modules: host waiting
+        "fused.bind": 1.0 + 1.0 + 0.8,
+        "fused.cache": 0.5,
+        "fused.launch": 0.5 + 0.5 + 0.5,
+        "fused.collect": 0.4 + 0.5,
+        "fused": 0.1 + 0.5 + 0.3,
+        "query": 0.3 + 0.3 + 0.3 + 0.2,
+        "wire.request": 0.2 + 0.2 + 0.2 + 6.0,  # no inner span open
+    })
+    assert sum(c["idle_ms"].values()) == pytest.approx(20.0)
+    spans = c["spans"]
+    assert spans["wire.request"]["count"] == 2
+    assert spans["fused.bind"]["count"] == 3
+    assert spans["fused.bind"]["total_ms"] == pytest.approx(3.3)
+    assert spans["fused.bind"]["self_ms"] == pytest.approx(3.3 - 0.5)
+    assert c["dispatch_split_ms"]["fused.cache"] == pytest.approx(0.5)
+    assert c["dispatch_split_sum_ms"] == pytest.approx(
+        2.8 + 0.5 + 3.0 + 14.2 + 0.9
+    )
+    assert report["outside"]["programs_ms"] == pytest.approx(
+        {"jit_program_warmup": 2.0}
+    )
+    assert report["outside"]["device_busy_ms"] == pytest.approx(2.0)
+    text = profile.render(report)
+    assert "in_program:jit_program_scan_pallas" in text
+    assert "ops under no scope" in text
