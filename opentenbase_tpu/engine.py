@@ -8595,6 +8595,10 @@ def _sv_fused(c: Cluster):
          str(int(fx.cache.stats.get("delta_tail_rows", 0))))
     )
     rows.append(("fused_statements", str(fx.fused_statements)))
+    # launches of the MXU group reduce by lane plan: narrowed by the
+    # column statistics, or at the dtypes' full width
+    rows.append(("mxu_plans_bounded", str(fx.mxu_plans["bounded"])))
+    rows.append(("mxu_plans_full", str(fx.mxu_plans["full"])))
     dag = fx._dag
     if dag is not None:
         rows.append(("completed", str(dag.completed)))

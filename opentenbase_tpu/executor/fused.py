@@ -28,7 +28,7 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -976,6 +976,49 @@ class _FusablePartial:
     scan: L.Scan
     steps: list  # Filter/Project chain bottom-up (excluding scan/agg)
     agg: L.Aggregate
+    _inlined: Optional[tuple] = None
+
+    def scan_exprs(self) -> tuple:
+        """(preds, group keys, aggregate arguments) rewritten over the
+        scan schema (the Project steps inlined; None for count(*)'s
+        argument): what the column statistics can bound. Both binds of
+        a statement (the Pallas certifier, the MXU lane plan) read it,
+        so it is made once."""
+        if self._inlined is None:
+            from opentenbase_tpu.ops import pallas_scan as ps
+
+            chain: list = []
+            preds: list = []
+            for step in self.steps:
+                if isinstance(step, L.Filter):
+                    preds.append(ps.inline_projects(step.predicate, chain))
+                else:
+                    chain.append(tuple(
+                        ps.inline_projects(e, chain) for e in step.exprs
+                    ))
+            self._inlined = (
+                preds,
+                [ps.inline_projects(g, chain) for g in self.agg.group_exprs],
+                [
+                    None if a.arg is None
+                    else ps.inline_projects(a.arg, chain)
+                    for a in self.agg.aggs
+                ],
+            )
+        return self._inlined
+
+
+class _MxuBind(NamedTuple):
+    """One bind's lane plan of the MXU group reduce, as the host sees it."""
+
+    bounds: "agg_ops.MxuBounds"  # part of the program key
+    lanes: int  # K of the plan as bound
+    lanes_full: int  # K the dtype-wide plan would have had
+    narrowed: bool  # a bound dropped at least one lane
+
+
+def _is_float(ty) -> bool:
+    return np.issubdtype(ty.np_dtype, np.floating)
 
 
 # Resident-cache ceiling for one table's scan columns: beyond this the
@@ -1109,6 +1152,11 @@ class FusedExecutor:
         # zone-map pruning on the DEVICE path (VERDICT r2 missing-5):
         # blocks excluded from the scanned window per fused query
         self.zone_stats = {"pruned_blocks": 0, "total_blocks": 0}
+        # launches of the MXU group reduce whose lane plan the column
+        # statistics narrowed by at least one lane / by none
+        # (pg_stat_fused mxu_plans_bounded / mxu_plans_full)
+        self.mxu_plans = {"bounded": 0, "full": 0}
+        self._mxu_binds: dict = {}  # id(plan) -> (plan, stats, _MxuBind)
         # the statement path's one way to call a jitted program
         # (fused.launch span, launch/retry accounting); the DAG runner
         # shares it
@@ -1258,9 +1306,22 @@ class FusedExecutor:
                     skey = plan_skey(m.agg)
                 except NotImplementedError:
                     skey = m.agg.key()
+                # the MXU group reduce cuts as many limb lanes as the
+                # table's CURRENT statistics say the values need: like
+                # _try_pallas's sig, the certified widths are part of
+                # the key, so data grown past a limb boundary binds a
+                # wider program and never reuses a narrower one
+                mxu = (
+                    self._mxu_bounds(m, dtab, has_valid)
+                    if grouping == "hash" else None
+                )
+                mxu_bounds = None
+                if mxu is not None:
+                    mxu_bounds = mxu.bounds
+                    bsp.set(lanes=mxu.lanes, lanes_full=mxu.lanes_full)
                 key = (
                     skey, dtab.rmax, len(dtab.nrows), cap, has_valid,
-                    grouping, win,
+                    grouping, win, mxu_bounds,
                 )
                 # the structural key masks literal values; the
                 # compile-time param specs BAKE them. Rebuild the
@@ -1268,7 +1329,8 @@ class FusedExecutor:
                 # and pair the cached executable with the fresh specs —
                 # otherwise 'x = 1' silently reuses 'x = 7''s parameter
                 fresh = self._compile(
-                    m, meta, dtab, cap, has_valid, grouping, win=win
+                    m, meta, dtab, cap, has_valid, grouping, win=win,
+                    mxu_bounds=mxu_bounds,
                 )
                 cached = self._programs.get(key)
                 bsp.set(cache="miss" if cached is None else "hit")
@@ -1304,7 +1366,11 @@ class FusedExecutor:
                     *extra, snap, params,
                 )
 
-            outs = self.launch(program, args, mode=f"{grouping}/{cap}")
+            mode = f"{grouping}/{cap}"
+            if mxu is not None:
+                mode += f"/k{mxu.lanes}"
+                self.mxu_plans["bounded" if mxu.narrowed else "full"] += 1
+            outs = self.launch(program, args, mode=mode)
             return self._collect(m, outs, out_info, cap, dtab)
 
         def is_collision(e):
@@ -1326,6 +1392,117 @@ class FusedExecutor:
                 raise
             self.launch.note_retry("hash collision, sort grouping")
             return run_mode("sort", group_cap)
+
+    def _mxu_bounds(
+        self, m: _FusablePartial, dtab: DeviceTable, has_valid
+    ) -> Optional["_MxuBind"]:
+        """The MXU group reduce's lane plan for this fragment against
+        the table's CURRENT statistics, or None where that reduce does
+        not run. Asked on every bind; answered from the last answer for
+        the same plan object (the plan cache hands it back) while the
+        statistics read the same."""
+        stats = (
+            tuple(dtab.col_maxabs.get(c) for c in m.scan.columns),
+            tuple(dtab.col_range.get(c) for c in m.scan.columns),
+            has_valid,
+        )
+        memo = self._mxu_binds.get(id(m.agg))
+        if memo is not None and memo[0] is m.agg and memo[1] == stats:
+            return memo[2]
+        bind = self._mxu_bind(m, *stats)
+        if len(self._mxu_binds) >= 256:
+            self._mxu_binds.clear()
+        # the entry keeps its plan alive, so the id is not reused
+        self._mxu_binds[id(m.agg)] = (m.agg, stats, bind)
+        return bind
+
+    def _mxu_bind(
+        self, m: _FusablePartial, col_bounds, col_ranges, has_valid
+    ) -> Optional["_MxuBind"]:
+        """What the statistics certify about a grouped fragment's keys
+        and sum arguments, quantised to limb counts for
+        ``ops/agg.mxu_lane_plan`` — or None where the MXU group reduce
+        does not run (ungrouped, min/max, float keys or sums).
+
+        The inputs are the Pallas certifier's: |max| per scan column
+        (``col_maxabs``, ``col_range``; kept current by uploads and
+        delta tails, over real rows only) through the interval
+        arithmetic of ``pallas_scan.bound``. Where that says nothing (a
+        division, a CASE, a float, a window table without statistics)
+        the value keeps its dtype's width: the same plan, not another
+        path."""
+        from opentenbase_tpu.ops import pallas_scan as ps
+        from opentenbase_tpu.plan import texpr as E
+
+        if not m.agg.group_exprs:
+            return None
+        specs = []
+        for a in m.agg.aggs:
+            if a.func not in ("sum", "count"):
+                return None
+            if a.func == "sum" and _is_float(a.arg.type):
+                return None
+            specs.append(
+                "count_star" if a.arg is None else a.func
+            )
+        if any(_is_float(g.type) for g in m.agg.group_exprs):
+            return None
+        _preds, key_exprs, arg_exprs = m.scan_exprs()
+
+        def limbs(e, out_dict=None):
+            if isinstance(e, E.Col):
+                # a bare column: its physical range, whatever its SQL
+                # type (dictionary codes, dates) — unless a text key is
+                # re-coded into another dictionary on the way
+                src_dict = m.scan.schema[e.index].dict_id
+                if e.type.is_text and out_dict != src_dict:
+                    return None
+                return agg_ops.limbs_for_range(col_ranges[e.index])
+            return agg_ops.limbs_for_bound(ps.bound(e, col_bounds))
+
+        def col(e, ty):
+            nullable = any(
+                isinstance(x, E.Col) and has_valid[x.index]
+                for x in E.walk(e)
+            )
+            return ty.np_dtype, nullable
+
+        key_limbs = tuple(
+            limbs(e, oc.dict_id)
+            for e, oc in zip(key_exprs, m.agg.schema)
+        )
+        ids: dict = {}
+        arg_ids = tuple(
+            None if e is None else ids.setdefault(e, len(ids))
+            for e in arg_exprs
+        )
+        summed = {
+            e for e, spec in zip(arg_exprs, specs) if spec == "sum"
+        }
+        bounds = agg_ops.MxuBounds(
+            key_limbs, arg_ids,
+            tuple(limbs(e) if e in summed else None for e in ids),
+        )
+        key_cols = [
+            col(e, g.type) for e, g in zip(key_exprs, m.agg.group_exprs)
+        ]
+        arg_cols = [
+            None if e is None else col(e, a.arg.type)
+            for e, a in zip(arg_exprs, m.agg.aggs)
+        ]
+        lanes = len(
+            agg_ops.mxu_lane_plan(key_cols, specs, arg_cols, bounds).lanes
+        )
+        unbounded = agg_ops.MxuBounds(
+            (None,) * len(key_limbs), arg_ids, (None,) * len(ids)
+        )
+        return _MxuBind(
+            bounds, lanes,
+            agg_ops.mxu_lanes_dtype_wide(key_cols, specs, arg_cols),
+            lanes < len(agg_ops.mxu_lane_plan(
+                key_cols, specs, arg_cols, unbounded
+            ).lanes),
+        )
 
     def _scan_footprint(self, meta, columns) -> tuple[int, int, int, int]:
         """(resident_bytes, row_bytes, S, max_shard_rows) for caching a
@@ -1619,43 +1796,27 @@ class FusedExecutor:
         Raises PallasUnsupported when outside the certified subset."""
         from opentenbase_tpu.ops import pallas_scan as ps
 
-        project_chain: list = []
-        preds: list = []
-        for step in m.steps:
-            if isinstance(step, L.Filter):
-                preds.append(
-                    ps.inline_projects(step.predicate, project_chain)
-                )
-            else:
-                project_chain.append(tuple(
-                    ps.inline_projects(e, project_chain)
-                    for e in step.exprs
-                ))
+        preds, key_exprs, arg_exprs = m.scan_exprs()
         for p in preds:
             if not ps.certify_predicate(p, col_bounds):
                 raise ps.PallasUnsupported("predicate")
         group_plan = None
         sig_parts: list = []
-        if m.agg.group_exprs:
-            key_exprs = [
-                ps.inline_projects(g, project_chain)
-                for g in m.agg.group_exprs
-            ]
+        if key_exprs:
             _key_fn, decoders, n_groups = ps.plan_group_keys(
                 key_exprs, col_ranges
             )
             group_plan = (key_exprs, decoders, n_groups)
             sig_parts.append(("groups", tuple(decoders)))
         agg_args: list = []
-        for a in m.agg.aggs:
+        for a, arg in zip(m.agg.aggs, arg_exprs):
             if a.func == "count":
-                if a.arg is not None:
+                if arg is not None:
                     # count(expr) == count(*) only when expr can never be
                     # NULL: columns have no validity masks here (gated
                     # above) AND the expression stays in the bounded
                     # arithmetic subset — nullif/division/CASE produce
                     # dynamic NULLs and must keep the XLA path
-                    arg = ps.inline_projects(a.arg, project_chain)
                     if ps.bound(arg, col_bounds) is None:
                         raise ps.PallasUnsupported("nullable count arg")
                 agg_args.append(None)
@@ -1663,7 +1824,6 @@ class FusedExecutor:
                 continue
             if a.func != "sum":
                 raise ps.PallasUnsupported(a.func)
-            arg = ps.inline_projects(a.arg, project_chain)
             dec = ps.decompose_value(arg, col_bounds)
             if dec is None:
                 raise ps.PallasUnsupported("value bound")
@@ -1757,6 +1917,7 @@ class FusedExecutor:
     def _compile(
         self, m: _FusablePartial, meta, dtab: DeviceTable, group_cap,
         has_valid, grouping: str = "hash", win: Optional[int] = None,
+        mxu_bounds=None,
     ):
         comp = ExprCompiler(lift_consts=True)
         scan_dids = [c.dict_id for c in m.scan.schema]
@@ -1897,7 +2058,8 @@ class FusedExecutor:
                             keys, mask, group_cap
                         )
                     return agg_ops._mxu_group_reduce_impl(
-                        keys, vals, slot, group_cap, tuple(specs)
+                        keys, vals, slot, group_cap, tuple(specs),
+                        mxu_bounds,
                     )
                 with scope("agg/hashslot"):
                     slot, ngroups, collision = agg_ops._hash_slots_impl(

@@ -20,7 +20,9 @@ equivalent of make_remotesubplan's agg split
 
 from __future__ import annotations
 
+import math
 from functools import partial
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -144,42 +146,165 @@ _MXU_BLOCK = 4096  # rows per one-hot matmul block
 # product passes.)
 _LIMB_BITS = 8
 _LIMB_MASK = (1 << _LIMB_BITS) - 1
+# a bound at or above this is not trusted to size limbs: it comes from
+# float interval arithmetic (ops/pallas_scan.bound), exact on integers
+# only below 2^53
+_BOUND_TRUSTED = float(1 << 52)
 
 
-def _int_limbs(v, n_limbs: int):
-    """Split an integer column into ``n_limbs`` radix-4096 limbs (f32
-    arrays, each value < 4096; the top limb carries the sign via
-    arithmetic shift). Exact recombination: sum_l limb_l << 12l."""
-    v = v.astype(jnp.int64)
-    out = []
-    for l in range(n_limbs - 1):
-        out.append(
-            jnp.bitwise_and(
-                jnp.right_shift(v, _LIMB_BITS * l), _LIMB_MASK
-            ).astype(jnp.float32)
+def limbs_for_bound(bound, nonneg: bool = False):
+    """``(n_limbs, signed)`` holding every integer v with |v| <= bound in
+    8-bit limbs — the top limb carries the sign by arithmetic shift,
+    unless ``nonneg`` proves there is none to carry (one bit less).
+    None when the bound is unknown or too large to trust: the caller
+    then takes the width of the value's dtype."""
+    if bound is None or not 0 <= bound < _BOUND_TRUSTED:
+        return None
+    bits = int(math.ceil(bound)).bit_length() + (0 if nonneg else 1)
+    return max(-(-bits // _LIMB_BITS), 1), not nonneg
+
+
+def limbs_for_range(rng):
+    """``limbs_for_bound`` of a column's host-known ``(min, max)``."""
+    if rng is None:
+        return None
+    return limbs_for_bound(max(abs(rng[0]), abs(rng[1])), rng[0] >= 0)
+
+
+def _dtype_limbs(dtype):
+    """The limbs that hold ANY value of an integer dtype (bool: one)."""
+    dtype = jnp.dtype(dtype)
+    return dtype.itemsize, not jnp.issubdtype(dtype, jnp.unsignedinteger)
+
+
+class MxuBounds(NamedTuple):
+    """What the host certifies about a grouped reduction's keys and
+    arguments (executor/fused.py, from the device table's column
+    statistics): the quantised half of the lane plan, and as such part
+    of the compiled program's key. ``None`` where nothing is known."""
+
+    key_limbs: tuple  # per group key: limbs_for_bound() or None
+    arg_ids: tuple  # per spec: its distinct argument, None for count(*)
+    arg_limbs: tuple  # per distinct argument: limbs_for_bound() or None
+
+
+class MxuLanePlan(NamedTuple):
+    """The K lanes of the one-hot matmul and who reads which."""
+
+    # per lane: ("limb", raw, l, masked) | ("f32", raw) | ("ones",)
+    lanes: tuple
+    raws: tuple  # per raw column: (source, index, n_limbs or None=validity)
+    key_slices: tuple  # per key: (first lane, n_limbs)
+    key_valid: tuple  # per key: validity lane or None
+    arg_ids: tuple  # per spec: its distinct argument, None for count(*)
+    arg_slices: tuple  # per distinct argument: (first lane, n_limbs) or None
+    arg_valid: tuple  # per distinct argument: validity lane or None
+    ones: int  # the count(*) lane
+
+
+def mxu_lane_plan(key_cols, specs, arg_cols, bounds: Optional[MxuBounds]):
+    """Lay out the lanes from what each value can hold, not from what
+    its dtype could: ``key_cols`` / ``arg_cols`` give ``(dtype,
+    nullable)`` per group key / per spec (None for count(*)).
+
+    - only lanes that are read: a ``count(x)`` reads x's validity lane
+      when x has one and the ones lane otherwise, never limbs;
+    - each distinct argument once (``bounds.arg_ids``): sum(x) and
+      avg(x)'s sum share one raw column and one set of limbs;
+    - as many limbs as the certified bound needs, never more than the
+      dtype's; no bound (``bounds`` None, or None inside it) gives the
+      dtype's width, so the unbounded plan is this same plan.
+
+    The host calls this with the types it knows to report K
+    (fused.bind), the trace with the arrays it has."""
+    if bounds is None:
+        ids, nxt = [], 0
+        for spec in specs:
+            ids.append(None if spec == "count_star" else nxt)
+            nxt += spec != "count_star"
+        bounds = MxuBounds((None,) * len(key_cols), tuple(ids), (None,) * nxt)
+    lanes: list = []
+    raws: list = []
+
+    def width(dtype, certified):
+        nl, signed = _dtype_limbs(dtype)
+        if certified is not None and certified[0] < nl:
+            nl, signed = certified
+        return nl, signed
+
+    def add_limbs(source, index, nl, signed):
+        start = len(lanes)
+        raws.append((source, index, nl))
+        lanes.extend(
+            ("limb", len(raws) - 1, l, not (signed and l == nl - 1))
+            for l in range(nl)
         )
-    out.append(
-        jnp.right_shift(v, _LIMB_BITS * (n_limbs - 1)).astype(jnp.float32)
+        return start, nl
+
+    def add_valid(source, index):
+        raws.append((source, index, None))
+        lanes.append(("f32", len(raws) - 1))
+        return len(lanes) - 1
+
+    key_slices, key_valid = [], []
+    for i, ((dtype, nullable), certified) in enumerate(
+        zip(key_cols, bounds.key_limbs)
+    ):
+        key_slices.append(add_limbs("key", i, *width(dtype, certified)))
+        key_valid.append(add_valid("key", i) if nullable else None)
+    arg_slices: list = [None] * len(bounds.arg_limbs)
+    arg_valid: list = [None] * len(bounds.arg_limbs)
+    seen: set = set()
+    for i, (spec, aid) in enumerate(zip(specs, bounds.arg_ids)):
+        if aid is None:
+            continue
+        dtype, nullable = arg_cols[i]
+        if aid not in seen:
+            seen.add(aid)
+            if nullable:
+                arg_valid[aid] = add_valid("arg", i)
+        if spec == "sum" and arg_slices[aid] is None:
+            arg_slices[aid] = add_limbs(
+                "arg", i, *width(dtype, bounds.arg_limbs[aid])
+            )
+    lanes.append(("ones",))
+    return MxuLanePlan(
+        tuple(lanes), tuple(raws), tuple(key_slices), tuple(key_valid),
+        bounds.arg_ids, tuple(arg_slices), tuple(arg_valid), len(lanes) - 1,
     )
-    return out
 
 
-def _limbs_needed(dtype) -> int:
-    return 4 if jnp.dtype(dtype).itemsize <= 4 else 8
+def mxu_lanes_dtype_wide(key_cols, specs, arg_cols) -> int:
+    """K of the plan sized by dtype alone (the one this module cut
+    before it read bounds): every key at 4 or 8 limbs, every sum and
+    count argument at 8, nothing shared. Kept as the yardstick the
+    ``lanes_full`` span argument reports against."""
+    k = 1
+    for dtype, nullable in key_cols:
+        k += (4 if jnp.dtype(dtype).itemsize <= 4 else 8) + bool(nullable)
+    for spec, col in zip(specs, arg_cols):
+        if spec != "count_star":
+            k += 8 + bool(col[1])
+    return k
 
 
-def _mxu_group_reduce_impl(keys, vals, slot, num_groups: int, specs: tuple):
+def _mxu_group_reduce_impl(
+    keys, vals, slot, num_groups: int, specs: tuple,
+    bounds: Optional[MxuBounds] = None,
+):
     """Grouped reduction on the MXU: one-hot(slot) matmuls instead of
     segment scatters — XLA's TPU scatter/sort are orders of magnitude
     slower than a systolic-array pass for cap-bounded grouping.
 
-    Exactness: every accumulated quantity is integer-valued and
-    limb-split (radix 4096); each 4096-row block's one-hot matmul sums
-    each limb exactly in f32 (<= 2^24), per-block partials convert to
-    int64 and sum exactly. Group keys are recovered by division
-    (all rows in a slot share one key, or the collision flag is set):
-    khat = sum(key)/count, checked per row via a gather-compare — which
-    doubles as exact hash-collision detection.
+    Exactness: every accumulated quantity is integer-valued and split
+    into 8-bit limbs (``mxu_lane_plan``; sum_l limb_l << 8l is the
+    value); each 4096-row block's one-hot matmul sums each limb exactly
+    in f32 (<= 2^24), per-block partials convert to int64 and sum
+    exactly. ``bounds`` only ever drops limbs that are zero (or pure
+    sign) on every row the statistics cover. Group keys are recovered
+    by division (all rows in a slot share one key, or the collision
+    flag is set): khat = sum(key)/count, checked per row via a
+    gather-compare — which doubles as exact hash-collision detection.
 
     Eligibility (caller-enforced): integer-typed keys/vals, specs in
     sum/count/count_star. Returns (out_keys, out_vals, gvalid, ngroups,
@@ -199,61 +324,35 @@ def _mxu_group_reduce_impl(keys, vals, slot, num_groups: int, specs: tuple):
     super_rows = sb * _MXU_BLOCK
     ns = max(-(-n // super_rows), 1)
     padded = ns * super_rows
-    nb = padded // _MXU_BLOCK
     if padded != n:
         slot = jnp.pad(slot, (0, padded - n), constant_values=cap)
 
     def pad0(x):
         return jnp.pad(x, (0, padded - n)) if padded != n else x
 
-    # Plan the accumulated lane layout without materializing anything:
+    plan = mxu_lane_plan(
+        [(d.dtype, v is not None) for d, v in keys],
+        specs,
+        [None if val is None else (val[0].dtype, val[1] is not None)
+         for val in vals],
+        bounds,
+    )
     # raw columns ride through the scan, limbs are cut per superblock.
-    # Entry kinds: ("limbs", raw_idx, nl) | ("f32", raw_idx).
-    raw: list = []  # padded [ns, super_rows] arrays carried by the scan
-
-    def add_raw(x):
-        raw.append(pad0(x).reshape(ns, super_rows))
-        return len(raw) - 1
-
-    lanes: list = []  # lane plan, len = K
-    key_slices: list = []  # (start, n_limbs) per key DATA column
-    kvalid_idx: list = []  # lane index of the validity column (or None)
-    for data, valid in keys:
-        nl = _limbs_needed(data.dtype)
-        d = data
-        if valid is not None:
-            d = jnp.where(valid, d, jnp.zeros((), d.dtype))
-        key_slices.append((len(lanes), nl))
-        ri = add_raw(d.astype(jnp.int64))
-        lanes.extend(("limbs", ri, nl, l) for l in range(nl))
-        if valid is not None:
-            kvalid_idx.append(len(lanes))
-            lanes.append(("f32", add_raw(valid.astype(jnp.float32)),
-                          0, 0))
+    # A column whose limbs fit 32 bits rides as int32 (native shifts,
+    # half the carry); a wrapping cast keeps the low 32 bits, which is
+    # all its limbs read.
+    raw = []  # padded [ns, super_rows] arrays carried by the scan
+    for source, index, nl in plan.raws:
+        data, valid = (keys if source == "key" else vals)[index]
+        if nl is None:
+            x = valid.astype(jnp.float32)
         else:
-            kvalid_idx.append(None)
-    val_slices: list = []  # per spec: (start, n_limbs, vstart) or None
-    for spec, val in zip(specs, vals):
-        if spec == "count_star":
-            val_slices.append(None)
-            continue
-        data, valid = val
-        vstart = None
-        if valid is not None:
-            vstart = len(lanes)
-            lanes.append(("f32", add_raw(valid.astype(jnp.float32)),
-                          0, 0))
-        nl = 8  # sums are widened to int64
-        d = data
-        if valid is not None:
-            d = jnp.where(valid, d, jnp.zeros((), d.dtype))
-        val_slices.append((len(lanes), nl, vstart))
-        ri = add_raw(d.astype(jnp.int64))
-        lanes.extend(("limbs", ri, nl, l) for l in range(nl))
-    cnt_idx = len(lanes)
-    lanes.append(("ones", 0, 0, 0))
+            if valid is not None:  # canonical NULL payload: zero
+                data = jnp.where(valid, data, jnp.zeros((), data.dtype))
+            x = data.astype(jnp.int32 if nl <= 4 else jnp.int64)
+        raw.append(pad0(x).reshape(ns, super_rows))
 
-    K = len(lanes)
+    K = len(plan.lanes)
     slot_b = slot.reshape(ns, sb, _MXU_BLOCK)
 
     def step(acc, xs):
@@ -261,30 +360,20 @@ def _mxu_group_reduce_impl(keys, vals, slot, num_groups: int, specs: tuple):
         cols = xs[1:]
         lane_arrays = []
         with scope("agg/limbs"):
-            for kind, ri, nl, l in lanes:
-                if kind == "ones":
+            for lane in plan.lanes:
+                if lane[0] == "ones":
                     lane_arrays.append(
                         jnp.ones((sb, _MXU_BLOCK), dtype=jnp.float32)
                     )
-                elif kind == "f32":
-                    lane_arrays.append(
-                        cols[ri].reshape(sb, _MXU_BLOCK)
-                    )
-                else:  # one limb of an int64 raw column
-                    v = cols[ri].reshape(sb, _MXU_BLOCK)
-                    if l == nl - 1:
-                        lane_arrays.append(
-                            jnp.right_shift(
-                                v, _LIMB_BITS * l
-                            ).astype(jnp.float32)
-                        )
-                    else:
-                        lane_arrays.append(
-                            jnp.bitwise_and(
-                                jnp.right_shift(v, _LIMB_BITS * l),
-                                _LIMB_MASK,
-                            ).astype(jnp.float32)
-                        )
+                    continue
+                v = cols[lane[1]].reshape(sb, _MXU_BLOCK)
+                if lane[0] == "limb":
+                    _kind, _ri, l, masked = lane
+                    v = jnp.right_shift(v, _LIMB_BITS * l)
+                    if masked:  # the unmasked top limb carries the sign
+                        v = jnp.bitwise_and(v, _LIMB_MASK)
+                    v = v.astype(jnp.float32)
+                lane_arrays.append(v)
             lb = jnp.stack(lane_arrays, axis=-1)  # [sb, B, K]
         # masked/invisible rows carry slot == cap: their one-hot row is
         # all zero, so they contribute nothing (incl. the count column)
@@ -310,19 +399,13 @@ def _mxu_group_reduce_impl(keys, vals, slot, num_groups: int, specs: tuple):
         (slot_b, *raw),
     )  # [cap, K]
     with scope("agg/recombine"):
-        return _mxu_recombine(
-            totals, cnt_idx, key_slices, kvalid_idx, keys, slot, cap,
-            pad0, specs, vals, val_slices,
-        )
+        return _mxu_recombine(totals, plan, keys, slot, cap, pad0, specs)
 
 
-def _mxu_recombine(
-    totals, cnt_idx, key_slices, kvalid_idx, keys, slot, cap, pad0,
-    specs, vals, val_slices,
-):
+def _mxu_recombine(totals, plan, keys, slot, cap, pad0, specs):
     """The limb totals of ``_mxu_group_reduce_impl`` back to keys, sums
     and the exact collision verdict."""
-    cnt = totals[:, cnt_idx]
+    cnt = totals[:, plan.ones]
     got = cnt > 0
     safe_cnt = jnp.maximum(cnt, 1)
 
@@ -335,10 +418,10 @@ def _mxu_recombine(
     out_keys = []
     khats = []
     for (start, nl), vidx, (data, valid) in zip(
-        key_slices, kvalid_idx, keys
+        plan.key_slices, plan.key_valid, keys
     ):
         khat = recombine(start, nl) // safe_cnt
-        khats.append((khat, data))
+        khats.append(khat)
         d = khat.astype(data.dtype)
         if vidx is None:
             v = got
@@ -352,7 +435,7 @@ def _mxu_recombine(
     vis = slot < cap
     collision = jnp.asarray(False)
     gslot = jnp.minimum(slot, cap - 1)
-    for (khat, _data), (orig_data, orig_valid) in zip(khats, keys):
+    for khat, (orig_data, orig_valid) in zip(khats, keys):
         d = orig_data
         if orig_valid is not None:
             d = jnp.where(orig_valid, d, jnp.zeros((), d.dtype))
@@ -362,24 +445,18 @@ def _mxu_recombine(
         )
 
     out_vals = []
-    for spec, val, sl in zip(specs, vals, val_slices):
+    for spec, aid in zip(specs, plan.arg_ids):
         if spec == "count_star":
             out_vals.append((cnt.astype(jnp.int64), got))
             continue
-        data, valid = val
-        start, nl, vstart = sl
+        vidx = plan.arg_valid[aid]
+        nonnull = cnt if vidx is None else totals[:, vidx]
         if spec == "count":
-            c = (
-                totals[:, vstart]
-                if vstart is not None
-                else cnt
-            )
-            out_vals.append((c.astype(jnp.int64), got))
+            out_vals.append((nonnull.astype(jnp.int64), got))
             continue
-        # sum
-        s = recombine(start, nl)
-        nonnull = totals[:, vstart] if vstart is not None else cnt
-        out_vals.append((s, (nonnull > 0) & got))
+        out_vals.append(
+            (recombine(*plan.arg_slices[aid]), (nonnull > 0) & got)
+        )
 
     ngroups = jnp.sum(got, dtype=jnp.int32)
     return out_keys, out_vals, got, ngroups, collision

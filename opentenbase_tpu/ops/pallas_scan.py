@@ -82,7 +82,11 @@ def bound(e: E.TExpr, col_bounds: list) -> Optional[float]:
             return None
         return abs(float(e.value))
     if isinstance(e, E.CastE):
-        if not _is_int_type(e.type):
+        # only a cast that keeps the physical value: one between decimal
+        # scales (or decimal and integer) multiplies or divides it
+        # (ops/expr._cast_data), and compile_f32 passes casts through
+        src, dst = e.operand.type, e.type
+        if not _is_int_type(dst) or src.decimal_factor != dst.decimal_factor:
             return None
         return bound(e.operand, col_bounds)
     if isinstance(e, E.UnaryE) and e.op == "-":
@@ -231,19 +235,25 @@ def inline_projects(e: E.TExpr, project_chain: list) -> E.TExpr:
     return e
 
 
-def _subst(e: E.TExpr, exprs) -> E.TExpr:
+def _subst(e, exprs):
+    """``e`` with every Col replaced by ``exprs[index]``; tuples (a
+    function's args, a CASE's (cond, value) pairs) are rewritten
+    element-wise. What holds no Col comes back as the same object."""
     import dataclasses
 
     if isinstance(e, E.Col):
         return exprs[e.index]
-    if dataclasses.is_dataclass(e):
+    if isinstance(e, tuple):
+        out = tuple(_subst(x, exprs) for x in e)
+        return e if all(a is b for a, b in zip(out, e)) else out
+    if isinstance(e, E.TExpr) and dataclasses.is_dataclass(e):
         changes = {}
         for f in dataclasses.fields(e):
             v = getattr(e, f.name)
-            if isinstance(v, E.TExpr):
-                changes[f.name] = _subst(v, exprs)
-            elif isinstance(v, tuple) and v and isinstance(v[0], E.TExpr):
-                changes[f.name] = tuple(_subst(x, exprs) for x in v)
+            if isinstance(v, (E.TExpr, tuple)):
+                nv = _subst(v, exprs)
+                if nv is not v:
+                    changes[f.name] = nv
         if changes:
             return dataclasses.replace(e, **changes)
     return e

@@ -135,3 +135,73 @@ def test_literal_change_reuses_program_not_parameters(jax8):
         "select who, count(*) from lit where k < 1000 group by who "
         "order by who"
     ) == [(1, 12), (7, 40)]
+
+
+def _xla_lane_plans(fx):
+    """The MXU lane bounds in the keys of the XLA grouped programs."""
+    return {k[-1] for k in fx._programs if k[0] != "pallas" and k[-1]}
+
+
+def _fused_row(s, event):
+    return int(dict(s.query("select event, detail from pg_stat_fused"))[event])
+
+
+def test_lane_plan_follows_the_statistics(jax8):
+    """The MXU group reduce cuts as many limbs as the column statistics
+    say the values need. A value that crosses a limb boundary binds a
+    wider program (never the narrower one again); an argument the
+    interval arithmetic cannot bound keeps its dtype's width."""
+    s = Cluster(num_datanodes=2, shard_groups=32).session()
+    s.execute(
+        "create table lp (k bigint, g bigint, v bigint) "
+        "distribute by shard(k)"
+    )
+    s.execute("insert into lp values " + ",".join(
+        f"({i},{i % 20},{i % 101})" for i in range(600)
+    ))
+    fx = s.cluster.fused_executor()
+    # twenty groups: beyond the Pallas kernel's joint key domain, so the
+    # XLA grouped program answers
+    q = "select g, sum(v), count(v) from lp group by g order by g"
+
+    def want(rows):
+        out: dict = {}
+        for g, v in rows:
+            t = out.setdefault(g, [0, 0])
+            t[0] += v
+            t[1] += 1
+        return [(g, t[0], t[1]) for g, t in sorted(out.items())]
+
+    rows = [(i % 20, i % 101) for i in range(600)]
+    assert s.query(q) == want(rows)
+    narrow = _xla_lane_plans(fx)
+    assert len(narrow) == 1
+    (b,) = narrow
+    assert b.key_limbs == ((1, False),) and b.arg_limbs == ((1, False),)
+    assert b.arg_ids == (0, 0)  # sum(v) and count(v): one argument
+    bounded = _fused_row(s, "mxu_plans_bounded")
+    assert bounded >= 1 and _fused_row(s, "mxu_plans_full") == 0
+
+    # 70000 needs three limbs, -3 a sign: the old program must not run
+    s.execute("insert into lp values (1000, 3, 70000), (1001, 4, -3)")
+    rows += [(3, 70000), (4, -3)]
+    assert s.query(q) == want(rows)
+    (wide,) = _xla_lane_plans(fx) - narrow
+    assert wide.arg_limbs == ((3, True),)
+    assert _fused_row(s, "mxu_plans_bounded") > bounded
+
+    # integer division and modulo are outside pallas_scan.bound: every
+    # limb of the dtype, and still the exact answer
+    got = s.query(
+        "select g % 7, sum(v / 2), count(*) from lp group by g % 7 "
+        "order by 1"
+    )
+    ref: dict = {}
+    for g, v in rows:
+        t = ref.setdefault(g % 7, [0, 0])
+        t[0] += int(v / 2)  # PG integer division truncates toward zero
+        t[1] += 1
+    assert got == [(g, t[0], t[1]) for g, t in sorted(ref.items())]
+    assert _fused_row(s, "mxu_plans_full") >= 1
+    (full,) = _xla_lane_plans(fx) - narrow - {wide}
+    assert full.key_limbs == (None,) and full.arg_limbs == (None,)

@@ -167,6 +167,8 @@ def test_explain_analyze_fused_phases(sess):
         r[0] for r in sess.query("select event, detail from pg_stat_fused")
     }
     assert "fused_statements" in events
+    # the MXU group reduce's launches by lane plan (ISSUE 28)
+    assert {"mxu_plans_bounded", "mxu_plans_full"} <= events
     # no timing row is left in the view: the spans and columns carry them
     assert not any(e.endswith(("_ms", "]")) for e in events)
 

@@ -38,6 +38,35 @@ def test_certifier_bounds():
     )
 
 
+@pytest.mark.parametrize("src, dst, want", [
+    (t.INT4, t.INT8, 9.0),  # the physical value is kept
+    (t.INT8, t.SqlType(t.TypeId.DECIMAL, 15, 0), 9.0),
+    (t.INT8, t.SqlType(t.TypeId.DECIMAL, 15, 2), None),  # times 100
+    (t.SqlType(t.TypeId.DECIMAL, 15, 2),
+     t.SqlType(t.TypeId.DECIMAL, 15, 4), None),
+    (t.SqlType(t.TypeId.DECIMAL, 15, 2), t.INT8, None),  # divided
+    (t.INT8, t.FLOAT8, None),
+])
+def test_bound_refuses_a_cast_that_rescales(src, dst, want):
+    """A bound sizes the MXU group reduce's limbs and certifies the
+    Pallas kernel: a cast that multiplies or divides the physical value
+    (ops/expr._cast_data) must not pass its operand's bound through."""
+    assert ps.bound(E.CastE(C(0, src), dst), [9.0]) == want
+
+
+def test_inline_projects_reaches_inside_case():
+    case = E.CaseE(
+        ((E.BinE(">", C(0), K(0), t.BOOL), C(1)),), C(0), t.INT8
+    )
+    proj = (E.BinE("+", C(2), K(1), t.INT8), C(0))
+    got = ps.inline_projects(E.BinE("*", case, K(2), t.INT8), [proj])
+    cols = sorted(
+        n.index for n in E.walk(got) if isinstance(n, E.Col)
+    )
+    assert cols == [0, 2, 2]  # #1 -> scan #0, #0 -> scan #2 + 1, twice
+    assert ps.inline_projects(K(3), [proj]) == K(3)
+
+
 def test_decompose_value_wide_product():
     cb = [1e7, 10.0]
     dec = ps.decompose_value(E.BinE("*", C(0), C(1), t.INT8), cb)

@@ -115,6 +115,38 @@ def test_span_tree_under_trace_queries(wire, kind):
         assert launch.args["join_modes"]
 
 
+def test_bind_span_reports_the_lane_plan(wire):
+    """A grouped statement on the XLA hash program: ``fused.bind`` says
+    how many limb lanes the plan cut and how many the dtypes alone would
+    have, the launch's ``mode`` carries the same K, and pg_stat_fused
+    counts the launch as narrowed by the statistics."""
+    cluster, client = wire
+    # thirty groups (beyond the Pallas kernel's key domain), q < 50 and
+    # p < 107 in one non-negative limb each, sum(q) and count(q) sharing
+    sql = "select d, sum(q), count(q), sum(p) from li group by d order by d"
+
+    def counters():
+        rows = dict(
+            client.execute("select event, detail from pg_stat_fused").rows
+        )
+        return int(rows["mxu_plans_bounded"]), int(rows["mxu_plans_full"])
+
+    before = counters()
+    tr = _traced(cluster, client, sql)
+    bind = next(
+        sp for sp in tr.spans
+        if sp.name == "fused.bind" and "lanes" in sp.args
+    )
+    assert bind.args["program"] == "program_scan_xla_hash"
+    # key d: 1 of 8 limbs; q: 1 of 8 (twice over: its sum, its count);
+    # p: 1 of 8; ones
+    assert bind.args["lanes"] == 4 and bind.args["lanes_full"] == 33
+    launch = next(sp for sp in tr.spans if sp.name == "fused.launch")
+    assert launch.args["mode"] == "hash/64/k4"
+    after = counters()
+    assert after[0] == before[0] + 1 and after[1] == before[1]
+
+
 @pytest.fixture(scope="module")
 def profiled(wire, tmp_path_factory):
     """The three statements under ``jax.profiler.start_trace`` with
