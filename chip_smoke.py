@@ -567,13 +567,14 @@ class Smoke:
         )
         limits = [b for b in self.record["bytes_limit"] if b]
         if len(self.devices) > 1 and limits:
-            # The engine's default exchange budget (4e9 bytes) was
-            # calibrated for ONE 16 GB chip holding all of SF10; on a
-            # four-chip mesh it refuses Q3's 3.84 GiB exchange by 0.7%
-            # and the statement falls to the host executor (PR 21 chip
-            # run). An operator of such a host states the device's
-            # memory through the existing GUC; the smoke does the same,
-            # from what the device reports.
+            # PR 21's four-chip run needed this to pass the exchange
+            # budget: the check held every device's buffers together
+            # (Q3's 3.84 GiB) against the one-device 4e9 bytes. Since
+            # PR 29 it judges one device's share (0.96 GiB here) and
+            # passes without it; the setting stays until a four-chip
+            # run of this smoke shows the Q3 legs on the device with
+            # the default budgets (the GUC also sizes the radix tables
+            # and the probe windows, so their join modes may move).
             limit = min(limits) // 2
             self.statement(
                 f"set device_memory_limit = {limit}", "device_memory_limit"

@@ -21,6 +21,17 @@ from opentenbase_tpu.storage.column import Column
 from opentenbase_tpu.utils.hashing import combine_hashes, hash32_np, hash_strings
 
 
+def route_by_table(key_hash, table, xp=np):
+    """THE placement formula: a key hash picks entry ``hash mod len`` of
+    a route table (shard group -> node for SHARD, the node list for
+    HASH, consumer slots or mesh devices for a redistribute). Inserts,
+    the host executor's and the DN processes' redistributes and the
+    device programs' exchanges all route through it (numpy here, jax
+    with ``xp=jnp``), so a row exchanged onto a table's placement lands
+    where an insert of its key would."""
+    return table[(key_hash % xp.uint32(table.shape[0])).astype(xp.int32)]
+
+
 class Locator:
     """Routing for one table, bound to its distribution spec + node set."""
 
@@ -57,16 +68,24 @@ class Locator:
             bounds = np.asarray(self.spec.range_bounds)
             slot = np.searchsorted(bounds, key.data, side="right")
             return np.asarray(self.node_indices, dtype=np.int32)[slot]
-        h = self.key_hash(key_columns)
-        if s == DistStrategy.SHARD:
-            assert self.shardmap is not None
-            return self.shardmap.route_hash(h)
-        nodes = np.asarray(self.node_indices, dtype=np.int32)
         if s == DistStrategy.MODULO:
+            nodes = np.asarray(self.node_indices, dtype=np.int32)
             key = key_columns[self.spec.key_columns[0]]
             return nodes[(key.data.astype(np.int64) % len(nodes)).astype(np.int32)]
-        # HASH: direct hash onto the node list
-        return nodes[h % np.uint32(len(nodes))]
+        return route_by_table(self.key_hash(key_columns), self.route_table())
+
+    def route_table(self) -> np.ndarray:
+        """Node index per hash bucket: the shard map for SHARD (as it
+        stands now: a moved shard group is followed), the node list for
+        HASH (direct hash onto it)."""
+        if self.spec.strategy == DistStrategy.SHARD:
+            assert self.shardmap is not None
+            return self.shardmap.map
+        if self.spec.strategy == DistStrategy.HASH:
+            return np.asarray(self.node_indices, dtype=np.int32)
+        raise ValueError(
+            f"{self.spec.strategy.value} placement has no route table"
+        )
 
     def key_hash(self, key_columns: dict[str, Column]) -> np.ndarray:
         """uint32 hash of the distribution key for each row."""
@@ -143,9 +162,8 @@ class Locator:
         if hp is None:
             return None
         h, first_phys = hp
-        if s == DistStrategy.SHARD:
-            assert self.shardmap is not None
-            return [int(self.shardmap.route_hash(h)[0])]
+        if s in (DistStrategy.SHARD, DistStrategy.HASH):
+            return [int(route_by_table(h, self.route_table())[0])]
         if s == DistStrategy.MODULO:
             if first_phys is None or isinstance(first_phys, str):
                 return None
@@ -156,7 +174,7 @@ class Locator:
             bounds = np.asarray(self.spec.range_bounds)
             slot = int(np.searchsorted(bounds, key, side="right"))
             return [self.node_indices[slot]]
-        return [self.node_indices[int(h[0]) % len(self.node_indices)]]
+        return None
 
 
 def _physical_key(v: object, ty: t.SqlType | None) -> tuple[object, bool]:

@@ -894,7 +894,7 @@ class DNServer:
                 parts[int(dn)] = wire
         else:  # redistribute — the ONE shared routing formula
             idx_by = partition_batch(
-                out, mo["hash_positions"], len(dest)
+                out, mo["hash_positions"], len(dest), mo.get("route")
             )
             for di in range(len(dest)):
                 parts[int(dest[di][0])] = serde.batch_to_wire(

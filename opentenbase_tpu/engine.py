@@ -8423,6 +8423,8 @@ def _sv_stat_statements(c: Cluster):
               for f in _stmtobs.DEVICE_SPLIT_FIELDS),
             round(float(ent.merge_ms), 3),
             *(int(getattr(ent, f)) for f in _stmtobs.FUSED_COUNT_FIELDS),
+            round(float(ent.exchange_ms), 3),
+            *(int(getattr(ent, f)) for f in _stmtobs.EXCHANGE_COUNT_FIELDS),
         ))
     return rows
 
@@ -8613,6 +8615,10 @@ def _sv_fused(c: Cluster):
             rows.append(
                 ("last_programs", ",".join(dag.last_programs))
             )
+        # what the mesh's motion fragments moved since start-up (the
+        # counts; like every timing, exchange_ms is the ledger's column)
+        for f in _stmtobs.EXCHANGE_COUNT_FIELDS:
+            rows.append((f, str(dag.exchange_totals[f])))
         for r in dag.unsupported:
             rows.append(("unsupported", r))
     for d in fx.dag_demotions:
@@ -9256,6 +9262,9 @@ _SYSTEM_VIEWS: dict[str, tuple] = {
             **{f: t.FLOAT8 for f in _stmtobs.DEVICE_SPLIT_FIELDS},
             "merge_ms": t.FLOAT8,
             **{f: t.INT8 for f in _stmtobs.FUSED_COUNT_FIELDS},
+            # the mesh's motion fragments (zero on a one-device mesh)
+            "exchange_ms": t.FLOAT8,
+            **{f: t.INT8 for f in _stmtobs.EXCHANGE_COUNT_FIELDS},
         },
         _sv_stat_statements,
     ),

@@ -23,12 +23,14 @@ import numpy as np
 
 from opentenbase_tpu import types as t
 from opentenbase_tpu.catalog.catalog import Catalog
+from opentenbase_tpu.catalog.locator import route_by_table
 from opentenbase_tpu.executor.local import LocalExecutor
 from opentenbase_tpu.plan.distribute import (
     COORDINATOR,
     DistributedPlan,
     Fragment,
     RemoteSource,
+    motion_route,
 )
 from opentenbase_tpu.storage.column import Column
 from opentenbase_tpu.storage.table import ColumnBatch
@@ -127,17 +129,20 @@ def concat_batches(batches: list[ColumnBatch]) -> ColumnBatch:
 
 
 def partition_batch(
-    batch: ColumnBatch, hash_positions, ndest: int
+    batch: ColumnBatch, hash_positions, ndest: int, route=None
 ) -> list[np.ndarray]:
-    """Row-index arrays per destination slot. THE one redistribute
-    routing formula — the coordinator's _apply_motion and the DN's
-    peer-exchange push must route identically or rows silently land on
-    the wrong consumer."""
+    """Row-index arrays per destination slot — the coordinator's
+    _apply_motion and the DN's peer-exchange push both partition here,
+    through the locator's own formula. ``route`` is the consumer slot of
+    every bucket of the target placement's route table
+    (``plan.distribute.motion_route``); None hashes over the slots."""
     if batch.nrows == 0:
         return [np.empty(0, np.int64) for _ in range(ndest)]
+    if route is None:
+        route = np.arange(ndest, dtype=np.int32)
     h = hash_batch_columns(batch, list(hash_positions))
-    route = (h % np.uint32(ndest)).astype(np.int64)
-    return [np.nonzero(route == di)[0] for di in range(ndest)]
+    slot = route_by_table(h, np.asarray(route, dtype=np.int32))
+    return [np.nonzero(slot == di)[0] for di in range(ndest)]
 
 
 def hash_batch_columns(batch: ColumnBatch, positions: list[int]) -> np.ndarray:
@@ -865,6 +870,10 @@ class DistExecutor:
                 "xid": peer_xid,
                 "kind": frag.motion,
                 "hash_positions": list(frag.hash_positions),
+                "route": (
+                    None if frag.target is None
+                    else motion_route(frag, self.catalog).tolist()
+                ),
                 "from": node,
                 "dest": [
                     [n, self.dn_channels[n].host,
@@ -961,12 +970,13 @@ class DistExecutor:
             return {n: merged for n in frag.dest_nodes}
         if frag.motion == "redistribute":
             dest = list(frag.dest_nodes)
+            route = motion_route(frag, self.catalog)
             shards: dict[int, list[ColumnBatch]] = {n: [] for n in dest}
             for b in ordered:
                 if b.nrows == 0:
                     continue
                 parts = partition_batch(
-                    b, frag.hash_positions, len(dest)
+                    b, frag.hash_positions, len(dest), route
                 )
                 for di, n in enumerate(dest):
                     shards[n].append(b.take(parts[di]))

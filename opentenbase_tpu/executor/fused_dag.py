@@ -43,7 +43,9 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from opentenbase_tpu import types as t
-from opentenbase_tpu.executor.fused import fetch, named_program
+from opentenbase_tpu.catalog.locator import route_by_table
+from opentenbase_tpu.executor.fused import _pad_shards, fetch, named_program
+from opentenbase_tpu.obs import statements as _stmtobs
 from opentenbase_tpu.obs.trace import scope, span as _span
 from opentenbase_tpu.ops import agg as agg_ops
 from opentenbase_tpu.ops import filter as filt_ops
@@ -52,8 +54,10 @@ from opentenbase_tpu.plan import logical as L
 from opentenbase_tpu.plan import texpr as E
 from opentenbase_tpu.plan.distribute import (
     DistributedPlan,
+    DistributeError,
     Fragment,
     RemoteSource,
+    motion_route,
 )
 from opentenbase_tpu.plan.skey import plan_skey
 from opentenbase_tpu.storage.column import Column
@@ -1515,6 +1519,12 @@ class DagRunner:
         # the device programs the last run launched, in order
         self.last_programs: tuple = ()
         self._frag = None  # fragment the run is on (span arg)
+        # what the motion fragments moved since start-up
+        # (pg_stat_fused's exchange_* rows; the per-statement share is
+        # the ledger's)
+        self.exchange_totals = dict.fromkeys(
+            _stmtobs.EXCHANGE_COUNT_FIELDS, 0
+        )
         # bounded log of plans that fell back to the host path and why —
         # surfaced through pg_stat_fused so demotion is NEVER silent
         self.unsupported: list = []
@@ -1593,11 +1603,12 @@ class DagRunner:
                     if f.motion == "broadcast"
                     else self._run_exchange
                 )
-                self._frag = f.index
-                exchanged[f.index] = run(
-                    f, exchanged, snap, dicts_view, subquery_values, D,
-                    versions,
-                )
+                with self._motion_span(f, D) as xsp:
+                    exchanged[f.index] = run(
+                        f, exchanged, snap, dicts_view, subquery_values,
+                        D, versions, xsp,
+                    )
+                self.exchange_totals["exchange_fragments"] += 1
         self._frag = "final"
         batch = self._run_final(
             final, final_root, exchanged, snap, dicts_view,
@@ -1853,8 +1864,9 @@ class DagRunner:
 
     def _check_hbm_budget(self, cap: int, schema, D: int) -> None:
         """Bail to the host path before an exchange whose buffers would
-        exhaust device memory (a crashed TPU worker is unrecoverable
-        in-process; the host path is merely slower). The budget is the
+        exhaust a device's memory (a crashed TPU worker is unrecoverable
+        in-process; the host path is merely slower). One device's share
+        of the exchange is held against the one-device budget, the
         spill-aware planner's (device_memory_limit GUC > env knob >
         default)."""
         budget = batchplan.resolve_budget(
@@ -1866,13 +1878,68 @@ class DagRunner:
         )
         if est > budget:
             raise DagUnsupported(
-                f"exchange needs ~{est >> 20} MiB (> budget)"
+                f"exchange needs ~{est >> 20} MiB a device (> budget "
+                f"{budget >> 20} MiB)"
             )
+
+    def _device_route(self, frag, D: int) -> np.ndarray:
+        """Mesh device of every bucket of the exchange's route table
+        (``route_by_table``): for a redistribute onto a table's
+        placement the device that holds the shard ``Locator.route_insert``
+        gives the key, else the D devices in turn. A program ARGUMENT: a
+        moved shard group changes the table, never the program."""
+        if frag.target is None:
+            return np.arange(D, dtype=np.int32)
+        nodes = _scan_nodes(self.fx.catalog.get(frag.target.table))
+        per_dev = _pad_shards(len(nodes), D) // D
+        try:
+            return motion_route(
+                frag, self.fx.catalog,
+                {n: i // per_dev for i, n in enumerate(nodes)},
+            )
+        except DistributeError as e:
+            raise DagUnsupported(str(e))
+
+    def _motion_span(self, frag, D: int):
+        """The ``fused.exchange`` span of one motion fragment
+        (``exchange_ms``: its own time, the binds, launches and waits
+        inside it bill their own columns)."""
+        self._frag = frag.index
+        return _span(
+            None, "fused.exchange", "exchange_ms", "exchange_fragments",
+            cat="fused", frag=frag.index, motion=frag.motion,
+            target=(
+                frag.target.label() if frag.target is not None
+                else ("hash" if frag.motion == "redistribute" else None)
+            ),
+            devices=D,
+        )
+
+    def _note_motion(self, xsp, moved: int, cap: int, schema, D: int,
+                     counted: bool) -> None:
+        """What the motion moved between devices, on its span and the
+        statement's ledger: rows that left their device, the bucket
+        slots shipped for them (``slots / rows`` is the padding waste)
+        and those slots' bytes."""
+        slots = D * (D - 1) * cap
+        nbytes = slots * batchplan.exchange_row_bytes(schema)
+        xsp.set(
+            rows=moved, cap=cap, slots=slots, bytes=nbytes,
+            count_pass="run" if counted else "cached",
+        )
+        led = _stmtobs.current()
+        for field, v in (
+            ("exchange_rows", moved), ("exchange_slots", slots),
+            ("exchange_bytes", nbytes),
+        ):
+            self.exchange_totals[field] += v
+            if led is not None:
+                setattr(led, field, getattr(led, field) + v)
 
     # -- exchange (redistribute) fragments ---------------------------------
     def _run_exchange(
         self, frag, exchanged, snap, dicts_view, subquery_values, D,
-        versions,
+        versions, xsp,
     ) -> dict:
         skey = self._frag_skey(frag)
         orientation = self._orientation_for(skey, frag.root)
@@ -1885,13 +1952,14 @@ class DagRunner:
 
         arrays = _collect_arrays(self.fx, frag.root, exchanged, D)
         sig = self._shapes_sig(arrays)
+        route = self._device_route(frag, D)
         while True:
             fo = self._offs(skey)
             # pass 1: per-(src, dest) routed-row counts -> bucket size.
             # Skipped entirely (one round trip saved) when this exact
-            # program + literal values already sized itself against
-            # unchanged data (literals are lifted params, so the skey
-            # alone would alias different constants).
+            # program + literal values + route already sized itself
+            # against unchanged data (literals are lifted params, so the
+            # skey alone would alias different constants).
             ckey = ("xcnt", skey, orientation, hashpos, D, sig, fo)
             prog, comp, jinfo, params = self._bind(
                 ckey,
@@ -1902,12 +1970,13 @@ class DagRunner:
             )
             capkey = (
                 "cap", skey, orientation, hashpos, D, sig, versions, fo,
-                _params_sig(params),
+                _params_sig(params), route.tobytes(),
             )
-            cap = self._caps.get(capkey)
-            if cap is None:
+            sized = self._caps.get(capkey)
+            counted = sized is None
+            if counted:
                 counts, flags = self._fetch(
-                    self._launch(prog, arrays, params, snap),
+                    self._launch(prog, arrays, (params, route), snap),
                     "count pass",
                 )
                 flip = _first_true(flags)
@@ -1916,8 +1985,13 @@ class DagRunner:
                         skey, orientation, flip, jinfo
                     )
                     continue
-                cap = filt_ops.bucket_size(max(int(counts.max()), 1))
-                self._cap_store(capkey, cap)
+                counts = np.asarray(counts).reshape(D, D)
+                sized = (
+                    filt_ops.bucket_size(max(int(counts.max()), 1)),
+                    int(counts.sum() - np.trace(counts)),
+                )
+                self._cap_store(capkey, sized)
+            cap, moved = sized
             self._check_hbm_budget(cap, frag.root.schema, D)
 
             # pass 2: the bucketed all_to_all
@@ -1931,7 +2005,7 @@ class DagRunner:
                 dicts_view, subquery_values,
             )
             cols, valids, rcounts, flags = self._launch(
-                prog, arrays, params, snap, mode=f"cap/{cap}"
+                prog, arrays, (params, route), snap, mode=f"cap/{cap}"
             )
             flip = _first_true(self._fetch(flags, "join flags"))
             if flip is not None:
@@ -1939,6 +2013,9 @@ class DagRunner:
                 continue
             self._accept(prog)
             self._orientations[skey] = orientation
+            self._note_motion(
+                xsp, moved, cap, frag.root.schema, D, counted
+            )
             return {
                 "cols": cols,
                 "valids": valids,
@@ -1950,7 +2027,7 @@ class DagRunner:
     # -- broadcast fragments -----------------------------------------------
     def _run_broadcast(
         self, frag, exchanged, snap, dicts_view, subquery_values, D,
-        versions,
+        versions, xsp,
     ) -> dict:
         """Replicate a (small) fragment's rows to every device: compact
         per source, then all_gather — the broadcast-motion analog of the
@@ -1974,8 +2051,9 @@ class DagRunner:
                 "bcap", skey, orientation, D, sig, versions, fo,
                 _params_sig(params),
             )
-            cap = self._caps.get(capkey)
-            if cap is None:
+            sized = self._caps.get(capkey)
+            counted = sized is None
+            if counted:
                 counts, flags = self._fetch(
                     self._launch(prog, arrays, params, snap),
                     "count pass",
@@ -1986,8 +2064,12 @@ class DagRunner:
                         skey, orientation, flip, jinfo
                     )
                     continue
-                cap = filt_ops.bucket_size(max(int(counts.max()), 1))
-                self._cap_store(capkey, cap)
+                sized = (
+                    filt_ops.bucket_size(max(int(counts.max()), 1)),
+                    int(counts.sum()) * (D - 1),  # each row to D-1 others
+                )
+                self._cap_store(capkey, sized)
+            cap, moved = sized
             self._check_hbm_budget(cap, frag.root.schema, D)
 
             bkey = ("bcast", skey, orientation, D, cap, sig, fo)
@@ -2007,6 +2089,9 @@ class DagRunner:
                 continue
             self._accept(prog)
             self._orientations[skey] = orientation
+            self._note_motion(
+                xsp, moved, cap, frag.root.schema, D, counted
+            )
             return {
                 "cols": cols,
                 "valids": valids,
@@ -2099,8 +2184,11 @@ class DagRunner:
 
         return self._program(program, "broadcast", b), comp, b.jinfo()
 
-    def _routed_eval(self, ev, hashpos, D):
-        def run(blocks, params, snap):
+    def _routed_eval(self, ev, hashpos):
+        """``ev`` plus every row's destination device: the key hash
+        through the exchange's route table (``_device_route``), the
+        locator's own formula."""
+        def run(blocks, params, snap, route):
             env, mask, n, flags = ev(blocks, params, snap)
             hashes = []
             with scope("exchange/route"):
@@ -2114,9 +2202,9 @@ class DagRunner:
                         # never drop here
                         h = jnp.where(v, h, jnp.uint32(0))
                     hashes.append(h)
-                dest = (
-                    combine_hashes(hashes, jnp) % jnp.uint32(D)
-                ).astype(jnp.int32)
+                dest = route_by_table(
+                    combine_hashes(hashes, jnp), route, jnp
+                )
             return env, mask, n, dest, flags
 
         return run
@@ -2130,13 +2218,17 @@ class DagRunner:
             fold_off=fo,
         )
         ev = b.build(root, exchanged, D)
-        routed = self._routed_eval(ev, hashpos, D)
+        routed = self._routed_eval(ev, hashpos)
         mesh = self.fx.mesh
         nflags = _count_inner_joins(root)
 
         def program(arrays, params, snap):
+            params, route = params
+
             def block(blocks):
-                _env, mask, _n, dest, flags = routed(blocks, params, snap)
+                _env, mask, _n, dest, flags = routed(
+                    blocks, params, snap, route
+                )
                 cnt = jax.ops.segment_sum(
                     mask.astype(jnp.int32), dest, num_segments=D
                 )
@@ -2161,15 +2253,19 @@ class DagRunner:
             fold_off=fo,
         )
         ev = b.build(root, exchanged, D)
-        routed = self._routed_eval(ev, hashpos, D)
+        routed = self._routed_eval(ev, hashpos)
         mesh = self.fx.mesh
         ncols = len(root.schema)
         nflags = _count_inner_joins(root)
 
         def program(arrays, params, snap):
+            params, route = params
+
             @_staged
             def block(blocks, st):
-                env, mask, n, dest, flags = routed(blocks, params, snap)
+                env, mask, n, dest, flags = routed(
+                    blocks, params, snap, route
+                )
                 st.to("exchange/bucket")
                 dkey = jnp.where(mask, dest, D)
                 order = jnp.argsort(dkey, stable=True)

@@ -87,12 +87,10 @@ def analyze_report(dplan, ex, verbose: bool = False) -> list[str]:
         by_frag.setdefault(entry["fragment"], []).append(entry)
     lines: list[str] = []
     for frag in dplan.fragments:
-        motion = frag.motion
-        if frag.hash_positions:
-            motion += f"({','.join(map(str, frag.hash_positions))})"
         head = (
             f"Fragment {frag.index}: nodes="
-            f"{','.join(_node_name(n) for n in frag.nodes)} ->{motion}"
+            f"{','.join(_node_name(n) for n in frag.nodes)} "
+            f"->{frag.motion_label()}"
         )
         ms = ex.motion_stats.get(frag.index)
         if ms is not None:

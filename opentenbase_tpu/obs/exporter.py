@@ -595,7 +595,8 @@ def render_cluster_metrics(cluster) -> str:
             # the fused path's split of device_ms and its counts, one
             # series a ledger field (obs/statements.py)
             for f in (_stmtobs.DEVICE_SPLIT_FIELDS + ("merge_ms",)
-                      + _stmtobs.FUSED_COUNT_FIELDS):
+                      + _stmtobs.FUSED_COUNT_FIELDS
+                      + _stmtobs.EXCHANGE_FIELDS):
                 _head(out, f"otb_stmt_{f}", "counter",
                       f"Fused-path {f} per query fingerprint")
                 for e in top:

@@ -13,7 +13,13 @@ by the ``otb:query`` span):
 - every idle gap of the device inside a ``wire.request``, put down to
   ``in_program:<module>`` when a running program covers it, else to the
   innermost span open on the serving thread, else ``unattributed``;
-- launches, syncs and retries per statement.
+- launches, syncs and retries per statement;
+- on a mesh: the statement's fragments with their motion
+  (``fused.exchange``: target, rows, slots, bytes) and the programs each
+  launched, and per chip the time inside ``exchange/all_to_all`` split
+  into the part during which another chip was still computing (hidden:
+  the collective waited on, or overlapped, a peer's work) and the rest
+  (exposed: every other chip was in the collective or idle too).
 
 Two steps, so the arithmetic can be checked on a small recorded trace
 kept as JSON: ``load`` turns the file into plain lists, ``reduce`` does
@@ -42,6 +48,7 @@ SPLIT_SPANS = (
     "fused.gate_wait", "fused.cache", "fused.bind", "fused.launch",
     "fused.wait", "fused.collect",
 )
+ALL_TO_ALL = "all_to_all"  # last word of the exchange's collective scope
 # JAX's own name-stack frames: an ``otb/`` scope ends where one starts
 _FRAMES = frozenset((
     "while", "body", "cond", "closed_call", "shard_map", "pallas_call",
@@ -394,7 +401,8 @@ def reduce(trace: dict) -> dict:
                 "statements": 0, "spans": {}, "launches": 0, "syncs": 0,
                 "retries": 0, "programs_ms": {}, "scopes_ms": {},
                 "unscoped_ops_ms": {}, "device_busy_ms": 0.0,
-                "idle_ms": {}, "join_modes": {},
+                "idle_ms": {}, "join_modes": {}, "fragments": {},
+                "all_to_all": {},
             }
         return c
 
@@ -410,6 +418,22 @@ def reduce(trace: dict) -> dict:
             rec["count"] += 1
             rec["total_ms"] += ms
             rec["self_ms"] += ms - n["kids_ms"]
+            frag = n["args"].get("frag")
+            if frag is not None and name in ("fused.launch",
+                                             "fused.exchange"):
+                f = c["fragments"].setdefault(str(frag), {
+                    "programs": {}, "motion": None, "target": None,
+                    "exchange_ms": 0.0, "rows": 0, "slots": 0, "bytes": 0,
+                })
+                if name == "fused.launch":
+                    prog = str(n["args"].get("program"))
+                    f["programs"][prog] = f["programs"].get(prog, 0) + 1
+                else:
+                    f["motion"] = n["args"].get("motion")
+                    f["target"] = n["args"].get("target")
+                    f["exchange_ms"] += ms
+                    for k in ("rows", "slots", "bytes"):
+                        f[k] += int(n["args"].get(k) or 0)
             if name == "fused.launch":
                 c["launches"] += 1
                 if "retry_of" in n["args"]:
@@ -437,6 +461,8 @@ def reduce(trace: dict) -> dict:
         return None
 
     outside = {"programs_ms": {}, "device_busy_ms": 0.0}
+    collective: list = []  # per chip: the all_to_all ops' intervals
+    compute: list = []  # per chip: the union of every other op
     for plane in dev_planes:
         modules, ops = [], []
         for line in plane["lines"]:
@@ -467,6 +493,12 @@ def reduce(trace: dict) -> dict:
                 _add(c["unscoped_ops_ms"], name.split(" = ")[0][:60],
                      self_ns / 1e6)
         busy = _union([[s, s + d] for _n, s, d, _a in ops])
+        a2a, work = [], []
+        for _n, s, d, a in ops:
+            (a2a if a.get("scope", "").endswith(ALL_TO_ALL)
+             else work).append([s, s + d])
+        collective.append((plane["name"], _union(a2a)))
+        compute.append(_union(work))
         for st in stmts:
             c = cls(st)
             segments = _innermost(st, [])
@@ -480,6 +512,21 @@ def reduce(trace: dict) -> dict:
                 edge = max(edge, e)
                 if edge >= st["end"]:
                     break
+    # exposed against hidden: a chip's time inside the collective while
+    # some OTHER chip was still running an op outside it
+    for i, (chip, spans_i) in enumerate(collective):
+        others = _union([
+            iv for j, ivs in enumerate(compute) if j != i for iv in ivs
+        ])
+        for s, e in spans_i:
+            st = stmt_at((s + e) / 2.0)
+            if st is None:
+                continue
+            rec = cls(st)["all_to_all"].setdefault(
+                chip, {"total_ms": 0.0, "hidden_ms": 0.0}
+            )
+            rec["total_ms"] += (e - s) / 1e6
+            rec["hidden_ms"] += _overlap(s, e, others) / 1e6
     for c in classes.values():
         c["unscoped_ops_ms"] = dict(sorted(
             c["unscoped_ops_ms"].items(), key=lambda kv: -kv[1]
@@ -493,7 +540,20 @@ def reduce(trace: dict) -> dict:
         c["fused_self_ms"] = c["spans"].get("fused", {}).get("self_ms", 0.0)
     return {
         "statements": len(stmts), "classes": classes, "outside": outside,
+        "chips": len(dev_planes),
     }
+
+
+def _overlap(lo: float, hi: float, intervals: list) -> float:
+    """Length of [lo, hi) covered by sorted disjoint ``intervals``."""
+    i = bisect.bisect_left(intervals, [lo, lo])
+    if i and intervals[i - 1][1] > lo:
+        i -= 1
+    total = 0.0
+    while i < len(intervals) and intervals[i][0] < hi:
+        total += max(min(intervals[i][1], hi) - max(intervals[i][0], lo), 0)
+        i += 1
+    return total
 
 
 def _causes(idle: dict, lo: float, hi: float, modules: list,
@@ -528,7 +588,10 @@ def _causes(idle: dict, lo: float, hi: float, modules: list,
 
 def render(report: dict) -> str:
     """The report as text, per-statement means."""
-    out = [f"{report['statements']} statements traced"]
+    chips = max(report.get("chips", 1), 1)
+    out = [f"{report['statements']} statements traced"
+           + (f" on {chips} chips (device times summed over them)"
+              if chips > 1 else "")]
     for key, c in sorted(
         report["classes"].items(),
         key=lambda kv: -kv[1]["spans"].get(
@@ -560,6 +623,30 @@ def render(report: dict) -> str:
             ) + f" sum={c['dispatch_split_sum_ms'] / n:.3f}"
             f" fused_self={c['fused_self_ms'] / n:.3f}"
         )
+        if c.get("fragments"):
+            out.append("  fragments (per statement):")
+            for frag, f in sorted(c["fragments"].items()):
+                progs = ", ".join(
+                    f"{k} x{v / n:.2f}" for k, v in f["programs"].items()
+                )
+                line = f"    {frag:<6} {progs}"
+                if f["motion"]:
+                    line += (
+                        f" | {f['motion']} to {f['target']}: "
+                        f"{f['exchange_ms'] / n:.3f} ms, rows "
+                        f"{f['rows'] / n:.0f}, slots {f['slots'] / n:.0f}"
+                        f", bytes {f['bytes'] / n:.0f}"
+                    )
+                out.append(line)
+        if c.get("all_to_all"):
+            out.append("  exchange/all_to_all per chip (ms a statement): "
+                       "total = exposed + hidden behind other chips' work")
+            for chip, r in sorted(c["all_to_all"].items()):
+                out.append(
+                    f"    {chip:<16} {r['total_ms'] / n:>10.3f} = "
+                    f"{(r['total_ms'] - r['hidden_ms']) / n:.3f} + "
+                    f"{r['hidden_ms'] / n:.3f}"
+                )
         busy = c["device_busy_ms"]
         out.append(f"  device busy {busy / n:.3f} ms; by program:")
         for k, v in sorted(c["programs_ms"].items(), key=lambda kv: -kv[1]):

@@ -84,6 +84,18 @@ LEDGER_FIELDS = (
     "device_launches",
     "device_syncs",
     "fused_retries",
+    # the mesh's motion fragments (fused_dag's ``fused.exchange`` span,
+    # one a redistribute or broadcast; none on a one-device mesh, where
+    # the DAG is one program): the span's own ms — a seventh part of
+    # device_ms, its binds, launches and waits bill their own columns —
+    # then rows that left their device, the bucket slots shipped for
+    # them (slots / rows is the padding waste), those slots' bytes, and
+    # the fragments
+    "exchange_ms",
+    "exchange_rows",
+    "exchange_bytes",
+    "exchange_slots",
+    "exchange_fragments",
 )
 
 #: the six parts of device_ms (what is left is the fused span's own)
@@ -92,6 +104,12 @@ DEVICE_SPLIT_FIELDS = (
     "collect_ms",
 )
 FUSED_COUNT_FIELDS = ("device_launches", "device_syncs", "fused_retries")
+#: the mesh's motions: the span's own ms, then four counts
+EXCHANGE_COUNT_FIELDS = (
+    "exchange_rows", "exchange_bytes", "exchange_slots",
+    "exchange_fragments",
+)
+EXCHANGE_FIELDS = ("exchange_ms",) + EXCHANGE_COUNT_FIELDS
 
 
 class ResourceLedger:
@@ -479,6 +497,14 @@ def resource_footer(ledger: ResourceLedger, total_ms: float) -> list[str]:
             ) + "".join(
                 f" {f}={int(getattr(ledger, f))}"
                 for f in FUSED_COUNT_FIELDS
+            )
+        )
+    if ledger.exchange_fragments:
+        lines.append(
+            f"  exchange: own={float(ledger.exchange_ms):.3f} ms"
+            + "".join(
+                f" {f.removeprefix('exchange_')}={int(getattr(ledger, f))}"
+                for f in EXCHANGE_COUNT_FIELDS
             )
         )
     if ledger.wait_ms:

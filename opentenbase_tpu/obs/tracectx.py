@@ -40,12 +40,25 @@ from opentenbase_tpu.analysis.racewatch import shared_state
 _tls = threading.local()
 
 
+def _hex_id(nbytes: int) -> str:
+    """Random hex that starts with a letter. The ids ride a
+    ``jax.profiler`` TraceMe as metadata, and the profiler stores a
+    value that reads as a number as one: an id of decimal digits alone
+    (one in ~1,800 of eight bytes) came back as an int, one with a
+    single ``e`` among them (``1234e56789012345``) as a float, and the
+    span no longer joined its trace. A leading a, b, e or f reads as
+    neither."""
+    raw = bytearray(os.urandom(nbytes))
+    raw[0] |= 0xA0
+    return raw.hex()
+
+
 def new_trace_id() -> str:
-    return os.urandom(16).hex()
+    return _hex_id(16)
 
 
 def new_span_id() -> str:
-    return os.urandom(8).hex()
+    return _hex_id(8)
 
 
 class TraceContext:

@@ -121,10 +121,12 @@ def exchange_row_bytes(schema) -> int:
 
 
 def exchange_bytes(cap: int, row_bytes: int, devices: int) -> int:
-    """Footprint of one bucketed all_to_all exchange: the (D+1, cap)
-    scatter buffer, the all_to_all result, and consumer copies — ~3x
-    the bucketed payload (measured at TPC-H SF10 Q3 on one 16GB v5e)."""
-    return cap * (devices + 1) * devices * row_bytes * 3
+    """ONE device's footprint of a bucketed all_to_all exchange: its
+    (D+1, cap) scatter buffer, its (D, cap) all_to_all result, and
+    consumer copies — ~3x its bucketed payload (the factor measured at
+    TPC-H SF10 Q3 on one 16GB v5e). Every device holds the same, so
+    this is what a one-device budget is held against."""
+    return cap * (devices + 1) * row_bytes * 3
 
 
 def probe_window_width(

@@ -29,7 +29,7 @@ decomposed into sum+count and re-divided in a finalize projection.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Optional
 
 from opentenbase_tpu import types as t
@@ -52,6 +52,14 @@ class Dist:
     nodes: tuple[int, ...]
     strategy: Optional[DistStrategy] = None  # sharded only
     key_positions: tuple[int, ...] = ()  # sharded only; () = underivable
+    # sharded only: the table whose locator places these rows on
+    # ``key_positions`` (its strategy, its node list and, for SHARD, the
+    # shard map) — what a redistribute of the other join side targets
+    table: Optional[str] = None
+    # sharded only: other output positions that hold the same values as
+    # ``key_positions`` row for row (an inner join equated them), so a
+    # grouping on either is whole per node
+    alt_positions: tuple[tuple[int, ...], ...] = ()
 
     @staticmethod
     def single(node: int) -> "Dist":
@@ -62,8 +70,25 @@ class Dist:
         return Dist("replicated", tuple(nodes))
 
     @staticmethod
-    def sharded(nodes, strategy=None, key_positions=()) -> "Dist":
-        return Dist("sharded", tuple(nodes), strategy, tuple(key_positions))
+    def sharded(nodes, strategy=None, key_positions=(), table=None) -> "Dist":
+        return Dist(
+            "sharded", tuple(nodes), strategy, tuple(key_positions), table
+        )
+
+    def rekeyed(self, key_positions, alts=()) -> "Dist":
+        """The same placement seen through other output positions
+        (``alts``: further position tuples equal to them)."""
+        keys = tuple(key_positions)
+        alts = tuple(a for a in map(tuple, alts) if a and a != keys)
+        if not keys and alts:
+            keys, alts = alts[0], alts[1:]
+        return replace(self, key_positions=keys, alt_positions=alts)
+
+    def all_keys(self) -> tuple:
+        """``key_positions`` and every tuple equal to it."""
+        if not self.key_positions:
+            return ()
+        return (self.key_positions,) + self.alt_positions
 
     @property
     def is_single(self) -> bool:
@@ -105,6 +130,23 @@ def eq_consts(scan, pred) -> dict:
     return consts
 
 
+@dataclass(frozen=True)
+class Placement:
+    """A table's own placement as the target of a redistribute: rows go
+    where ``Locator.route_insert`` of that table puts the same key
+    (redistribute_path aims at the other side's distribution through
+    the same locator, pathnode.c:1469). The route itself — for SHARD
+    the shard map — is read from the catalog when the motion runs, so a
+    cached plan follows a MOVE DATA."""
+
+    table: str
+    strategy: DistStrategy
+    nodes: tuple[int, ...]
+
+    def label(self) -> str:
+        return f"{self.strategy.value}:{self.table}"
+
+
 @dataclass
 class Fragment:
     """One plan fragment + the motion delivering its output upward."""
@@ -119,6 +161,42 @@ class Fragment:
     # sorted-gather: merge on these sort keys at the consumer (the
     # merge-sorted ResponseCombiner, execRemote.h:150)
     merge_keys: tuple[L.SortKey, ...] = ()
+    # 'redistribute' onto a table's placement (None: hash over the
+    # consumer slots, both join sides moving alike)
+    target: Optional[Placement] = None
+
+    def motion_label(self) -> str:
+        """``redistribute(1) to shard:customer`` — EXPLAIN's one form."""
+        out = self.motion
+        if self.hash_positions:
+            out += f"({','.join(map(str, self.hash_positions))})"
+        if self.target is not None:
+            out += f" to {self.target.label()}"
+        return out
+
+
+def motion_route(frag: Fragment, catalog, slot_of: Optional[dict] = None):
+    """Per entry of the target's route table, where its rows go: by
+    default the consumer slot (index into ``frag.dest_nodes``);
+    ``slot_of`` maps a node to something else (the mesh runner: its
+    device). None for a plain hash redistribute."""
+    import numpy as np
+
+    if frag.target is None:
+        return None
+    if slot_of is None:
+        slot_of = {n: i for i, n in enumerate(frag.dest_nodes)}
+    table = catalog.get(frag.target.table).locator.route_table()
+    lookup = np.full(max(int(table.max()), *slot_of) + 1, -1, np.int32)
+    lookup[list(slot_of)] = list(slot_of.values())
+    route = lookup[table]
+    if (route < 0).any():
+        raise DistributeError(
+            f"{frag.target.label()} routes to node "
+            f"{int(table[route < 0][0])}, which the plan's redistribute "
+            "does not reach: replan"
+        )
+    return route
 
 
 @dataclass
@@ -131,11 +209,10 @@ class DistributedPlan:
     def explain(self) -> str:
         lines = []
         for f in self.fragments:
-            dest = (
-                f"->{f.motion}"
-                + (f"({','.join(map(str, f.hash_positions))})" if f.hash_positions else "")
+            lines.append(
+                f"Fragment {f.index} on nodes {list(f.nodes)} "
+                f"->{f.motion_label()}:"
             )
-            lines.append(f"Fragment {f.index} on nodes {list(f.nodes)} {dest}:")
             lines.append(L.explain_tree(f.root, 1))
         lines.append("Coordinator:")
         lines.append(L.explain_tree(self.root, 1))
@@ -169,10 +246,14 @@ class Distributor:
         hash_positions: tuple[int, ...] = (),
         dest_nodes: tuple[int, ...] = (),
         merge_keys: tuple[L.SortKey, ...] = (),
+        target: Optional[Placement] = None,
     ) -> RemoteSource:
         idx = len(self.fragments)
         self.fragments.append(
-            Fragment(idx, plan, nodes, motion, hash_positions, dest_nodes, merge_keys)
+            Fragment(
+                idx, plan, nodes, motion, hash_positions, dest_nodes,
+                merge_keys, target,
+            )
         )
         return RemoteSource(idx, plan.schema)
 
@@ -224,7 +305,9 @@ class Distributor:
                 else:
                     positions = []
                     break
-            return plan, Dist.sharded(nodes, meta.dist.strategy, tuple(positions))
+            return plan, Dist.sharded(
+                nodes, meta.dist.strategy, tuple(positions), plan.table
+            )
         return plan, Dist.sharded(nodes)  # roundrobin
 
     def _d_valuesscan(self, plan: L.ValuesScan):
@@ -241,7 +324,7 @@ class Distributor:
         ):
             pruned = self._prune_nodes(child, plan.predicate, dist)
             if pruned is not None:
-                dist = Dist.sharded(pruned, dist.strategy, dist.key_positions)
+                dist = replace(dist, nodes=tuple(pruned))
         return L.Filter(child, plan.predicate, plan.schema), dist
 
     def _prune_nodes(self, scan: L.Scan, pred: E.TExpr, dist: Dist):
@@ -267,14 +350,11 @@ class Distributor:
             for out_i, ex in enumerate(plan.exprs):
                 if isinstance(ex, E.Col) and ex.index not in remap:
                     remap[ex.index] = out_i
-            if all(p in remap for p in dist.key_positions):
-                new_dist = Dist.sharded(
-                    dist.nodes,
-                    dist.strategy,
-                    tuple(remap[p] for p in dist.key_positions),
-                )
-            else:
-                new_dist = Dist.sharded(dist.nodes, dist.strategy, ())
+            kept = [
+                tuple(remap[p] for p in ks) for ks in dist.all_keys()
+                if all(p in remap for p in ks)
+            ]
+            new_dist = dist.rekeyed(kept[0] if kept else (), kept[1:])
         return L.Project(child, plan.exprs, plan.schema), new_dist
 
     # -- aggregation -------------------------------------------------------
@@ -291,15 +371,15 @@ class Distributor:
             for gi, g in enumerate(plan.group_exprs):
                 if isinstance(g, E.Col):
                     covered.add(g.index)
-            if set(dist.key_positions) <= covered:
+            whole = [ks for ks in dist.all_keys() if set(ks) <= covered]
+            if whole:
                 pos_map = {}
                 for gi, g in enumerate(plan.group_exprs):
                     if isinstance(g, E.Col) and g.index not in pos_map:
                         pos_map[g.index] = gi
-                return local, Dist.sharded(
-                    dist.nodes,
-                    dist.strategy,
-                    tuple(pos_map[p] for p in dist.key_positions),
+                return local, dist.rekeyed(
+                    (pos_map[p] for p in whole[0]),
+                    [[pos_map[p] for p in ks] for ks in whole[1:]],
                 )
 
         if any(a.distinct for a in plan.aggs):
@@ -446,8 +526,8 @@ class Distributor:
             and jt != "full"
         ):
             if set(ldist.nodes) <= set(rdist.nodes):
-                return rebuild(left, right), Dist.sharded(
-                    ldist.nodes, ldist.strategy, out_key_positions
+                return rebuild(left, right), ldist.rekeyed(
+                    out_key_positions
                 )
         if (
             ldist.kind == "replicated"
@@ -459,14 +539,16 @@ class Distributor:
                 rpos = tuple(
                     nleft + p for p in rdist.key_positions
                 ) if rdist.key_positions else ()
-                return rebuild(left, right), Dist.sharded(
-                    rdist.nodes, rdist.strategy, rpos
-                )
+                return rebuild(left, right), rdist.rekeyed(rpos)
 
         # colocated shard-to-shard join
         if self._colocated(plan, ldist, rdist):
-            return rebuild(left, right), Dist.sharded(
-                ldist.nodes, ldist.strategy, out_key_positions
+            nleft = len(plan.left.schema)
+            alts = [
+                tuple(nleft + p for p in ks) for ks in rdist.all_keys()
+            ] if jt == "inner" else []
+            return rebuild(left, right), ldist.rekeyed(
+                out_key_positions, list(ldist.alt_positions) + alts
             )
 
         # cost-based motion choice (redistribute_path vs broadcast,
@@ -488,9 +570,7 @@ class Distributor:
                 # that preserve the LEFT side: a right/full join would
                 # emit each unmatched broadcast row once per left shard
                 rsrc = self._motion_broadcast(right, rdist, ldist.nodes)
-                return rebuild(left, rsrc), Dist.sharded(
-                    ldist.nodes, ldist.strategy, out_key_positions
-                )
+                return rebuild(left, rsrc), ldist.rekeyed(out_key_positions)
             if (
                 jt == "inner"
                 and rdist.kind == "sharded"
@@ -505,24 +585,65 @@ class Distributor:
                 rpos = tuple(
                     nleft + p for p in rdist.key_positions
                 ) if rdist.key_positions else ()
-                return rebuild(lsrc, right), Dist.sharded(
-                    rdist.nodes, rdist.strategy, rpos
-                )
+                return rebuild(lsrc, right), rdist.rekeyed(rpos)
 
-        # general case: redistribute both sides by the join keys onto the
-        # union nodeset (the squeue all-to-all, squeue.c:403+). Sides whose
-        # keys are not simple columns are first projected to append the key.
+        # general case. Sides whose keys are not simple columns are first
+        # projected to append the key.
         if not plan.left_keys:
             # cross join: broadcast the right side to the left's nodes
             if ldist.kind == "sharded":
                 rsrc = self._motion_broadcast(right, rdist, ldist.nodes)
-                return rebuild(left, rsrc), Dist.sharded(
-                    ldist.nodes, ldist.strategy, out_key_positions
-                )
+                return rebuild(left, rsrc), ldist.rekeyed(out_key_positions)
             lc = self._to_single(left, ldist)
             rc = self._to_single(right, rdist)
             return rebuild(lc, rc), Dist.single(COORDINATOR)
 
+        # a side that its table's own locator already places on the join
+        # keys stays where it is; the other side is cut and redistributed
+        # ONTO that placement (redistribute_path targets the other side's
+        # distribution, pathnode.c:1469). The join then keeps the kept
+        # side's placement and key positions, so a grouping on the
+        # distribution key is known whole per node.
+        kept = self._kept_side(plan, ldist, rdist) if jt != "full" else None
+        if kept is not None:
+            side, order = kept
+            kd = ldist if side == "L" else rdist
+            target = Placement(kd.table, kd.strategy, kd.nodes)
+            nleft = len(plan.left.schema)
+            if side == "L":
+                moved_keys = [plan.right_keys[j] for j in order]
+                lsrc = left
+                rsrc = self._motion_by_keys(
+                    right, rdist, moved_keys, kd.nodes, target=target
+                )
+                kept_pos = kd.key_positions
+                moved_pos = tuple(
+                    nleft + p for p in _cols_or_none(moved_keys) or ()
+                )
+                # unmatched right rows null-extend the kept columns
+                kept_whole = jt != "right"
+            else:
+                moved_keys = [plan.left_keys[j] for j in order]
+                lsrc = self._motion_by_keys(
+                    left, ldist, moved_keys, kd.nodes, target=target
+                )
+                rsrc = right
+                kept_pos = tuple(nleft + p for p in kd.key_positions)
+                moved_pos = _cols_or_none(moved_keys) or ()
+                # the right columns are null-extended or not in the output
+                kept_whole = jt in ("inner", "right")
+            # where the kept columns cannot say where a row lives, the
+            # moved keys still do; an inner join makes the two equal
+            if not kept_whole:
+                outpos, alts = moved_pos, []
+            else:
+                outpos = kept_pos
+                alts = [moved_pos] if jt == "inner" else []
+            return rebuild(lsrc, rsrc), kd.rekeyed(outpos, alts)
+
+        # neither side is placed on the join keys: redistribute both by
+        # hash of the keys onto the union nodeset (the squeue all-to-all,
+        # squeue.c:403+)
         dest = tuple(
             sorted(set(ldist.nodes) | set(rdist.nodes))
             if ldist.kind == "sharded" and rdist.kind == "sharded"
@@ -568,14 +689,68 @@ class Distributor:
         want = list(zip(ldist.key_positions, rdist.key_positions))
         return all(p in pairs for p in want)
 
-    def _motion_by_keys(self, plan, dist, keys, dest, force=False):
-        """Redistribute ``plan`` by hash of join ``keys`` onto ``dest``.
-        ``force`` redistributes even a replicated input — required for
-        FULL joins, where an in-place replica would emit its unmatched
-        rows once per dest node."""
+    def _kept_side(self, plan: L.Join, ldist: Dist, rdist: Dist):
+        """('L'|'R', order) when that side can stay in place with the
+        other redistributed onto its placement, else None. ``order[i]``
+        is the join-key pair equating the kept side's i-th distribution
+        key column. Both qualify: the one estimated larger stays."""
+        lo = self._placed_on_keys(plan.left, ldist, plan.left_keys,
+                                  plan.right_keys)
+        ro = self._placed_on_keys(plan.right, rdist, plan.right_keys,
+                                  plan.left_keys)
+        if lo is not None and ro is not None:
+            from opentenbase_tpu.plan import costs
+
+            lest = costs.estimate_rows(plan.left, self.catalog)
+            rest = costs.estimate_rows(plan.right, self.catalog)
+            return ("L", lo) if lest >= rest else ("R", ro)
+        if lo is not None:
+            return "L", lo
+        if ro is not None:
+            return "R", ro
+        return None
+
+    def _placed_on_keys(self, node, dist: Dist, keys, other_keys):
+        """Indices of the join-key pairs that equate ``dist``'s
+        distribution key columns, in the distribution key's order — or
+        None unless ``dist`` is a table's whole SHARD/HASH placement
+        and every one of its key columns is equated to a value that
+        hashes the same (bare column, same physical representation)."""
+        if (
+            dist.kind != "sharded"
+            or dist.table is None
+            or not dist.key_positions
+            or dist.strategy not in (DistStrategy.SHARD, DistStrategy.HASH)
+        ):
+            return None
+        meta = self.catalog.get(dist.table)
+        if dist.nodes != tuple(meta.node_indices):
+            return None  # pruned to some nodes: no longer the placement
+        order = []
+        for p in dist.key_positions:
+            for j, (k, ok) in enumerate(zip(keys, other_keys)):
+                if (
+                    isinstance(k, E.Col) and k.index == p
+                    and _hash_alike(node.schema[p].type, ok.type)
+                ):
+                    order.append(j)
+                    break
+            else:
+                return None
+        return order
+
+    def _motion_by_keys(
+        self, plan, dist, keys, dest, force=False, target=None
+    ):
+        """Redistribute ``plan`` by hash of join ``keys`` onto ``dest``
+        — through ``target``'s route when the other side stays on its
+        table's placement. ``force`` redistributes even a replicated
+        input — required for FULL joins, where an in-place replica would
+        emit its unmatched rows once per dest node."""
         src_override = None
         if (
-            dist.kind == "sharded"
+            target is None
+            and dist.kind == "sharded"
             and dist.strategy == DistStrategy.HASH
             and dist.nodes == dest
             and dist.key_positions
@@ -622,6 +797,7 @@ class Distributor:
             "redistribute",
             tuple(positions),
             tuple(dest),
+            target=target,
         )
         if exprs:
             # hide the appended key columns again
@@ -708,6 +884,30 @@ def _base_col(e: E.TExpr) -> Optional[int]:
     if isinstance(e, E.CastE):
         return _base_col(e.operand)
     return None
+
+
+def _cols_or_none(keys) -> Optional[tuple]:
+    """Output positions of ``keys`` when every one is a plain column."""
+    pos = tuple(_base_col(k) for k in keys)
+    return None if any(p is None for p in pos) else pos
+
+
+_INT_KEYS = (t.TypeId.INT4, t.TypeId.INT8)
+
+
+def _hash_alike(a: t.SqlType, b: t.SqlType) -> bool:
+    """Equal values of the two types hash to the same placement: every
+    integer width goes through one sign-extended 64-bit path
+    (utils/hashing.py); dates, timestamps and decimals of one scale share
+    a physical representation. Text hashes per dictionary and floats by
+    their float32 bits: such keys move both sides, as before."""
+    if a.id in _INT_KEYS and b.id in _INT_KEYS:
+        return True
+    if a.id != b.id:
+        return False
+    if a.id == t.TypeId.DECIMAL:
+        return a.scale == b.scale
+    return a.id in (t.TypeId.DATE, t.TypeId.TIMESTAMP, t.TypeId.BOOL)
 
 
 def distribute_statement(

@@ -26,7 +26,8 @@ import numpy as np
 from opentenbase_tpu import types as t
 from opentenbase_tpu.plan import logical as L
 from opentenbase_tpu.plan import texpr as E
-from opentenbase_tpu.plan.distribute import RemoteSource
+from opentenbase_tpu.catalog.distribution import DistStrategy
+from opentenbase_tpu.plan.distribute import Placement, RemoteSource
 from opentenbase_tpu.storage.column import Column
 from opentenbase_tpu.storage.table import ColumnBatch
 
@@ -39,6 +40,7 @@ def _registry() -> dict:
             if isinstance(cls, type) and dataclasses.is_dataclass(cls):
                 out[name] = cls
     out["RemoteSource"] = RemoteSource
+    out["Placement"] = Placement  # a redistribute's target
     return out
 
 
@@ -61,6 +63,8 @@ def plan_to_jsonable(x):
         return [plan_to_jsonable(v) for v in x]
     if isinstance(x, t.TypeId):
         return {"$id": x.value}
+    if isinstance(x, DistStrategy):
+        return {"$dist": x.value}
     if isinstance(x, (np.integer,)):
         return int(x)
     if isinstance(x, (np.floating,)):
@@ -77,6 +81,8 @@ def plan_from_jsonable(x):
             return t.SqlType(t.TypeId(tid), prec, scale)
         if "$id" in x:
             return t.TypeId(x["$id"])
+        if "$dist" in x:
+            return DistStrategy(x["$dist"])
         if "$tu" in x:
             return tuple(plan_from_jsonable(v) for v in x["$tu"])
         if "$n" in x:
