@@ -10,7 +10,10 @@ equivalent of that whole pipeline:
 - every fragment compiles to one jitted ``shard_map`` program over the
   'dn' mesh axis;
 - a ``redistribute`` motion is a bucketed ``jax.lax.all_to_all`` — the
-  DataPump exchange as an ICI collective;
+  DataPump exchange as an ICI collective. Each device sorts its routed
+  rows once by destination with every column riding the sort, and the
+  ``(D, cap)`` slab it sends is D contiguous slices of each sorted
+  column: no search, no row gather, no scatter;
 - the join is a sort + searchsorted lookup against the (verified-unique)
   build side — the TPU-friendly formulation of a hash join, since sorted
   binary search vectorizes where per-tuple hash probing does not;
@@ -31,6 +34,7 @@ flips the build side or gives up so the host path answers instead.
 
 from __future__ import annotations
 
+import math
 from functools import wraps
 from typing import Callable, Optional
 
@@ -71,9 +75,11 @@ import os
 from opentenbase_tpu.ops import join as join_ops
 from opentenbase_tpu.plan import batchplan
 
-# Exchange buffers materialize ~3x their payload (bucket scatter, the
-# all_to_all result, consumer copies). Beyond this budget the DAG bails
-# to the host path instead of crashing the TPU worker on HBM exhaustion
+# An exchange holds, a device, the bucketing sort's operands going in and
+# coming out (the fragment's padded rows plus one bucket of padding),
+# the (D, cap) slab it sends and the (D, cap) result it receives
+# (plan/batchplan.exchange_bytes). Beyond this budget the DAG bails to
+# the host path instead of crashing the TPU worker on HBM exhaustion
 # (observed at TPC-H SF10 Q3 on one 16GB v5e). The ``device_memory_limit``
 # GUC (threaded through FusedExecutor.device_memory_limit) overrides the
 # env knob at runtime — plan/batchplan.resolve_budget is the one resolver.
@@ -979,6 +985,48 @@ def _topk_idx(packed, live, k: int):
     return idx, val
 
 
+def _rows_a_device(sig: tuple, D: int) -> int:
+    """The widest leaf array of a fragment (``_shapes_sig``), a device:
+    the padded row count its program works on. A join answers at its
+    probe side's rows, so no fragment's root is wider than its widest
+    leaf."""
+    return max(
+        (math.prod(shape) for blk in sig for shape, _dt in blk),
+        default=0,
+    ) // D
+
+
+def _dest_counts(mask, dest, D: int):
+    """Live rows bound for each of the ``D`` devices, by ``D`` masked
+    reductions (a ``segment_sum`` over the rows lowers to a
+    scatter-add)."""
+    return jnp.stack([
+        # otb_lint: ignore[int32-width] -- a count of one device's padded rows, which index as int32 (n < 2^31)
+        jnp.sum(mask & (dest == d), dtype=jnp.int32) for d in range(D)
+    ])
+
+
+def _pack_bits(planes: list) -> list:
+    """Boolean planes as bits of ``uint32`` words, 32 a word: the
+    validity of every nullable column rides a sort or a collective as
+    one operand."""
+    words = []
+    for w in range(0, len(planes), 32):
+        word = jnp.zeros(planes[w].shape, dtype=jnp.uint32)
+        for j, p in enumerate(planes[w:w + 32]):
+            word = word | (p.astype(jnp.uint32) << j)
+        words.append(word)
+    return words
+
+
+def _unpack_bits(words: list, count: int) -> list:
+    """The ``count`` planes ``_pack_bits`` packed."""
+    return [
+        ((words[i // 32] >> (i % 32)) & 1).astype(jnp.bool_)
+        for i in range(count)
+    ]
+
+
 def _collect_arrays(fx, root, exchanged: dict, D: int) -> list:
     return [
         _leaf_arrays(fx, n, exchanged, D) for n in _walk_leaves(root)
@@ -1862,19 +1910,22 @@ class DagRunner:
         self._retry(f"join{flip} duplicate build keys: flip sides")
         return self._flip(orientation, flip)
 
-    def _check_hbm_budget(self, cap: int, schema, D: int) -> None:
+    def _check_hbm_budget(
+        self, cap: int, schema, D: int, rows: int = 0
+    ) -> None:
         """Bail to the host path before an exchange whose buffers would
         exhaust a device's memory (a crashed TPU worker is unrecoverable
         in-process; the host path is merely slower). One device's share
         of the exchange is held against the one-device budget, the
         spill-aware planner's (device_memory_limit GUC > env knob >
-        default)."""
+        default). ``rows``: the padded rows a device the redistribute's
+        bucketing sort carries (a broadcast sorts none)."""
         budget = batchplan.resolve_budget(
             int(getattr(self.fx, "device_memory_limit", 0) or 0),
             "OTB_EXCHANGE_HBM_BUDGET", EXCHANGE_HBM_BUDGET,
         )
         est = batchplan.exchange_bytes(
-            cap, batchplan.exchange_row_bytes(schema), D
+            cap, batchplan.exchange_row_bytes(schema), D, rows
         )
         if est > budget:
             raise DagUnsupported(
@@ -1992,7 +2043,9 @@ class DagRunner:
                 )
                 self._cap_store(capkey, sized)
             cap, moved = sized
-            self._check_hbm_budget(cap, frag.root.schema, D)
+            self._check_hbm_budget(
+                cap, frag.root.schema, D, _rows_a_device(sig, D)
+            )
 
             # pass 2: the bucketed all_to_all
             xkey = ("xchg", skey, orientation, hashpos, D, cap, sig, fo)
@@ -2229,9 +2282,7 @@ class DagRunner:
                 _env, mask, _n, dest, flags = routed(
                     blocks, params, snap, route
                 )
-                cnt = jax.ops.segment_sum(
-                    mask.astype(jnp.int32), dest, num_segments=D
-                )
+                cnt = _dest_counts(mask, dest, D)
                 return cnt[None], [jnp.reshape(f, (1,)) for f in flags]
 
             return shard_map(
@@ -2266,45 +2317,64 @@ class DagRunner:
                 env, mask, n, dest, flags = routed(
                     blocks, params, snap, route
                 )
-                st.to("exchange/bucket")
-                dkey = jnp.where(mask, dest, D)
-                order = jnp.argsort(dkey, stable=True)
-                sdkey = jnp.take(dkey, order)
-                pos = jnp.arange(n) - jnp.searchsorted(
-                    sdkey, sdkey, side="left"
+                # after ONE stable sort on the destination the rows of
+                # bucket d ARE the contiguous run [off[d], off[d] +
+                # cnt[d]): the payload rides the sort, every bucket is a
+                # slice. No search, no row gather, no scatter (the chip
+                # prices those at seconds where a sort streams).
+                st.to("exchange/bucket/sort")
+                dkey = jnp.where(mask, dest, D).astype(jnp.int32)
+                nullable = [
+                    i for i in range(ncols) if env[i][1] is not None
+                ]
+                vwords = _pack_bits(
+                    [jnp.broadcast_to(env[i][1], (n,)) for i in nullable]
                 )
-                pos = jnp.clip(pos, 0, cap - 1)
-                out_cols = []
-                out_valids = []
-                for i in range(ncols):
-                    d, v = env[i]
-                    sd = jnp.take(jnp.broadcast_to(d, (n,)), order)
-                    buck = jnp.zeros((D + 1, cap), dtype=sd.dtype)
-                    buck = buck.at[sdkey, pos].set(sd)[:D]
-                    with jax.named_scope("all_to_all"):
-                        out_cols.append(jax.lax.all_to_all(
-                            buck, "dn", split_axis=0, concat_axis=0
-                        ))
-                    # always exchange a validity plane: keeps the output
-                    # pytree static regardless of input nullability
-                    vv = (
-                        jnp.ones(n, dtype=jnp.bool_)
-                        if v is None
-                        else jnp.broadcast_to(v, (n,))
+                sorted_ = jax.lax.sort(
+                    [dkey]
+                    + [jnp.broadcast_to(env[i][0], (n,))
+                       for i in range(ncols)]
+                    + vwords,
+                    num_keys=1, is_stable=True,
+                )
+                st.to("exchange/bucket/slab")
+                cnt = _dest_counts(mask, dest, D)
+                off = jnp.cumsum(cnt) - cnt
+                live = jnp.arange(cap)[None, :] < cnt[:, None]
+
+                def slab(col):
+                    # cap slots beyond the last row: no start is ever
+                    # clamped (a clamped start would shift a bucket)
+                    ext = jnp.concatenate(
+                        [col, jnp.zeros(cap, dtype=col.dtype)]
                     )
-                    sv = jnp.take(vv, order)
-                    vb = jnp.zeros((D + 1, cap), dtype=jnp.bool_)
-                    vb = vb.at[sdkey, pos].set(sv)[:D]
-                    with jax.named_scope("all_to_all"):
-                        out_valids.append(jax.lax.all_to_all(
-                            vb, "dn", split_axis=0, concat_axis=0
-                        ))
-                cnt = jax.ops.segment_sum(
-                    mask.astype(jnp.int32), dest, num_segments=D
-                )
+                    rows = jnp.stack([
+                        jax.lax.dynamic_slice(ext, (off[d],), (cap,))
+                        for d in range(D)
+                    ])
+                    return jnp.where(live, rows, jnp.zeros_like(rows))
+
+                slabs = [slab(c) for c in sorted_[1:]]
+                st.to("exchange/bucket/all_to_all")
+                recv = [
+                    jax.lax.all_to_all(
+                        b, "dn", split_axis=0, concat_axis=0
+                    )
+                    for b in slabs
+                ]
                 rcnt = jax.lax.all_to_all(
                     cnt.reshape(D, 1), "dn", split_axis=0, concat_axis=0
                 ).reshape(D)
+                st.to("exchange/bucket/slab")
+                out_cols = recv[:ncols]
+                # a validity plane a column keeps the output pytree
+                # static: a never-NULL column's is the received slots
+                # themselves, a nullable one's its bit of the words
+                filled = jnp.arange(cap)[None, :] < rcnt[:, None]
+                planes = dict(zip(
+                    nullable, _unpack_bits(recv[ncols:], len(nullable))
+                ))
+                out_valids = [planes.get(i, filled) for i in range(ncols)]
                 return (
                     out_cols,
                     out_valids,

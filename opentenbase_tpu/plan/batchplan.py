@@ -120,13 +120,17 @@ def exchange_row_bytes(schema) -> int:
     )
 
 
-def exchange_bytes(cap: int, row_bytes: int, devices: int) -> int:
-    """ONE device's footprint of a bucketed all_to_all exchange: its
-    (D+1, cap) scatter buffer, its (D, cap) all_to_all result, and
-    consumer copies — ~3x its bucketed payload (the factor measured at
-    TPC-H SF10 Q3 on one 16GB v5e). Every device holds the same, so
+def exchange_bytes(
+    cap: int, row_bytes: int, devices: int, rows: int = 0
+) -> int:
+    """ONE device's footprint of an exchange whose buckets hold ``cap``
+    rows: the operands of the bucketing sort going in and coming out
+    extended to ``rows + cap`` (``rows``: the fragment's padded rows a
+    device; 0 for a broadcast, which compacts ``cap`` rows by a take and
+    sorts no payload), the ``(D, cap)`` slab it sends and the ``(D,
+    cap)`` result of the collective. Every device holds the same, so
     this is what a one-device budget is held against."""
-    return cap * (devices + 1) * row_bytes * 3
+    return (2 * (rows + cap) + 2 * devices * cap) * row_bytes
 
 
 def probe_window_width(

@@ -532,6 +532,13 @@ def test_exchange_span_and_ledger_columns(tpch4):
     assert d[11] == sum(x.args["slots"] for x in xs)
     assert d[12] == 2
     assert d[8] > 0  # exchange_ms: the spans' own time
+    # how the buckets are filled moves none of it: the same rows go to
+    # the same places in the same padded slabs as before the bucketing
+    # sort carried the payload (the parent commit's reading, this data)
+    assert [(x.args["rows"], x.args["cap"], x.args["slots"],
+             x.args["bytes"]) for x in xs] == [
+        (2211, 256, 3072, 86016), (527, 128, 1536, 64512)]
+    assert d[9:12] == [2738.0, 150528.0, 4608.0]
     totals = t.fused_rows()
     assert int(totals["exchange_fragments"][-1]) >= 2 and "exchange_ms" not in totals
     assert int(totals["exchange_rows"][-1]) >= d[9]
@@ -660,12 +667,30 @@ def test_exchange_budget_is_one_devices_share():
     s.execute("set enable_fused_execution = on")
     assert s.query(Q3) == host
     dag = c._fused._dag
-    # the widest exchange as the run sized it
-    caps = [v[0] for k, v in dag._caps.items() if k[0] == "cap"]
-    schema = plan_of(c, Q3).fragments[0].root.schema
-    row_bytes = batchplan.exchange_row_bytes(schema)
-    one = batchplan.exchange_bytes(max(caps), row_bytes, 4)
-    assert one == max(caps) * 5 * row_bytes * 3
+    # every exchange as a run holds it against the budget: its bucket
+    # size, its schema and the padded rows a device its sort carries
+    held = []
+    check = dag._check_hbm_budget
+    dag._check_hbm_budget = lambda *a: (held.append(a), check(*a))[1]
+    try:
+        assert s.query(Q3) == host
+    finally:
+        del dag._check_hbm_budget
+    assert len(held) == 2 and all(a[3] > 0 for a in held), held
+    ests = [
+        batchplan.exchange_bytes(
+            cap, batchplan.exchange_row_bytes(schema), D, rows)
+        for cap, schema, D, rows in held
+    ]
+    one = max(ests)
+    # the buffers that exist: the sort's operands in and out over the
+    # rows and the pad, the slab sent and the collective's result
+    assert ests == [
+        (2 * (rows + cap) + 2 * 4 * cap)
+        * batchplan.exchange_row_bytes(schema)
+        for cap, schema, _D, rows in held
+    ]
+    schema = held[0][1]
     # every device's buffers together pass this budget; one device's
     # share fits: the statement stays on the device
     s.execute(f"set device_memory_limit = {one + 1}")
@@ -706,8 +731,12 @@ def test_xplane_reduction_lists_fragments_and_exposed_all_to_all():
                 ["jit_program_dag_gsort(2)", 10 * ms, 4 * ms, {}],
             ]},
             {"name": "XLA Ops", "events": [
-                ["%fusion.1", 1 * ms, (work_end - 1) * ms,
+                ["%fusion.1", 1 * ms, (work_end - 2) * ms,
                  {"scope": "exchange/route"}],
+                ["%sort.1", (work_end - 1) * ms, 0.75 * ms,
+                 {"scope": "exchange/bucket/sort"}],
+                ["%dynamic-slice.1", (work_end - 0.25) * ms, 0.25 * ms,
+                 {"scope": "exchange/bucket/slab"}],
                 ["%all-to-all.1", work_end * ms, (9 - work_end) * ms, a2a],
                 ["%sort.2", 10 * ms, 4 * ms,
                  {"scope": "join0/merge/sort"}],
@@ -742,6 +771,14 @@ def test_xplane_reduction_lists_fragments_and_exposed_all_to_all():
     assert c["all_to_all"]["/device:TPU:1"] == pytest.approx(
         {"total_ms": 2.0, "hidden_ms": 0.0})
     assert c["device_busy_ms"] == pytest.approx(2 * (8 + 4))  # chip-ms
+    # the bucketing is attributed whole: its sort, its slices and the
+    # collective each under a scope of their own, nothing unscoped
+    assert c["scopes_ms"] == pytest.approx({
+        "exchange/route": 2 + 5, "exchange/bucket/sort": 2 * 0.75,
+        "exchange/bucket/slab": 2 * 0.25,
+        "exchange/bucket/all_to_all": 5 + 2, "join0/merge/sort": 2 * 4,
+    })
+    assert c["unscoped_ops_ms"] == {}
     text = profile.render(report)
     assert "on 2 chips" in text and "fragments (per statement)" in text
     assert "redistribute to shard:t" in text
