@@ -9,12 +9,11 @@ One process (it owns the chip) drives the normal statement path once:
 
 against a durable ``Cluster`` + ``ClusterServer`` built exactly as
 ``cli/otb_server.py`` builds them, at TPC-H SF10 scale (60M-row
-``lineitem``, 15M ``orders``, 1.5M ``customer`` in ``bench.py``'s
-shapes), and checks every answer against a plain numpy computation over
-the same seeded arrays. After EVERY statement it reads ``pg_stat_fused``,
-``pg_stat_pallas`` and ``pg_cluster_health`` over the wire and fails on
-the first sign that the host executor, an XLA fallback or another
-platform produced the rows.
+``lineitem``, 15M ``orders``, 1.5M ``customer``), and checks every
+answer against a plain numpy computation over the same seeded arrays.
+After EVERY statement it reads ``pg_stat_fused``, ``pg_stat_pallas`` and
+``pg_cluster_health`` over the wire and fails on the first sign that the
+host executor, an XLA fallback or another platform produced the rows.
 
     python chip_smoke.py                      # needs a TPU; exits 2 without
     python chip_smoke.py --dry-run-cpu --rows 200000   # sandbox debugging
@@ -101,7 +100,7 @@ T0 = time.monotonic()
 
 
 # ---------------------------------------------------------------------------
-# data (bench.py's shapes, regenerated here from --seed)
+# data (regenerated here from --seed)
 # ---------------------------------------------------------------------------
 
 
@@ -434,9 +433,9 @@ class Smoke:
             f"{self.args.datanodes} datanodes, data_dir {self.data_dir}")
 
     def bulk_append(self, table: str, arrays: dict) -> None:
-        """Pre-sharded append straight into the shard stores, the way
-        bench.py's _bulk_append does (COPY FROM is a row-at-a-time CSV
-        loop that would take tens of minutes at this size)."""
+        """Pre-sharded append straight into the shard stores (COPY
+        FROM is a row-at-a-time CSV loop that would take tens of
+        minutes at this size)."""
         from opentenbase_tpu.storage.column import Column
         from opentenbase_tpu.storage.table import ColumnBatch
 

@@ -18,8 +18,9 @@ rwlock's per-table mutexes are all born on one line and acquired in
 order, not an inversion — which is exactly what the ALLOWLIST is for:
 every entry names the lock pair and the reason the order is safe.
 
-Enabling must happen BEFORE the locks of interest are created (the
-tier-1 lockwatch smoke sets the env var and then imports the engine);
+Enabling must happen BEFORE the locks of interest are created
+(tests/test_static_analysis.py's engine drive sets the env var for a
+subprocess that then imports the engine);
 locks created pre-enable stay native and invisible, by design — the
 watchdog is opt-in instrumentation, never a production tax.
 """
@@ -264,7 +265,7 @@ def find_cycles(include_allowed: bool = False) -> list:
 
 def report(stream=None) -> int:
     """Print the verdict; returns the number of NON-allowlisted
-    cycles (the tier-1 smoke's exit code)."""
+    cycles."""
     stream = stream if stream is not None else sys.stderr
     cycles = find_cycles()
     with _graph_mu:

@@ -20,8 +20,9 @@ before ``__init__`` returns are construction-private and exempt.
 
 Zero production tax: with the env var unset, ``@shared_state`` returns
 the class untouched and the import does nothing.  Enabling must happen
-before the annotated classes are DEFINED (the tier-1 racewatch smoke
-sets the env var and then imports the engine), mirroring lockwatch's
+before the annotated classes are DEFINED (tests/test_race_analysis.py
+sets the env var for a subprocess that then imports the engine),
+mirroring lockwatch's
 create-after-enable rule.
 
 Races surface as ``analysis.core.Finding``s with rule ``race-dynamic``

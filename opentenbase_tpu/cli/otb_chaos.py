@@ -21,7 +21,7 @@ primary refuses its own warmed result-cache hit with SQLSTATE 72000,
 promotions stay bounded under flap, and the ex-primary rejoins.
 
 One JSON verdict line per run plus a final ``chaos_gate`` summary
-line, bench_gate style; exit code 4 on any violated invariant.
+line; exit code 4 on any violated invariant.
 
 A failing run replays from its printed seed alone: the schedule, the
 prob-fault draws, the matrix flap timings, the reconnect jitter, and

@@ -5,12 +5,13 @@
     python -m opentenbase_tpu.cli.otb_lint --list-rules
     python -m opentenbase_tpu.cli.otb_lint            # full report
 
-``--check`` is the tier-1 stage: it diffs the tree's findings against
+``--check`` is the ratchet (tests/test_static_analysis.py runs it on
+the shipped tree): it diffs the tree's findings against
 ``tools/lint_baseline.json`` and exits nonzero ONLY on findings absent
 from the baseline (new debt). Burned-down entries print as a hint;
 ``--update-baseline`` harvests them (and blesses reviewed additions)
 by regenerating the file. The final line of ``--check`` is a one-line
-JSON verdict (the ``bench_gate`` convention) so CI logs grep clean:
+JSON verdict, so CI logs grep clean:
 
     {"lint_gate": "ok", "findings": 41, "new": 0, "fixed": 0, ...}
 

@@ -7,7 +7,8 @@ ratchet (the otb_lint shape, second instance).
     python -m opentenbase_tpu.cli.otb_race --format json
     python -m opentenbase_tpu.cli.otb_race --bless-dynamic KEY --reason WHY
 
-``--check`` is the tier-1 stage: it diffs the tree's STATIC findings
+``--check`` is the ratchet (tests/test_race_analysis.py runs it on the
+shipped tree): it diffs the tree's STATIC findings
 (``race-guard-mismatch`` / ``race-check-then-act`` /
 ``lock-release-path``) against ``tools/race_baseline.json`` and exits
 nonzero only on findings absent from it.  The baseline is SHARED with

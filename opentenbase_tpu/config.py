@@ -94,10 +94,6 @@ GUCS: dict = {
     "ssl_cert_file": (_str, ""),
     "ssl_key_file": (_str, ""),
     "enable_pallas_scan": (_bool, None),
-    # Pallas MXU bucket-probe for the radix hash join
-    # (ops/pallas_join.py): None = engine decides (on for real TPU
-    # backends, off elsewhere — interpret mode is for tests, not speed)
-    "enable_pallas_join": (_bool, None),
     # device join formulation (executor/fused_dag.py + the host
     # executor via OTB_JOIN_MODE): 'auto' picks fold > radix >
     # sort-merge by planner cardinality estimates; forcing a mode is
@@ -106,7 +102,7 @@ GUCS: dict = {
     # spill-aware batch planner (plan/batchplan.py): HBM budget in
     # bytes every data-dependent device allocation (radix tables,
     # exchange buffers, probe windows) is sized against; 0 = use the
-    # per-op env knobs / baked-in defaults
+    # per-op constants of plan/batchplan.py
     "device_memory_limit": (_int, 0),
     "enable_fast_query_shipping": (_bool, True),  # otb_lint: ignore[guc-unread] -- reserved: the FQS fast-path (pgxc_FQS_planner) is not built yet; accepted so conf files written for the reference load unchanged
     # within-fragment scan workers on DN processes (execParallel.c's
@@ -252,8 +248,8 @@ GUCS: dict = {
     # group commit (ROADMAP item 4a): concurrent committers share one
     # WAL fsync (leader election in storage/persist.WAL.flush_to) and
     # one batched GTS grant (engine.GtsCommitBatcher). Off = the seed's
-    # fsync-per-commit + RPC-per-commit path (the bench differential's
-    # baseline and an operator escape hatch).
+    # fsync-per-commit + RPC-per-commit path (what tests/test_write_path.py
+    # compares against, and an operator escape hatch).
     "enable_group_commit": (_bool, True),
     # PG's commit_delay/commit_siblings: the flush leader naps
     # commit_delay_us before its fsync — only when at least
@@ -278,8 +274,9 @@ GUCS: dict = {
     # mutate storage, compaction is a background amortizer. Off
     # restores the legacy fold-on-read read path (host scans fold
     # first; the device cache compacts before refresh and keeps the
-    # flat >8-entry MVCC full-plane cutoff) — the HTAP bench baseline
-    # on the same binary, and an operator escape hatch.
+    # flat >8-entry MVCC full-plane cutoff) — what
+    # tests/test_delta_scan.py compares against on the same binary, and
+    # an operator escape hatch.
     "enable_delta_scan": (_bool, True),
     # Elastic rebalance copy throttle (bytes/s of shard-move traffic a
     # background ADD/REMOVE NODE may stream; <= 0 = unthrottled). Read

@@ -2496,7 +2496,7 @@ class Session:
             # Durability rung: synchronous_commit=off skips the fsync
             # wait entirely; every other mode rides the group flush
             # (enable_group_commit=off degrades to fsync-per-commit,
-            # the seed behavior — the bench differential's baseline)
+            # the seed behavior)
             commit_lsn = p.log_commit_group(
                 [
                     (node, table, tw.ins_ranges, tw.del_idx)
@@ -5075,7 +5075,7 @@ class Session:
     def _delta_scan(self) -> bool:
         """enable_delta_scan GUC: scans iterate base + pending deltas
         without absorbing (on = default); off restores the legacy
-        fold-on-read path — the HTAP bench baseline."""
+        fold-on-read path."""
         return self.gucs.get("enable_delta_scan", True) is not False
 
     def _execute_dplan(
@@ -5316,27 +5316,6 @@ class Session:
         from opentenbase_tpu.executor.fused import FusedUnsupported
 
         fused_gate = self.cluster._fused_lock
-        # session GUC shadows the device planners read (join mode
-        # selection + the spill-aware batch planner's HBM budget)
-        fx.join_mode = str(self.gucs.get("join_mode", "auto"))
-        try:
-            fx.device_memory_limit = int(
-                self.gucs.get("device_memory_limit", 0) or 0
-            )
-        except (TypeError, ValueError):
-            fx.device_memory_limit = 0
-        fx.enable_pallas_join = self.gucs.get("enable_pallas_join")
-        # device-platform watchdog expectation: explicit, from the GUC
-        # alone ('' — the default / RESET — switches the watchdog off
-        # without an executor recycle)
-        fx.expected_platform = str(
-            self.gucs.get("expected_device_platform", "") or ""
-        )
-        # scannable delta plane: off = the device cache compacts before
-        # refresh + legacy MVCC replay cutoff (the fold-on-read
-        # baseline the HTAP bench differentials against)
-        fx.cache.legacy_fold = not self._delta_scan()
-
         # pallas single-pass kernel: default-on on a TPU mesh, opt-in
         # elsewhere (interpret mode is for tests, not speed)
         use_pallas = self.gucs.get(
@@ -5356,6 +5335,28 @@ class Session:
             with _span(self, "fused.gate_wait", "gate_ms", cat="fused"):
                 fused_gate.acquire()
             try:
+                # session GUC shadows the device planners read, written
+                # under the gate: the executor is the cluster's, and a
+                # program is built (and cached) under its holder's
+                # values. Join mode selection + the spill-aware batch
+                # planner's HBM budget
+                fx.join_mode = str(self.gucs.get("join_mode", "auto"))
+                try:
+                    fx.device_memory_limit = int(
+                        self.gucs.get("device_memory_limit", 0) or 0
+                    )
+                except (TypeError, ValueError):
+                    fx.device_memory_limit = 0
+                # device-platform watchdog expectation: explicit, from
+                # the GUC alone ('' — the default / RESET — switches the
+                # watchdog off without an executor recycle)
+                fx.expected_platform = str(
+                    self.gucs.get("expected_device_platform", "") or ""
+                )
+                # scannable delta plane: off = the device cache compacts
+                # before refresh + legacy MVCC replay cutoff (fold on
+                # read)
+                fx.cache.legacy_fold = not self._delta_scan()
                 # before-counter for the EXPLAIN delta-tail attribution
                 # — under the gate, so only THIS statement's refresh
                 # lands in the delta

@@ -270,7 +270,7 @@ class DistExecutor:
         self.node_generation = int(node_generation or 0)
         # scannable delta plane (storage/table.ScanView): scans iterate
         # base + pending deltas without absorbing; off restores the
-        # legacy fold-on-read path (the HTAP bench baseline)
+        # legacy fold-on-read path
         self.delta_scan = bool(delta_scan)
         # multi-coordinator serving: on a PEER CN the local stores are a
         # REPLICA, not the authoritative copy — a fragment failover to

@@ -243,7 +243,7 @@ class DeviceCache:
             # under the fused gate (engine._try_fused)
             "h2d_bytes": 0,
         }
-        # enable_delta_scan = off (HTAP bench baseline): refreshes fold
+        # enable_delta_scan = off: refreshes fold
         # stores before reading and keep the legacy per-entry MVCC
         # replay with its flat >8 full-plane cutoff — the pre-delta-
         # plane behavior on the same binary
@@ -370,8 +370,8 @@ class DeviceCache:
     def _store_views(self, stores):
         """One coherent non-folding ScanView per store. Under
         ``legacy_fold`` (enable_delta_scan = off) pending deltas are
-        compacted FIRST — reproducing the fold-on-read read path the
-        HTAP bench baselines against, on the same binary."""
+        compacted FIRST — reproducing the fold-on-read read path on
+        the same binary."""
         if self.legacy_fold:
             for s in stores:
                 if getattr(s, "pending_delta_rows", 0):
@@ -1025,9 +1025,7 @@ def _is_float(ty) -> bool:
 # fused path streams fixed-width shard windows instead of caching the
 # whole table in HBM (one v5e has 16 GB; leave room for intermediates
 # and other tables).
-SCAN_HBM_BUDGET = int(
-    os.environ.get("OTB_SCAN_HBM_BUDGET", 8_000_000_000)
-)
+SCAN_HBM_BUDGET = 8_000_000_000
 
 
 def _match_partial_fragment(root: L.LogicalPlan) -> Optional[_FusablePartial]:
@@ -1086,15 +1084,10 @@ def enable_compile_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
-# Process-lifetime pallas demotion count (bench_gate reads this): the
-# per-executor counter dies with its executor, and bench legs recycle
-# executors to free device residency.
-PALLAS_DEMOTIONS_TOTAL = [0]
-
 # Process-lifetime platform-demotion count (the r04/r05 class: a cluster
-# configured for TPU silently answering from CPU). Module-level for the
-# same reason as the pallas total — the exporter's counter must stay
-# monotone across executor recycles.
+# configured for TPU silently answering from CPU). Module-level because
+# the per-executor counter dies with its executor, and the exporter's
+# counter must stay monotone across executor recycles.
 PLATFORM_DEMOTIONS_TOTAL = [0]
 
 
@@ -1118,12 +1111,12 @@ class FusedExecutor:
         # silent-CPU-run bug class must show on a scrape.
         self.pallas_fallbacks: list[str] = []
         self.pallas_demotions = 0  # monotone counter (exporter)
-        # session GUC shadows (engine threads them in before every
-        # fused attempt): join formulation override + the spill-aware
-        # planner's HBM budget (plan/batchplan.py)
+        # session GUC shadows (the engine writes them under the fused
+        # gate, so a program is built under its holder's values): join
+        # formulation override + the spill-aware planner's HBM budget
+        # (plan/batchplan.py)
         self.join_mode = "auto"
         self.device_memory_limit = 0
-        self.enable_pallas_join = None
         # Unexpected exceptions that demoted a fused/DAG query to the
         # host path (VERDICT r2 §weak-3: the blanket except must not be
         # invisible). Exposed via pg_stat_fused; the monotone counter
@@ -1180,10 +1173,6 @@ class FusedExecutor:
         if str(key) not in self.pallas_fallbacks:
             self.pallas_fallbacks.append(str(key))
         self.pallas_demotions += 1
-        # process-wide running total: executors are torn down and
-        # rebuilt between bench legs (cluster._fused = None frees HBM
-        # residency), and the gate must still see EVERY demotion
-        PALLAS_DEMOTIONS_TOTAL[0] += 1
         _log.warning(
             "pallas kernel demoted to XLA path for %s:\n%s",
             key,

@@ -70,22 +70,8 @@ from opentenbase_tpu.utils.hashing import combine_hashes, hash32_jnp
 
 OPTIMISTIC_GROUP_CAP = 1 << 16
 
-import os
-
 from opentenbase_tpu.ops import join as join_ops
 from opentenbase_tpu.plan import batchplan
-
-# An exchange holds, a device, the bucketing sort's operands going in and
-# coming out (the fragment's padded rows plus one bucket of padding),
-# the (D, cap) slab it sends and the (D, cap) result it receives
-# (plan/batchplan.exchange_bytes). Beyond this budget the DAG bails to
-# the host path instead of crashing the TPU worker on HBM exhaustion
-# (observed at TPC-H SF10 Q3 on one 16GB v5e). The ``device_memory_limit``
-# GUC (threaded through FusedExecutor.device_memory_limit) overrides the
-# env knob at runtime — plan/batchplan.resolve_budget is the one resolver.
-EXCHANGE_HBM_BUDGET = int(
-    os.environ.get("OTB_EXCHANGE_HBM_BUDGET", 4_000_000_000)
-)
 
 # Dimension-fold: an inner join whose build side is this small (and at
 # most half the probe) is attempted as a dense direct-index lookup — the
@@ -94,9 +80,7 @@ EXCHANGE_HBM_BUDGET = int(
 # path. A runtime density flag falls back when the build keys aren't a
 # gap-free unique range (the replicated-dim join shippability the
 # reference reaches through pgxcship.c:139, done the TPU way).
-DIMFOLD_MAX_BUILD = int(
-    os.environ.get("OTB_DIMFOLD_MAX", 33_554_432)
-)
+DIMFOLD_MAX_BUILD = 33_554_432
 
 
 class _Stage:
@@ -1079,7 +1063,7 @@ class _Builder:
         self.join_mode = str(getattr(fx_h, "join_mode", "auto"))
         self.radix_budget = batchplan.resolve_budget(
             int(getattr(fx_h, "device_memory_limit", 0) or 0),
-            "OTB_RADIX_HBM_BUDGET", batchplan.DEFAULT_EXCHANGE_BUDGET,
+            batchplan.DEFAULT_EXCHANGE_BUDGET,
         )
         # windowed execution: (leaf id, width) — that scan leaf reads
         # only [wstart, wstart+width) of each shard's rows per run; the
@@ -1404,11 +1388,7 @@ class _Builder:
         # the MXU one-hot bucket probe (ops/pallas_join.py) rides only
         # on a TPU mesh; elsewhere interpret mode would measure the
         # emulator (the enable_pallas_scan convention)
-        pallas_probe = (
-            use_radix
-            and self.platform == "tpu"
-            and getattr(self.fx, "enable_pallas_join", True) is not False
-        )
+        pallas_probe = use_radix and self.platform == "tpu"
         # 'pallas' joins the program's join modes (EXPLAIN ANALYZE,
         # pg_stat_fused last_join_modes) when the probe is traced in
         note_mode = self.modes.add
@@ -1917,12 +1897,12 @@ class DagRunner:
         exhaust a device's memory (a crashed TPU worker is unrecoverable
         in-process; the host path is merely slower). One device's share
         of the exchange is held against the one-device budget, the
-        spill-aware planner's (device_memory_limit GUC > env knob >
-        default). ``rows``: the padded rows a device the redistribute's
+        spill-aware planner's (the device_memory_limit GUC, else the
+        constant). ``rows``: the padded rows a device the redistribute's
         bucketing sort carries (a broadcast sorts none)."""
         budget = batchplan.resolve_budget(
             int(getattr(self.fx, "device_memory_limit", 0) or 0),
-            "OTB_EXCHANGE_HBM_BUDGET", EXCHANGE_HBM_BUDGET,
+            batchplan.DEFAULT_EXCHANGE_BUDGET,
         )
         est = batchplan.exchange_bytes(
             cap, batchplan.exchange_row_bytes(schema), D, rows
@@ -3200,7 +3180,7 @@ class DagRunner:
         streams in shard-row windows. None when it all fits."""
         budget = batchplan.resolve_budget(
             int(getattr(self.fx, "device_memory_limit", 0) or 0),
-            "OTB_DAG_WINDOW_BUDGET", batchplan.DEFAULT_WINDOW_BUDGET,
+            batchplan.DEFAULT_WINDOW_BUDGET,
         )
         leaves = [
             lf for lf in _walk_leaves(root) if isinstance(lf, L.Scan)

@@ -107,9 +107,9 @@ class LocalExecutor:
         # every operator boundary. None (the overwhelmingly common case)
         # costs one attribute test per operator.
         self._cancel_check = cancel_check
-        # enable_delta_scan = off (the HTAP bench baseline / escape
-        # hatch): scans fold pending deltas before reading, restoring
-        # the pre-delta-plane read path on the same binary
+        # enable_delta_scan = off (the escape hatch): scans fold pending
+        # deltas before reading, restoring the pre-delta-plane read
+        # path on the same binary
         self._fold_on_read = fold_on_read
         # delta-resident rows the last _eval_scan served (EXPLAIN
         # ANALYZE evidence that the scan read the delta plane directly)
@@ -239,8 +239,8 @@ class LocalExecutor:
                 rec["detail"] = f"{rec.get('detail') or ''} ({jm})".strip()
         elif rec["op"] == "Scan" and self.last_scan_delta_rows:
             # how much of the scan answered from the delta plane
-            # without a fold — the read-after-write evidence the tier-1
-            # smoke asserts on
+            # without a fold — the read-after-write evidence
+            # tests/test_delta_scan.py asserts on
             rec["detail"] = (
                 f"{rec.get('detail') or ''} (delta-resident: "
                 f"{self.last_scan_delta_rows} rows)"
@@ -1248,11 +1248,7 @@ class LocalExecutor:
         ):
             return None
         plan = batchplan.plan_radix_join(
-            build.n, probe.n,
-            batchplan.resolve_budget(
-                0, "OTB_RADIX_HBM_BUDGET",
-                batchplan.DEFAULT_EXCHANGE_BUDGET,
-            ),
+            build.n, probe.n, batchplan.DEFAULT_EXCHANGE_BUDGET
         )
         if plan is None or plan.passes != 1:
             return None

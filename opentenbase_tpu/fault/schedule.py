@@ -149,6 +149,8 @@ class _Traffic:
         self.reads_ok = 0
         self._mu = threading.Lock()
         self.threads: list[threading.Thread] = []
+        # set once a write was acked AND a read answered
+        self.flowing = threading.Event()
 
     def start(self) -> None:
         for w in range(self.schedule.writers):
@@ -168,6 +170,11 @@ class _Traffic:
         self.stop_evt.set()
         for t in self.threads:
             t.join(timeout=30)
+
+    def _note_progress(self) -> None:
+        """Caller holds ``_mu``."""
+        if self.acked_set and self.reads_ok:
+            self.flowing.set()
 
     def _writer(self, cid: int) -> None:
         from opentenbase_tpu.ha import RoutingClient
@@ -197,6 +204,7 @@ class _Traffic:
                     self.acked[cid] = max(
                         self.acked.get(cid, 0), batch[-1]
                     )
+                    self._note_progress()
             except Exception:
                 with self._mu:
                     for s in batch:
@@ -235,6 +243,7 @@ class _Traffic:
                 else:
                     with self._mu:
                         self.reads_ok += 1
+                        self._note_progress()
             except Exception:
                 self.stop_evt.wait(0.05)
             self.stop_evt.wait(0.01 + rng.random() * 0.03)
@@ -300,6 +309,11 @@ def run_schedule(
         mon = HAMonitor(topo, detect_ms=detect_ms, beats=beats).start()
         traffic = _Traffic(topo, schedule)
         traffic.start()
+        # the schedule's clock starts once traffic flows: on a loaded
+        # host the first statements alone can outlast the first faults,
+        # and the run would judge a cluster that never served (a
+        # cluster that cannot serve at all still fails liveness below)
+        traffic.flowing.wait(30)
         t0 = time.monotonic()
         crash_wall: Optional[float] = None
         for ev in schedule.events:

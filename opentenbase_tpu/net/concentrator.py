@@ -738,8 +738,7 @@ class PgConcentrator:
             # happens in _release when its statement finishes.
             # 4-tuple like every other job: a 3-tuple here crashed the
             # unpacking worker with ValueError and silently shrank the
-            # worker pool (caught as a stray traceback in the tier-1
-            # serving smoke)
+            # worker pool
             self._jobs.put((cl, _CLOSE_JOB, None, None))
 
     def _finish_close(self, cl: _Client) -> None:

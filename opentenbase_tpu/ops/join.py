@@ -59,7 +59,7 @@ def JOIN_MODE() -> str:
     eligible single-int-key shapes), 'radix', or 'sortmerge'. The fused
     device path takes the same choice from the ``join_mode`` GUC; the
     host executor has no session handle, so the env var is the knob
-    (tests and the tier-1 smoke force both paths through it)."""
+    (tests force both paths through it)."""
     import os
 
     return os.environ.get("OTB_JOIN_MODE", "auto").lower()

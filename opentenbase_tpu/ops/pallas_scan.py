@@ -26,8 +26,9 @@ is 32-bit. Exactness is kept by CERTIFIED LIMB ACCUMULATION:
 Anything the certifier rejects falls back to the XLA-fused path, so
 results are never approximate.
 
-Tested in interpreter mode on CPU (tests/test_pallas_scan.py); bench.py
-compares this kernel against the XLA-fused path on the real chip.
+Tested in interpreter mode on CPU (tests/test_pallas_scan.py); on the
+chip the benchmark's scan cell runs Q6 through this kernel and Q1, which
+the certifier rejects, through the XLA-fused path (PERF.md).
 """
 
 from __future__ import annotations

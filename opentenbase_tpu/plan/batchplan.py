@@ -13,18 +13,19 @@ traces. Oversized build sides become multi-pass probes; oversized
 anything-else falls back to the host path loudly instead of crashing
 the TPU worker (an in-process OOM on the remote chip is unrecoverable).
 
-The budget resolves in priority order:
-  1. the ``device_memory_limit`` GUC (bytes; 0 = unset),
-  2. the op-specific environment override (the historical knobs),
-  3. the baked-in default for that op.
+The budget is the ``device_memory_limit`` GUC (bytes; 0 = unset) or,
+without it, the constant below for that op.
 """
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 
-# defaults mirror the historical env knobs in executor/fused_dag.py
+# An exchange holds, a device, the bucketing sort's operands going in and
+# coming out, the (D, cap) slab it sends and the (D, cap) result it
+# receives (exchange_bytes). Beyond its budget the DAG bails to the host
+# path instead of crashing the TPU worker on HBM exhaustion (observed at
+# TPC-H SF10 Q3 on one 16GB v5e). Radix tables borrow the same budget.
 DEFAULT_EXCHANGE_BUDGET = 4_000_000_000
 DEFAULT_WINDOW_BUDGET = 6_000_000_000
 # a radix hash table is transient (freed after its join): allow it a
@@ -42,17 +43,11 @@ def next_pow2(n: int, floor: int = 1) -> int:
     return p
 
 
-def resolve_budget(
-    device_memory_limit: int, env_name: str, default: int
-) -> int:
-    """One budget in bytes (see module docstring for the priority)."""
+def resolve_budget(device_memory_limit: int, default: int) -> int:
+    """One budget in bytes: the GUC when set, else the op's constant."""
     if device_memory_limit and device_memory_limit > 0:
         return int(device_memory_limit)
-    try:
-        env = int(os.environ.get(env_name, 0))
-    except ValueError:
-        env = 0
-    return env if env > 0 else int(default)
+    return int(default)
 
 
 @dataclass(frozen=True)

@@ -532,8 +532,8 @@ class ClusterPersistence:
                 header["gid"] = gid
             if not group_commit and sync_mode != "off":
                 # enable_group_commit=off: the seed's fsync-per-commit
-                # path, byte-identical frames (the bench differential's
-                # baseline and an operator escape hatch)
+                # path, byte-identical frames (an operator escape
+                # hatch)
                 return self._finish_commit_record(
                     header, arrays, gid, commit_ts, sync=True
                 )

@@ -98,11 +98,21 @@ def test_compile_cache_default_is_in_checkout():
         assert ".jax_cache/" in f.read().split()
 
 
-def test_private_cache_knob_is_gone():
-    """OTB_COMPILE_CACHE_DIR is read nowhere (one knob fewer)."""
-    knob = "OTB_" + "COMPILE_CACHE_DIR"
+@pytest.mark.parametrize("knob", [
+    "OTB_" + "COMPILE_CACHE_DIR",
+    "OTB_" + "EXCHANGE_HBM_BUDGET",
+    "OTB_" + "SCAN_HBM_BUDGET",
+    "OTB_" + "RADIX_HBM_BUDGET",
+    "OTB_" + "DAG_WINDOW_BUDGET",
+    "OTB_" + "DIMFOLD_MAX",
+    "enable_" + "pallas_join",
+])
+def test_private_cache_knob_is_gone(knob):
+    """A deleted knob is read nowhere: the compile cache's private
+    directory, the five budgets and limits the process environment
+    used to carry, and the GUC nobody set."""
     hits = []
-    for top in ("opentenbase_tpu", "tools", "bench.py", "chip_smoke.py",
+    for top in ("opentenbase_tpu", "tools", "chip_smoke.py",
                 "__graft_entry__.py"):
         path = os.path.join(ROOT, top)
         files = [path] if os.path.isfile(path) else [

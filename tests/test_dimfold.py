@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from opentenbase_tpu.engine import Cluster
+from opentenbase_tpu.plan import batchplan
 
 
 def _both(s, q, expect_dag=True):
@@ -206,13 +207,13 @@ def test_gagg_narrow_overflow_retries_wide(sess):
 def test_windowed_gagg_matches_host(monkeypatch):
     """Bigger-than-budget probes stream in windows (wgagg): per-window
     compacted partials merge in one final program. Forced here with a
-    tiny OTB_DAG_WINDOW_BUDGET on a 1-device mesh; results must match
+    tiny batchplan.DEFAULT_WINDOW_BUDGET on a 1-device mesh; results must match
     the host path exactly, including FD-dropped group keys and
     cross-window groups (the reference analog: multi-batch hash join,
     nodeHash.c ExecHashIncreaseNumBatches)."""
     import jax
 
-    monkeypatch.setenv("OTB_DAG_WINDOW_BUDGET", "200000")
+    monkeypatch.setattr(batchplan, "DEFAULT_WINDOW_BUDGET", 200_000)
     s = Cluster(num_datanodes=1, shard_groups=16).session()
     rng = np.random.default_rng(7)
     s.execute(
@@ -272,7 +273,7 @@ def test_windowed_gagg_minmax_and_carried_order(monkeypatch):
     key rides the carried columns."""
     import jax
 
-    monkeypatch.setenv("OTB_DAG_WINDOW_BUDGET", "200000")
+    monkeypatch.setattr(batchplan, "DEFAULT_WINDOW_BUDGET", 200_000)
     s = Cluster(num_datanodes=1, shard_groups=16).session()
     rng = np.random.default_rng(9)
     s.execute(
@@ -337,7 +338,7 @@ def test_windowed_gagg_hoisted_build_prep(monkeypatch):
     results identical, top join still folds."""
     import jax
 
-    monkeypatch.setenv("OTB_DAG_WINDOW_BUDGET", "200000")
+    monkeypatch.setattr(batchplan, "DEFAULT_WINDOW_BUDGET", 200_000)
     s = Cluster(num_datanodes=1, shard_groups=16).session()
     rng = np.random.default_rng(13)
     s.execute(

@@ -1,6 +1,8 @@
 """Fused mesh executor: results must match the general fragment executor
 exactly, and the multichip dry-run must validate on a virtual mesh."""
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -205,3 +207,57 @@ def test_lane_plan_follows_the_statistics(jax8):
     assert _fused_row(s, "mxu_plans_full") >= 1
     (full,) = _xla_lane_plans(fx) - narrow - {wide}
     assert full.key_limbs == (None,) and full.arg_limbs == (None,)
+
+
+class _WatchedGate:
+    """The cluster's fused gate, saying when a statement has reached it."""
+
+    def __init__(self, lock):
+        self.lock = lock
+        self.reached = threading.Event()
+
+    def acquire(self):
+        self.reached.set()
+        return self.lock.acquire()
+
+    def release(self):
+        self.lock.release()
+
+    def __enter__(self):
+        return self.lock.acquire()
+
+    def __exit__(self, *exc):
+        self.lock.release()
+
+
+def test_guc_shadows_are_written_under_the_fused_gate():
+    """The executor is the cluster's, one for every session: a statement
+    writes its join_mode / device_memory_limit / watchdog shadows onto
+    it only once it HOLDS the fused gate, so a session waiting for the
+    gate cannot change what the holder's program is built under."""
+    c = Cluster(num_datanodes=1, shard_groups=16)
+    try:
+        s = c.session()
+        s.execute("create table gg (k bigint, v bigint) "
+                  "distribute by shard(k)")
+        s.execute("insert into gg values (1, 2), (3, 4)")
+        fx = c.fused_executor()
+        fx.device_memory_limit = 111  # the holder's
+        fx.join_mode = "radix"
+        waiter = c.session()
+        waiter.execute("set device_memory_limit = 222")
+        waiter.execute("set join_mode = sortmerge")
+        gate = c._fused_lock = _WatchedGate(c._fused_lock)
+        got = []
+        t = threading.Thread(
+            target=lambda: got.append(waiter.query("select sum(v) from gg"))
+        )
+        with gate:
+            t.start()
+            assert gate.reached.wait(60), "statement never reached the gate"
+            assert (fx.device_memory_limit, fx.join_mode) == (111, "radix")
+        t.join(60)
+        assert got == [[(6,)]]
+        assert (fx.device_memory_limit, fx.join_mode) == (222, "sortmerge")
+    finally:
+        c.close()

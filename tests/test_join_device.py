@@ -1,4 +1,4 @@
-"""Device-join differential suite + perf-regression-gate checks.
+"""Device-join differential suite + demotion observability.
 
 Covers the PR-6 join stack end to end, all tier-1 safe on
 JAX_PLATFORMS=cpu:
@@ -16,10 +16,6 @@ JAX_PLATFORMS=cpu:
 - the spill-aware batch planner's sizing and multi-pass splitting
   (plan/batchplan.py + fused_dag._lookup_radix);
 - the emit_pairs int32->int64 offset overflow fix;
-- the perf-regression gate (opentenbase_tpu/bench_gate.py +
-  BENCH_FLOORS.json): schema validity of the checked-in floors, a
-  synthetic floor violation and a forced demotion BOTH fail, a healthy
-  record passes;
 - demotion observability: a pallas->XLA demotion emits a warning into
   pg_cluster_logs and moves the otb_pallas_demotions_total exporter
   counter; otb_device_platform renders on every scrape.
@@ -35,7 +31,6 @@ import pytest
 import opentenbase_tpu.ops  # noqa: F401  (x64)
 import jax.numpy as jnp
 
-from opentenbase_tpu import bench_gate
 from opentenbase_tpu.engine import Cluster
 from opentenbase_tpu.ops import filter as filt_ops
 from opentenbase_tpu.ops import join as join_ops
@@ -228,12 +223,14 @@ def test_batchplan_sizing_and_passes():
 
 
 def test_resolve_budget_precedence(monkeypatch):
-    monkeypatch.delenv("OTB_TEST_BUDGET", raising=False)
-    assert batchplan.resolve_budget(0, "OTB_TEST_BUDGET", 42) == 42
-    monkeypatch.setenv("OTB_TEST_BUDGET", "77")
-    assert batchplan.resolve_budget(0, "OTB_TEST_BUDGET", 42) == 77
-    # the device_memory_limit GUC wins over the env knob
-    assert batchplan.resolve_budget(99, "OTB_TEST_BUDGET", 42) == 99
+    """The device_memory_limit GUC over the op's constant, and nothing
+    else: a budget in the process environment changes no answer."""
+    assert batchplan.resolve_budget(0, 42) == 42
+    assert batchplan.resolve_budget(99, 42) == 99
+    for name in ("EXCHANGE_HBM", "SCAN_HBM", "RADIX_HBM", "DAG_WINDOW"):
+        monkeypatch.setenv(f"OTB_{name}_BUDGET", "77")
+    assert batchplan.resolve_budget(0, 42) == 42
+    assert batchplan.resolve_budget(99, 42) == 99
 
 
 def test_multipass_lookup_radix_matches_single_table():
@@ -381,101 +378,6 @@ def test_fused_radix_flag_degrades_to_sortmerge(join_cluster):
     s.execute("set join_mode = radix")
     assert s.query(q) == want
     s.close()
-
-
-# ---------------------------------------------------------------------------
-# perf-regression gate
-# ---------------------------------------------------------------------------
-
-
-def test_checked_in_floors_validate():
-    doc = bench_gate.load_floors()  # raises on schema errors
-    assert doc["_meta"]["source_run"]
-    assert "q3_rows_per_sec" in doc["floors"]
-
-
-def _green_record(doc):
-    rec = {"platform": "tpu"}
-    for m, spec in doc["floors"].items():
-        rec[m] = spec["floor"] * 1.05
-    return rec
-
-
-def test_gate_passes_healthy_record():
-    doc = bench_gate.load_floors()
-    assert bench_gate.check_record(_green_record(doc), doc) == []
-
-
-def test_gate_fails_synthetic_floor_violation():
-    doc = bench_gate.load_floors()
-    rec = _green_record(doc)
-    spec = doc["floors"]["q3_rows_per_sec"]
-    rec["q3_rows_per_sec"] = spec["floor"] * spec.get(
-        "tolerance", doc["_meta"].get("default_tolerance", 0.75)
-    ) * 0.5
-    out = bench_gate.check_record(rec, doc)
-    assert len(out) == 1 and "q3_rows_per_sec" in out[0]
-
-
-def test_gate_fails_forced_demotion():
-    doc = bench_gate.load_floors()
-    # r04/r05 shape: the record says it ran on the CPU — ONE demotion
-    # line, device floors not piled on top
-    rec = {"platform": "cpu"}
-    out = bench_gate.check_record(rec, doc)
-    assert len(out) == 1 and "demotion" in out[0]
-    # the same on a record whose numbers would pass every floor
-    rec = _green_record(doc)
-    rec["platform"] = "cpu"
-    out = bench_gate.check_record(rec, doc)
-    assert len(out) == 1 and "'cpu'" in out[0]
-    # pallas->XLA kernel demotion fails even on a healthy platform
-    rec = _green_record(doc)
-    rec["pallas_demotions"] = 2
-    assert any(
-        "pallas" in v for v in bench_gate.check_record(rec, doc)
-    )
-
-
-def test_gate_reads_headline_via_metric_value_alias():
-    """bench.py stores the Q6 headline as record['value'] with its name
-    in record['metric'] — the gate must find it there, not report the
-    headline floor as a missing leg."""
-    doc = bench_gate.load_floors()
-    rec = _green_record(doc)
-    headline = "tpch_q6_rows_per_sec"
-    assert headline in doc["floors"]
-    rec["metric"] = headline
-    rec["value"] = rec.pop(headline)
-    assert bench_gate.check_record(rec, doc) == []
-    rec["value"] = 1  # and a headline REGRESSION is still caught
-    assert any(
-        headline in v for v in bench_gate.check_record(rec, doc)
-    )
-
-
-def test_gate_fails_missing_leg():
-    doc = bench_gate.load_floors()
-    rec = _green_record(doc)
-    del rec["q1_rows_per_sec"]
-    assert any(
-        "missing" in v for v in bench_gate.check_record(rec, doc)
-    )
-
-
-def test_validate_floors_rejects_malformed():
-    assert bench_gate.validate_floors([]) != []
-    assert bench_gate.validate_floors({"floors": {}}) != []
-    bad = {
-        "_meta": {"source_run": "r03"},
-        "floors": {"x": {"floor": -1}},
-    }
-    assert any("floor" in e for e in bench_gate.validate_floors(bad))
-    bad = {
-        "_meta": {"source_run": "r03"},
-        "floors": {"x": {"floor": 10, "tolerance": 2}},
-    }
-    assert any("tolerance" in e for e in bench_gate.validate_floors(bad))
 
 
 # ---------------------------------------------------------------------------

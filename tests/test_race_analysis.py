@@ -3,11 +3,11 @@ both halves must catch their bug class, and the shared baseline must
 ratchet exactly like otb_lint's.
 
 Static seeds go into a COPY of the real tree and must turn
-``otb_race --check`` red against the COMMITTED baseline — the tier-1
-race-analysis stage's contract.  Dynamic tests run the real classes in
-a SUBPROCESS with ``OTB_RACEWATCH=1`` (instrumentation is applied at
-class-definition time, mirroring lockwatch's create-after-enable
-rule), or script a fresh class after an in-process ``enable()``.
+``otb_race --check`` red against the COMMITTED baseline.  Dynamic
+tests run the real classes in a SUBPROCESS with ``OTB_RACEWATCH=1``
+(instrumentation is applied at class-definition time, mirroring
+lockwatch's create-after-enable rule), or script a fresh class after
+an in-process ``enable()``.
 """
 
 from __future__ import annotations
@@ -733,6 +733,39 @@ def test_spanring_allocations_race_fixed():
         print("SPANRING_OK")
     """)
     assert "SPANRING_OK" in out
+
+
+@pytest.mark.slow
+def test_chaos_schedule_under_racewatch_finds_no_new_race(tmp_path):
+    """The dynamic gate: one fixed-seed chaos schedule (promotion,
+    fencing, resync under live traffic) with every @shared_state class
+    instrumented. Two threads on one instance field with disjoint
+    locksets and a write is a race; one whose ``race-dynamic::`` key is
+    not in tools/race_baseline.json fails. Slow: ~10 s of schedule
+    under the sanitizer's overhead. Replay: ``OTB_RACEWATCH=1 python -m
+    opentenbase_tpu.cli.otb_chaos --seed 1107 --schedules 1``."""
+    out = _run_racewatch_subprocess(f"""
+        import json
+        from opentenbase_tpu.analysis import baseline as bl
+        from opentenbase_tpu.analysis import racewatch
+        from opentenbase_tpu.fault.schedule import (
+            ChaosSchedule, run_schedule,
+        )
+
+        sched = ChaosSchedule.generate(1107, duration_s=4.0,
+                                       num_datanodes=2)
+        v = run_schedule(sched, {str(tmp_path / "chaos")!r},
+                         detect_ms=1100, beats=3)
+        new, _old = racewatch.check_baseline(bl.load({RACE_BASELINE!r}))
+        print(json.dumps({{"chaos_gate": v["chaos_gate"],
+                           "violations": v["violations"],
+                           "races_new": [f.key for f in new]}}))
+    """)
+    v = json.loads(out.strip().splitlines()[-1])
+    # a sanitizer run that breaks the invariants it watches under
+    # proves nothing
+    assert v["chaos_gate"] == "ok", v
+    assert v["races_new"] == [], v
 
 
 class _DevNull:

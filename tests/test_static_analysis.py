@@ -6,8 +6,7 @@ docstring: an unread GUC (log_min_messages, PR 5), a removed jax API
 (``enable_x64``, PR 3), close-without-shutdown (PR 3), a socket-I/O function with no
 FAULT site (PR 4's thesis), and an int32 cumsum offset (PR 6). Each
 test copies the package, applies one seed, and asserts ``otb_lint
---check`` against the COMMITTED baseline goes red — which is exactly
-the tier-1 analysis stage's contract.
+--check`` against the COMMITTED baseline goes red.
 """
 
 from __future__ import annotations
@@ -15,6 +14,9 @@ from __future__ import annotations
 import json
 import os
 import shutil
+import subprocess
+import sys
+import textwrap
 
 import pytest
 
@@ -539,6 +541,84 @@ def test_lockwatch_condition_rlock_recursive_wait(watched):
     held = getattr(watched._state, "held", [])
     assert held == []  # bookkeeping drained with the scopes
     assert watched.find_cycles() == []
+
+
+def test_lockwatch_engine_drive_has_no_cycle():
+    """The statement lock through every class it has (shared reads,
+    table-granular writers on one and on two tables, exclusive DDL)
+    over the WIRE, where the net server's backend threads take them,
+    with the watchdog recording every acquisition: no non-allowlisted
+    cycle in the acquisition graph. A subprocess, because the locks of
+    interest must be created after the watchdog is on."""
+    env = dict(os.environ, OTB_LOCKWATCH="1", JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent("""
+        import json, threading
+        from opentenbase_tpu.analysis import lockwatch
+        from opentenbase_tpu.engine import Cluster
+        from opentenbase_tpu.net.client import connect_tcp
+        from opentenbase_tpu.net.server import ClusterServer
+
+        c = Cluster(num_datanodes=2, shard_groups=16)
+        srv = ClusterServer(c).start()
+        boot = connect_tcp(srv.host, srv.port)
+        boot.execute("set enable_fused_execution = off")
+        for t in ("lwa", "lwb"):
+            boot.execute(f"create table {t} (k bigint, v bigint) "
+                         "distribute by shard(k)")
+        boot.execute("insert into lwa values " + ",".join(
+            f"({i},{i})" for i in range(50)))
+
+        def reader():
+            with connect_tcp(srv.host, srv.port) as x:
+                for _ in range(8):
+                    x.query("select count(*), sum(v) from lwa")
+
+        def writer(tbl, base):
+            with connect_tcp(srv.host, srv.port) as x:
+                for j in range(8):
+                    x.execute(f"insert into {tbl} values ({base+j}, 1)")
+
+        def multi_table():
+            # a two-table write set: the sorted table-mutex hierarchy
+            with connect_tcp(srv.host, srv.port) as x:
+                for j in range(4):
+                    x.execute(f"insert into lwb select k+{1000+j*100}, v "
+                              "from lwa where k < 5")
+
+        def ddl():
+            with connect_tcp(srv.host, srv.port) as x:
+                x.execute("create table lwc (k bigint) "
+                          "distribute by roundrobin")
+                x.execute("drop table lwc")
+
+        errs = []
+        def run(fn, *a):
+            # a dead driver thread watches nothing: it fails the drive
+            def wrapped():
+                try:
+                    fn(*a)
+                except BaseException as e:
+                    errs.append(f"{fn.__name__}: {e!r}")
+            return threading.Thread(target=wrapped)
+
+        ths = [run(reader) for _ in range(3)]
+        ths += [run(writer, "lwa", 100), run(writer, "lwb", 200),
+                run(multi_table), run(ddl)]
+        for t in ths: t.start()
+        for t in ths: t.join()
+        boot.close(); srv.stop(); c.close()
+        print(json.dumps({"cycles": lockwatch.find_cycles(),
+                          "ordered_pairs": len(lockwatch.edges()),
+                          "driver_errors": errs}, default=str))
+        """)],
+        capture_output=True, text=True, timeout=180, cwd=REPO_ROOT, env=env,
+    )
+    assert out.returncode == 0, (out.stdout, out.stderr)
+    v = json.loads(out.stdout.strip().splitlines()[-1])
+    assert v["driver_errors"] == [] and v["cycles"] == [], v
+    # the drive orders 30-odd lock pairs; far fewer means it did not run
+    assert v["ordered_pairs"] >= 15, v
 
 
 class _DevNull:

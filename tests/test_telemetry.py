@@ -235,6 +235,15 @@ def test_merged_logs_health_and_waits_reconstruct_a_chaos_run():
         )
         fi = [w for w in waits if w[0] == "FaultInjection"]
         assert fi and fi[0][2] >= 20, waits
+
+        # and a scrape carries the incident's series: fault hits, the
+        # DNs' liveness, the standbys' replication lag
+        from opentenbase_tpu.obs.exporter import render_cluster_metrics
+
+        body = render_cluster_metrics(c)
+        for series in ("otb_fault_hits_total", "otb_dn_up",
+                       "otb_replication_lag_bytes"):
+            assert series in body, series
     finally:
         _teardown(c, sender, dns)
 

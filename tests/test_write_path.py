@@ -675,8 +675,7 @@ def test_chaos_schedule_sync_mode(mode, tmp_path):
     mode-aware invariants must hold — remote_write loses zero acked
     writes; off/local may lose only a contiguous per-client tail and
     never duplicate, reorder, or grow phantoms. ('on' is covered by
-    test_ha.py::test_chaos_schedule_end_to_end and the tier-1 HA
-    smoke.)"""
+    test_ha.py::test_chaos_schedule_end_to_end.)"""
     from opentenbase_tpu.fault.schedule import (
         ChaosSchedule,
         run_schedule,
