@@ -8602,6 +8602,9 @@ def _sv_fused(c: Cluster):
     # column statistics, or at the dtypes' full width
     rows.append(("mxu_plans_bounded", str(fx.mxu_plans["bounded"])))
     rows.append(("mxu_plans_full", str(fx.mxu_plans["full"])))
+    # joins whose build the estimates admitted to a radix table and
+    # whose static width sent them to sort-merge (once a compiled program)
+    rows.append(("radix_sized_out", str(fx.radix_sized_out)))
     dag = fx._dag
     if dag is not None:
         rows.append(("completed", str(dag.completed)))

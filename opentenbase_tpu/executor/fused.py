@@ -153,10 +153,11 @@ class Launcher:
         if led is not None:
             led.fused_retries += 1
 
-    def __call__(self, program, build_args, **args):
+    def __call__(self, program, build_args, late=None, **args):
         """``program(*build_args())``; ``build_args`` makes the small
         uploads (``jnp.asarray`` of row counts, the snapshot) inside
-        the span."""
+        the span. ``late()`` gives the args only the call itself
+        settles (what a first call's trace chose), read after it."""
         name = program.__name__
         self.attempt += 1
         self.programs.append(name)
@@ -172,6 +173,8 @@ class Launcher:
             if cw.ms:
                 sp.set(compile_ms=round(cw.ms, 3))
                 sp.exclude(cw.ms)
+            if late is not None and sp.listening:
+                sp.set(**late())
         return outs
 
 
@@ -1149,6 +1152,12 @@ class FusedExecutor:
         # statistics narrowed by at least one lane / by none
         # (pg_stat_fused mxu_plans_bounded / mxu_plans_full)
         self.mxu_plans = {"bounded": 0, "full": 0}
+        # joins the radix gate's estimates admitted and the shape rule
+        # (fused_dag._lookup_radix: a table only where dimension-sized)
+        # sent to sort-merge, counted as each program is traced: once a
+        # compiled program, never on a cached re-bind
+        # (pg_stat_fused radix_sized_out)
+        self.radix_sized_out = 0
         self._mxu_binds: dict = {}  # id(plan) -> (plan, stats, _MxuBind)
         # the statement path's one way to call a jitted program
         # (fused.launch span, launch/retry accounting); the DAG runner

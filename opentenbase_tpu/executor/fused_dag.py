@@ -34,6 +34,7 @@ flips the build side or gives up so the host path answers instead.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import wraps
 from typing import Callable, Optional
@@ -654,14 +655,21 @@ def _radix_gate(
     runner, node: "L.Join", ji: int, build_right: bool, radix_off,
     mode: str,
 ) -> bool:
-    """THE radix-hash-join gate — the builder (which compiles it) and
-    any mode prediction share this one definition. The radix table
-    engages where the dense fold can't (keys unique but not a gap-free
-    range): build side estimated small relative to the probe — the
-    planner's cardinality estimates, the same signal that seeds build
-    orientation — or the ``join_mode`` GUC forcing it. Inner joins
-    only: semi/anti existence probes carry no per-join flag slot to
-    report a bucket overflow through."""
+    """THE radix-hash-join gate, the half of it that reads estimates —
+    the builder (which compiles it) and any mode prediction share this
+    one definition. The radix table is a candidate where the dense fold
+    can't engage (keys unique but not a gap-free range): build side
+    estimated small relative to the probe — the planner's cardinality
+    estimates, the same signal that seeds build orientation — or the
+    ``join_mode`` GUC forcing it. Inner joins only: semi/anti existence
+    probes carry no per-join flag slot to report a bucket overflow
+    through.
+
+    Admission here is not yet a table: the other half of the rule reads
+    the STATIC build width, which only the trace knows, and lives in
+    ``_lookup_radix`` — under ``auto`` a table is built only where it is
+    dimension-sized (``pallas_join.eligible``); every wider build takes
+    sort-merge."""
     if runner is None or ji in radix_off or mode == "sortmerge":
         return False
     bnode = node.right if build_right else node.left
@@ -680,23 +688,49 @@ def _radix_gate(
 
 def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
                   pallas_probe: bool = False, note_mode=None,
-                  tag: str = "join"):
+                  tag: str = "join", forced: bool = False):
     """Equi-join primitive over the bucket-padded radix hash table
     (ops/join.py): ONE small build-side sort + a log2(bucket)-deep
     bucket search per probe row, instead of sort-merge's full
     (build+probe)-width co-sort. The spill-aware batch planner sizes
     partitions/bucket against ``budget`` at trace time from the STATIC
-    shapes; a build side whose table would blow the budget splits into
+    shapes.
+
+    THE SHAPE RULE (``auto``, i.e. not ``forced``): a table is built
+    only where it is dimension-sized — where
+    ``pallas_join.eligible(chunk, P, B)`` holds for the plan of the
+    static build width (P <= 4096 and 6 * bucket <= 512: a padded build
+    of at most 65,536 rows). Every wider build takes ``fallback`` (the
+    sort-merge primitive). Static shapes only: no platform test, no
+    GUC. Its source is the chip record (ledger, PR 31,
+    ``tpch_sf30_4chip.join`` ``device_ops``): a 2^21-row build probed
+    by 2^23 rows through ``probe_radix_first`` cost 3,042 ms a
+    statement a chip, seven element-wise gathers ``u32[8388608]`` from
+    ``u32[5242881]`` tables at 20 ns an element (362 ns a probe row),
+    and its build 512 ms, where the same chips sort at 6-8 ns a row:
+    beyond the Pallas kernel's reach the XLA gather probe loses to
+    sort-merge by 10x at every shape the estimate gate admits.
+
+    ``forced`` (``join_mode = radix``) keeps what it names at any
+    width: a build side whose table would blow the budget splits into
     multi-pass probes (nodeHash.c's nbatch, device-style: probe stays
-    resident, one transient table per pass) — and when even the maximum
-    pass count can't fit, ``fallback`` (the sort-merge primitive, O(1)
-    extra HBM) answers instead of OOMing the worker.
+    resident, one transient table per pass), probed by the XLA probe
+    where the kernel is not eligible. Forced or not, when even the
+    maximum pass count can't fit, ``fallback`` (O(1) extra HBM) answers
+    instead of OOMing the worker.
+
+    ``note_mode(mode, sized_out=False)`` hears what is traced in:
+    ``radix`` once a table is, ``pallas`` when the kernel probes it,
+    ``merge`` when ``fallback`` answers — ``sized_out`` where the shape
+    rule, not the budget, sent it there.
 
     Same contract as ``_lookup_sortmerge``: (matched, bidx, flag); the
     flag is raised by duplicate build keys (in-bucket adjacency or a
     key matching in two passes), or by bucket overflow — the runner
     then disables the radix formulation for this join and the
     sort-merge retry re-derives the exact dup verdict."""
+    from opentenbase_tpu.ops import pallas_join as pj
+
     pd, pv = pk
     bd, bv = bk
     nb = bd.shape[0]
@@ -708,19 +742,24 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
             jnp.asarray(False),
         )
     plan = batchplan.plan_radix_join(nb, npr, budget)
-    if plan is None:
+    chunk = 0 if plan is None else -(-nb // plan.passes)
+    sized_out = (
+        plan is not None and not forced
+        and not pj.eligible(chunk, plan.partitions, plan.bucket)
+    )
+    if plan is None or sized_out:
         if note_mode is not None:
-            note_mode("merge")
+            note_mode("merge", sized_out=sized_out)
         with scope(f"{tag}/merge"):
             return fallback(pk, pmask, bk, bmask, check_dup=True)
+    if note_mode is not None:
+        note_mode("radix")
     breal = bmask if bv is None else (bmask & bv)
     preal = pmask if pv is None else (pmask & pv)
     P, B = plan.partitions, plan.bucket
     matched = jnp.zeros(npr, jnp.bool_)
     bidx = jnp.zeros(npr, jnp.int32)
     flag = jnp.asarray(False)
-    chunk = -(-nb // plan.passes)
-    from opentenbase_tpu.ops import pallas_join as pj
 
     for p in range(plan.passes):
         s = p * chunk
@@ -1017,6 +1056,30 @@ def _collect_arrays(fx, root, exchanged: dict, D: int) -> list:
     ]
 
 
+@dataclasses.dataclass(frozen=True)
+class _JoinInfo:
+    """What one compile decided about its inner joins — cached beside
+    the program. ``folded``, ``gated`` and ``forced`` are the build's
+    inputs to the join lowering (part of the entry's signature: a
+    cached executable answers only for the same three); ``radixed`` is
+    the builder's own set of joins a radix table was really traced in
+    for, filled at the program's first call and read by the flag
+    handler after it ran."""
+
+    folded: frozenset
+    gated: frozenset  # the radix gate's estimates (or the GUC) admitted
+    forced: bool  # join_mode = radix: a table at any width
+    radixed: set = dataclasses.field(compare=False)
+
+    def remap(self, fn) -> "_JoinInfo":
+        """The same record in another join-index space."""
+        return _JoinInfo(
+            frozenset(fn(x) for x in self.folded),
+            frozenset(fn(x) for x in self.gated),
+            self.forced, {fn(x) for x in self.radixed},
+        )
+
+
 class _Builder:
     def __init__(
         self, fx, comp: ExprCompiler, orientation: tuple, root,
@@ -1052,13 +1115,24 @@ class _Builder:
             self.radix_off = frozenset()
         self.folded: set = set()
         self.folded_ids: dict = {}  # id(join) -> build_right, folded
-        self.radixed: set = set()  # joins THIS compile radix-hashed
-        # the join formulations of THIS compile: 'fold'/'radix'/'merge'
-        # chosen here, 'pallas' (and a radix plan's sort-merge
-        # fallback) added when the probe is traced in. The set rides
-        # the jitted program (DagRunner._program), so a run reports the
-        # modes of the cache entry that ran
+        # joins of THIS compile the radix gate admitted, and those a
+        # table was traced in for (the shape rule of _lookup_radix
+        # decides at trace time, so ``radixed`` fills at the program's
+        # first call; jinfo() hands it out live)
+        self.gated: set = set()
+        self.radixed: set = set()
+        # the join formulations of THIS compile: 'fold' and a plain
+        # 'merge' chosen here; for a join the radix gate admitted,
+        # 'radix' (+ 'pallas') or 'merge' noted when the trace knows
+        # the build's width. The set rides the jitted program
+        # (DagRunner._program), so a run reports the modes of the cache
+        # entry that ran
         self.modes: set = set()
+        # join<i> -> '<formulations>:<build>x<probe>' (as join_modes
+        # spells them, e.g. 'pallas+radix'; static widths a device),
+        # written at trace time: the program's fused.bind /
+        # fused.launch arg ``joins``
+        self.joins: dict = {}
         fx_h = runner.fx if runner is not None else fx
         self.join_mode = str(getattr(fx_h, "join_mode", "auto"))
         self.radix_budget = batchplan.resolve_budget(
@@ -1081,11 +1155,17 @@ class _Builder:
             _lookup_sortmerge if self.platform == "tpu" else _lookup
         )
 
-    def jinfo(self) -> tuple:
-        """(folded, radixed) join-index sets for THIS compile — cached
-        beside the program so the runner's flag handler knows whether a
-        raised flag means fold-disable, radix-disable, or flip."""
-        return (frozenset(self.folded), frozenset(self.radixed))
+    def jinfo(self) -> _JoinInfo:
+        """This compile's join record — cached beside the program so
+        the runner's flag handler knows whether a raised flag means
+        fold-disable, radix-disable, or flip. A join the shape rule
+        sent to sort-merge is not in ``radixed``: its flag is answered
+        like any sort-merge join's (flip), not by a retry that disables
+        a table that never was."""
+        return _JoinInfo(
+            frozenset(self.folded), frozenset(self.gated),
+            self.join_mode == "radix" and bool(self.gated), self.radixed,
+        )
 
     def _fold_eligible(self, node: L.Join, ji: int, build_right: bool):
         """Attempt the dense direct-index lookup for this inner join?
@@ -1335,18 +1415,24 @@ class _Builder:
                 )
             else:
                 # mode selection: fold (perfect hash over a dense key
-                # range) > radix hash table (small-vs-probe build by
-                # planner estimate) > sort-merge — each failure class
-                # degrades one step at runtime via the flag machinery
+                # range) > radix hash table (build small against the
+                # probe by planner estimate AND, under ``auto``,
+                # dimension-sized by its static width: at most 65,536
+                # padded rows, the Pallas probe's reach) > sort-merge —
+                # each failure class degrades one step at runtime via
+                # the flag machinery. The width half of the radix rule
+                # is _lookup_radix's, at trace time; its source: ledger,
+                # PR 31, tpch_sf30_4chip.join device_ops (a 2^21-row
+                # build probed by XLA gathers: 3,042 ms of a 5,230 ms
+                # statement, 10x what sort-merge costs there)
                 use_radix = _radix_gate(
                     self.runner, node, ji, build_right, self.radix_off,
                     self.join_mode,
                 )
                 if use_radix:
-                    self.radixed.add(ji)
-            self.modes.add(
-                "fold" if fold else ("radix" if use_radix else "merge")
-            )
+                    self.gated.add(ji)
+            if not use_radix:
+                self.modes.add("fold" if fold else "merge")
         if self.D > 1:
             # replicated tables scanned INSIDE a multi-device join
             # fragment hold their rows on one device — a build side
@@ -1389,12 +1475,26 @@ class _Builder:
         # on a TPU mesh; elsewhere interpret mode would measure the
         # emulator (the enable_pallas_scan convention)
         pallas_probe = use_radix and self.platform == "tpu"
-        # 'pallas' joins the program's join modes (EXPLAIN ANALYZE,
-        # pg_stat_fused last_join_modes) when the probe is traced in
-        note_mode = self.modes.add
+        forced = self.join_mode == "radix"
         # scope of this join's lowering: join<i>/<formulation>
         jtag = f"join{ji}" if jt == "inner" else f"join_{jt}"
-        jmode = "fold" if fold else ("radix" if use_radix else "merge")
+        # what the trace put in for this join (a radix-gated join's
+        # formulation is known only there: _lookup_radix's shape rule)
+        traced: set = set() if use_radix else {"fold" if fold else "merge"}
+
+        def note_mode(mode: str, sized_out: bool = False) -> None:
+            """The program's join modes (EXPLAIN ANALYZE, pg_stat_fused
+            last_join_modes), the flag handler's ``radixed`` and the
+            ``radix_sized_out`` count, as the trace decides them."""
+            traced.add(mode)
+            builder.modes.add(mode)
+            if mode == "radix":
+                builder.radixed.add(ji)
+            if sized_out:
+                builder.fx.radix_sized_out += 1
+
+        def note_widths(bn: int, pn: int) -> None:
+            builder.joins[jtag] = f"{'+'.join(sorted(traced))}:{bn}x{pn}"
 
         def run(blocks, params, snap):
             if fold:
@@ -1422,6 +1522,7 @@ class _Builder:
                 flags = flags + [dup]
                 if do_capture:
                     builder.captured = (bidx, benv, bn)
+                note_widths(bn, pn)
                 with scope(f"{jtag}/fold/gather"):
                     gathered = [
                         (
@@ -1469,7 +1570,7 @@ class _Builder:
                     matched, bidx, dup = _lookup_radix(
                         pk, pmask, bk, bmask, radix_budget, lookup,
                         pallas_probe=pallas_probe,
-                        note_mode=note_mode, tag=jtag,
+                        note_mode=note_mode, tag=jtag, forced=forced,
                     )
                 else:
                     with scope(f"{jtag}/merge"):
@@ -1479,6 +1580,8 @@ class _Builder:
                 flags = flags + [dup]
                 if do_capture:
                     builder.captured = (bidx, benv, bn)
+                note_widths(bn, pn)
+                jmode = "radix" if "radix" in traced else "merge"
                 with scope(f"{jtag}/{jmode}/gather"):
                     gathered = [
                         (
@@ -1655,21 +1758,35 @@ class DagRunner:
     @staticmethod
     def _program(fn, name: str, b=None):
         """Jit ``fn`` as ``program_dag_<name>`` and hang the builder's
-        join-mode record on it: the record lives and dies with the
-        program-cache entry."""
+        join record on it (the formulations, and each join's with its
+        static widths): the record lives and dies with the
+        program-cache entry, and is whole once the program was traced,
+        i.e. from its first call on."""
         prog = named_program(fn, "program_dag_" + name)
         prog.join_modes = b.modes if b is not None else set()
+        prog.joins = b.joins if b is not None else {}
         return prog
+
+    @staticmethod
+    def _join_args(prog) -> dict:
+        """A program's join record as span args (None where it has no
+        join, or was not traced yet)."""
+        return {
+            "join_modes": "+".join(sorted(prog.join_modes)) or None,
+            "joins": ",".join(
+                f"{k}={v}" for k, v in sorted(prog.joins.items())
+            ) or None,
+        }
 
     def _launch(self, prog, arrays, params, snap, **args):
         """Enqueue one DAG program (``fused.launch``): its name, the
         fragment it serves and the join formulations it was compiled
-        with ride the span."""
+        with ride the span (read after the call: a first call is what
+        traces them in)."""
         return self.fx.launch(
             prog, lambda: (tuple(arrays), params, snap),
-            frag=self._frag,
-            join_modes="+".join(sorted(prog.join_modes)) or None,
-            **args,
+            late=lambda: self._join_args(prog),
+            frag=self._frag, **args,
         )
 
     def _fetch(self, tree, what: str):
@@ -1729,7 +1846,13 @@ class DagRunner:
             # executable no longer matches the fresh specs — replace
             self._programs[key] = fresh
             return fresh
-        return tuple(cached[:np_]) + tuple(fresh[np_:])
+        # the cached program with the fresh literals; its join record is
+        # the cached one too (equal by signature, and the one whose
+        # ``radixed`` the cached program's trace filled)
+        return tuple(cached[:np_]) + tuple(
+            c if isinstance(c, _JoinInfo) else f
+            for c, f in zip(cached[np_:], fresh[np_:])
+        )
 
     _NPROGS = {"wgagg": 2}  # cache entries holding >1 jitted program
 
@@ -1778,6 +1901,10 @@ class DagRunner:
             params = self._resolve(
                 entry[np_], dicts_view, subquery_values
             )
+            if bsp.listening:
+                # the join record of a program already traced (a miss
+                # learns it at its first launch, whose span carries it)
+                bsp.set(**self._join_args(entry[0]))
             bsp.set(
                 program=entry[0].__name__,
                 cache="hit" if hit else "miss", frag=self._frag,
@@ -1874,14 +2001,13 @@ class DagRunner:
         way (sort-merge re-derives the exact dup verdict); for a
         sort-merge join it means duplicate build keys — flip the build
         side (raises when both sides were tried)."""
-        folded, radixed = jinfo
-        if flip in folded:
+        if flip in jinfo.folded:
             self._retry(f"join{flip} fold density flag: fold off")
             self._fold_off.setdefault(skey, set()).add(flip)
             while len(self._fold_off) > 512:
                 self._fold_off.pop(next(iter(self._fold_off)))
             return orientation
-        if flip in radixed:
+        if flip in jinfo.radixed:
             self._retry(f"join{flip} radix overflow or dup: radix off")
             self._radix_off.setdefault(skey, set()).add(flip)
             while len(self._radix_off) > 512:
@@ -2574,7 +2700,7 @@ class DagRunner:
                 "result",
             )
             self.last_mode = mode
-            self.last_folded = jinfo[0]
+            self.last_folded = jinfo.folded
             okf = None
             ngroups = None
             if mode in ("gseg", "gsort", "gagg"):
@@ -3298,14 +3424,9 @@ class DagRunner:
                 "result",
             )
             (out_keys, out_vals, gvalid, novf, okf, flags) = outs
-            gjinfo = (
-                jinfo if gmap is None
-                else tuple(
-                    frozenset(gmap(x) for x in s) for s in jinfo
-                )
-            )
+            gjinfo = jinfo if gmap is None else jinfo.remap(gmap)
             self.last_mode = "wgagg"
-            self.last_folded = gjinfo[0]
+            self.last_folded = gjinfo.folded
             flip = _first_true(flags)
             if flip is not None:
                 orientation = self._on_flag(
@@ -3418,9 +3539,7 @@ class DagRunner:
             # map the prep-local join index back to the global space
             self._on_flag(
                 skey, orientation, flip + boff,
-                tuple(
-                    frozenset(x + boff for x in s) for s in jinfo_local
-                ),
+                jinfo_local.remap(lambda x: x + boff),
             )
             return "retry"
         self._accept(prog)
@@ -4975,7 +5094,15 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool):
     (build rows lead their equal-key runs), mark probe rows whose run
     holds a real build row, then a second sort by original probe
     position restores row order. Same contract as ``_lookup``:
-    (matched [np] bool, bidx [np] int, dup 0-d bool)."""
+    (matched [np] bool, bidx [np] int, dup 0-d bool).
+
+    No gather: XLA's TPU gather costs 7.25 ns an element whatever the
+    order of its indices (my chip run, PR 32: 8.4M elements from tables
+    of 2^16..2^23.3 rows; 13-20 ns from 2^26), so fetching each slot's
+    build row by position, ``take(sokey, pbpos)``, was 91 of this
+    function's 174 ms at 2^21 x 2^23 rows. The one ``cummax`` that finds
+    the last real build row carries that row's run (high word) and its
+    original position (low word) instead."""
     pd, pv = pk
     bd, bv = bk
     nb = bd.shape[0]
@@ -5013,21 +5140,28 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool):
         dup = jnp.asarray(False)
     with jax.named_scope("prefix"):
         runid = jnp.cumsum(boundary.astype(jnp.int32))
-        iota = jnp.arange(M, dtype=jnp.int32)
-        pbpos = jax.lax.cummax(jnp.where(isb, iota, jnp.int32(-1)))
-        pbrun = jax.lax.cummax(jnp.where(isb, runid, jnp.int32(-1)))
-        isp = sside == 1
-        matched_s = (pbrun == runid) & isp
-        bidx_s = jnp.take(sokey, jnp.maximum(pbpos, 0))
+        # runs are numbered upward, so the running max over the real
+        # build rows' (run << 32 | original position) is the LAST such
+        # row at or before each slot; -1 until the first
+        last = jax.lax.cummax(jnp.where(
+            isb,
+            (runid.astype(jnp.int64) << 32) | sokey.astype(jnp.int64),
+            jnp.int64(-1),
+        ))
+        matched_s = (
+            ((last >> 32).astype(jnp.int32) == runid) & (sside == 1)
+        )
+        # the restore sort carries one operand: the build row's
+        # position, or -1 for 'no match'
+        bidx_s = jnp.where(matched_s, last.astype(jnp.int32), jnp.int32(-1))
     # restore probe-row order: probe original positions are unique keys;
     # dead probe rows restore too (they must land back in place)
     rkey = jnp.where(sokey >= nb, sokey - nb, jnp.int32(2**31 - 1))
     with jax.named_scope("restore"):
-        _rk, m_p, b_p = jax.lax.sort(
-            (rkey, matched_s.astype(jnp.int8), bidx_s),
-            num_keys=1, is_stable=False,
+        _rk, b_p = jax.lax.sort(
+            (rkey, bidx_s), num_keys=1, is_stable=False
         )
-    matched = (m_p[:npr] > 0) & pmask
+    matched = (b_p[:npr] >= 0) & pmask
     bidx = jnp.clip(b_p[:npr], 0, max(nb - 1, 0))
     return matched, bidx, dup
 

@@ -401,7 +401,8 @@ def reduce(trace: dict) -> dict:
                 "statements": 0, "spans": {}, "launches": 0, "syncs": 0,
                 "retries": 0, "programs_ms": {}, "scopes_ms": {},
                 "unscoped_ops_ms": {}, "device_busy_ms": 0.0,
-                "idle_ms": {}, "join_modes": {}, "fragments": {},
+                "idle_ms": {}, "join_modes": {}, "joins": {},
+                "fragments": {},
                 "all_to_all": {},
             }
         return c
@@ -441,6 +442,10 @@ def reduce(trace: dict) -> dict:
                 jm = n["args"].get("join_modes")
                 if jm:
                     c["join_modes"][jm] = c["join_modes"].get(jm, 0) + 1
+                # each join's formulation with its static widths a device
+                jn = n["args"].get("joins")
+                if jn:
+                    c["joins"][jn] = c["joins"].get(jn, 0) + 1
             elif name == "fused.wait":
                 c["syncs"] += 1
 
@@ -606,6 +611,7 @@ def render(report: dict) -> str:
             f"syncs, {c['retries'] / n:.2f} retries"
             + (f", join_modes {sorted(c['join_modes'])}"
                if c["join_modes"] else "")
+            + (f", joins {sorted(c['joins'])}" if c["joins"] else "")
         )
         out.append("  span                  count   total ms    self ms"
                    "  (per statement)")

@@ -24,11 +24,18 @@ trick the engine's grouped aggregation plays (ops/agg.py superblock):
 
 The XLA probe (ops/join.probe_radix_first) remains the reference
 semantics; this kernel is the device fast path for small dimension
-tables (P <= 4096 keeps the one-hot block in VMEM). Tested in
+tables (P <= 4096 keeps the one-hot block in VMEM), and ``eligible``
+is also the DAG's shape rule: under ``join_mode = auto`` a radix table
+is built only where this kernel could probe it, every wider build
+takes sort-merge (executor/fused_dag._lookup_radix). Tested in
 interpreter mode on CPU (tests/test_join_device.py) and compiled by
 Mosaic on the chip (chip_smoke.py's radix join over a small build
-side); a lowering or runtime failure there demotes to the XLA probe
-LOUDLY through the pallas-demotion telemetry (obs/exporter.py).
+side). The kernel is compiled with the rest of its DAG program, so a
+lowering or runtime failure there FAILS THE PROGRAM: the statement is
+re-answered by the host executor as a counted, logged fused->host
+demotion (engine._try_fused_inner; ``demoted`` in pg_stat_fused,
+which chip_smoke.py and benchmarks/run.py refuse). There is no quiet
+switch to the XLA probe.
 """
 
 from __future__ import annotations

@@ -15,6 +15,8 @@ JAX_PLATFORMS=cpu:
   interpreter mode against the XLA probe;
 - the spill-aware batch planner's sizing and multi-pass splitting
   (plan/batchplan.py + fused_dag._lookup_radix);
+- the shape rule of ``join_mode = auto``: a radix table only where it is
+  dimension-sized (pallas_join.eligible), sort-merge above that;
 - the emit_pairs int32->int64 offset overflow fix;
 - demotion observability: a pallas->XLA demotion emits a warning into
   pg_cluster_logs and moves the otb_pallas_demotions_total exporter
@@ -266,6 +268,165 @@ def test_multipass_lookup_radix_matches_single_table():
 
 
 # ---------------------------------------------------------------------------
+# the shape rule: a radix table only where it is dimension-sized
+# ---------------------------------------------------------------------------
+
+RADIX_BOUND = 65_536  # widest padded build pallas_join.eligible admits
+
+
+def _shape_rule_case(nb: int, npr: int, seed: int = 32):
+    """Sparse int64 keys with NULLs and dead rows on both sides and one
+    live duplicate build key: (pk, pmask, bk, bmask, dup key)."""
+    rng = np.random.default_rng(seed + nb)
+    bk = rng.permutation(np.arange(-nb, 3 * nb, dtype=np.int64))[:nb]
+    bvalid = rng.random(nb) > 0.05
+    bmask = rng.random(nb) > 0.2
+    bvalid[:2] = bmask[:2] = True
+    bk[1] = bk[0]  # the duplicate: two live, non-NULL build rows
+    pk = np.where(
+        rng.random(npr) < 0.7, bk[rng.integers(0, nb, npr)],
+        rng.integers(4 * nb, 8 * nb, npr),
+    ).astype(np.int64)
+    pvalid = rng.random(npr) > 0.05
+    pmask = rng.random(npr) > 0.2
+    return (
+        (jnp.asarray(pk), jnp.asarray(pvalid)), jnp.asarray(pmask),
+        (jnp.asarray(bk), jnp.asarray(bvalid)), jnp.asarray(bmask),
+        int(bk[0]),
+    )
+
+
+def _lowered_radix(nb: int, npr: int, forced: bool):
+    """(noted modes, StableHLO text) of ``_lookup_radix`` at the static
+    widths, lowered and not run."""
+    import jax
+
+    from opentenbase_tpu.executor.fused_dag import _lookup, _lookup_radix
+
+    noted: list = []
+
+    def note(mode, sized_out=False):
+        noted.append((mode, sized_out))
+
+    def fn(pd, pv, pm, bd, bv, bm):
+        return _lookup_radix(
+            (pd, pv), pm, (bd, bv), bm,
+            batchplan.DEFAULT_EXCHANGE_BUDGET, _lookup,
+            note_mode=note, forced=forced,
+        )
+
+    def arg(n, dt):
+        return jax.ShapeDtypeStruct((n,), dt)
+
+    text = jax.jit(fn).lower(
+        arg(npr, jnp.int64), arg(npr, jnp.bool_), arg(npr, jnp.bool_),
+        arg(nb, jnp.int64), arg(nb, jnp.bool_), arg(nb, jnp.bool_),
+    ).as_text()
+    return noted, text
+
+
+@pytest.mark.parametrize("nb", [4_096, 65_536, 131_072, 1 << 21])
+def test_auto_builds_a_radix_table_only_where_dimension_sized(nb):
+    """Under ``auto`` ``_lookup_radix`` answers as ``_lookup`` does at
+    every width; at or under the bound it notes ``radix`` and lowers a
+    table, above it it notes ``merge`` (sized out by the shape rule, not
+    by the budget) and the lowered program holds no ``P*B+1`` operand."""
+    from opentenbase_tpu.executor.fused_dag import _lookup, _lookup_radix
+
+    npr = 4 * nb
+    plan = batchplan.plan_radix_join(
+        nb, npr, batchplan.DEFAULT_EXCHANGE_BUDGET
+    )
+    assert plan is not None and plan.passes == 1
+    table = f"tensor<{plan.partitions * plan.bucket + 1}x"
+    noted, text = _lowered_radix(nb, npr, forced=False)
+    if nb <= RADIX_BOUND:
+        assert noted == [("radix", False)], noted
+        assert table in text
+    else:
+        assert noted == [("merge", True)], noted
+        assert table not in text
+    pk, pmask, bk, bmask, dupkey = _shape_rule_case(nb, npr)
+    want = _lookup(pk, pmask, bk, bmask, check_dup=True)
+    got = _lookup_radix(
+        pk, pmask, bk, bmask, batchplan.DEFAULT_EXCHANGE_BUDGET, _lookup
+    )
+    # the duplicate build key raises the flag in either formulation
+    assert bool(want[2]) and bool(got[2])
+    wm, gm = np.asarray(want[0]), np.asarray(got[0])
+    assert wm.any() and np.array_equal(wm, gm)
+    # which of the two duplicates a probe row names is the retry's to
+    # settle: every other matched row names the same build row
+    sure = wm & (np.asarray(pk[0]) != dupkey)
+    assert np.array_equal(np.asarray(want[1])[sure], np.asarray(got[1])[sure])
+
+
+@pytest.mark.parametrize("nb,npr", [(2, 5), (700, 300), (4_096, 16_384)])
+@pytest.mark.parametrize("check_dup", [True, False])
+def test_sortmerge_lookup_equals_sorted_lookup(nb, npr, check_dup):
+    """The TPU's double-sort formulation (no gather: one ``cummax``
+    carries the build row's run and position) answers as the
+    ``searchsorted`` one does: NULLs and dead rows on both sides, the
+    full int64 key range, with and without a duplicate build key."""
+    from opentenbase_tpu.executor.fused_dag import (
+        _lookup, _lookup_sortmerge,
+    )
+
+    pk, pmask, bk, bmask, dupkey = _shape_rule_case(nb, npr)
+    if nb > 2:
+        # keys at both ends of int64 on both sides
+        ends = jnp.asarray([-(2**63), 2**63 - 1], jnp.int64)
+        bk = (bk[0].at[-2:].set(ends), bk[1].at[-2:].set(True))
+        bmask = bmask.at[-2:].set(True)
+        pk = (pk[0].at[-2:].set(ends), pk[1].at[-2:].set(True))
+        pmask = pmask.at[-2:].set(True)
+    for dup in (True, False):
+        if not dup:  # break the duplicate: a fresh key
+            bk = (bk[0].at[1].set(2**40), bk[1])
+        want = _lookup(pk, pmask, bk, bmask, check_dup=check_dup)
+        got = _lookup_sortmerge(pk, pmask, bk, bmask, check_dup=check_dup)
+        assert bool(got[2]) == bool(want[2]) == (dup and check_dup)
+        wm, gm = np.asarray(want[0]), np.asarray(got[0])
+        assert np.array_equal(wm, gm)
+        assert wm.any() or nb == 2
+        sure = wm & ((np.asarray(pk[0]) != dupkey) | (not dup))
+        assert np.array_equal(
+            np.asarray(want[1])[sure], np.asarray(got[1])[sure])
+        assert int(np.asarray(got[1]).min()) >= 0
+        assert int(np.asarray(got[1]).max()) < nb
+
+
+def test_forced_radix_builds_the_table_above_the_bound():
+    """``join_mode = radix`` keeps what it names: past the bound the
+    table is still lowered (probed by the XLA probe), nothing is sized
+    out."""
+    nb, npr = 131_072, 524_288
+    plan = batchplan.plan_radix_join(
+        nb, npr, batchplan.DEFAULT_EXCHANGE_BUDGET
+    )
+    noted, text = _lowered_radix(nb, npr, forced=True)
+    assert noted == [("radix", False)], noted
+    assert f"tensor<{plan.partitions * plan.bucket + 1}x" in text
+
+
+def test_the_bound_is_the_pallas_kernels_reach():
+    """The rule's bound is ``pallas_join.eligible`` on the planner's
+    table: flight 1's 2,556 dates and a 65,536-row build are
+    dimension-sized, 131,072 rows and SF30's 2^21 customers a chip are
+    not."""
+    from opentenbase_tpu.ops import pallas_join as pj
+
+    def sized(nb):
+        p = batchplan.plan_radix_join(
+            nb, 4 * nb, batchplan.DEFAULT_EXCHANGE_BUDGET
+        )
+        return pj.eligible(-(-nb // p.passes), p.partitions, p.bucket)
+
+    assert [sized(n) for n in (2_556, RADIX_BOUND, 131_072, 1 << 21)] == [
+        True, True, False, False]
+
+
+# ---------------------------------------------------------------------------
 # SQL-level parity: host executor + fused DAG, all four join types
 # ---------------------------------------------------------------------------
 
@@ -377,6 +538,91 @@ def test_fused_radix_flag_degrades_to_sortmerge(join_cluster):
     s.execute("set enable_fused_execution = on")
     s.execute("set join_mode = radix")
     assert s.query(q) == want
+    s.close()
+
+
+def test_cached_radix_program_keeps_its_flag_handling(join_cluster):
+    """A radix program found in the program cache still answers a
+    raised flag as a radix table's (table off, sort-merge re-derives the
+    verdict): the record of which joins it built a table for is the
+    cached program's, not the fresh closure's."""
+    c = join_cluster
+    s = c.session()
+    s.execute(
+        "create table latedup (k bigint, g int) distribute by roundrobin"
+    )
+    s.execute("insert into latedup values " + ",".join(
+        f"({i * 7 + 3}, {i})" for i in range(40)
+    ))
+    s.execute("analyze")
+    q = "select count(*), max(latedup.g) from f, latedup where f.k = latedup.k"
+    s.execute("set enable_fused_execution = on")
+    s.execute("set join_mode = radix")
+    s.query(q)  # the radix program is compiled and cached
+    s.execute("insert into latedup values (10, 100), (10, 101)")
+    s.execute("set enable_fused_execution = off")
+    want = s.query(q)
+    s.execute("set enable_fused_execution = on")
+    s.execute("set trace_queries = on")
+    assert s.query(q) == want
+    s.execute("set trace_queries = off")
+    tr = next(t for t in reversed(c.tracer.last(4)) if t.query == q)
+    final = [
+        sp for sp in tr.spans
+        if sp.name in ("fused.bind", "fused.launch")
+        and sp.args["frag"] == "final"
+    ]
+    assert final[0].args["cache"] == "hit"
+    assert final[0].args["joins"].startswith("join0=radix:")
+    assert [sp.args.get("reason") for sp in final
+            if sp.name == "fused.launch"][:2] == [
+        None, "join0 radix overflow or dup: radix off"]
+    s.close()
+
+
+def test_sized_out_join_flag_flips_sides_without_a_radix_retry(
+    join_cluster, monkeypatch
+):
+    """Duplicate build keys on a join the shape rule sent to sort-merge:
+    the flag is a sort-merge join's (flip the build side), not a radix
+    table's — no retry is spent disabling a table that never was."""
+    from opentenbase_tpu.ops import pallas_join
+
+    c = join_cluster
+    s = c.session()
+    s.execute(
+        "create table dupw (k bigint, g int) distribute by roundrobin"
+    )
+    s.execute("insert into dupw values " + ",".join(
+        f"({i % 8}, {i})" for i in range(64)  # every key duplicated
+    ))
+    s.execute("analyze")
+    # (a statement shape of its own: what the runner remembers of a
+    # join's failed formulations is keyed by the plan's structure)
+    q = "select count(*), min(dupw.g) from f, dupw where f.k = dupw.k"
+    s.execute("set enable_fused_execution = off")
+    want = s.query(q)
+    s.execute("set enable_fused_execution = on")
+    s.execute("set join_mode = auto")
+    fx = c.fused_executor()
+    # every table is past a bound of no partitions at all
+    monkeypatch.setattr(pallas_join, "MAX_PARTITIONS", 0)
+    sized_out = fx.radix_sized_out
+    radix_off = dict(fx._dag._radix_off) if fx._dag is not None else {}
+    s.execute("set trace_queries = on")
+    assert s.query(q) == want
+    s.execute("set trace_queries = off")
+    tr = next(t for t in reversed(c.tracer.last(4)) if t.query == q)
+    reasons = [
+        sp.args["reason"] for sp in tr.spans
+        if sp.name == "fused.launch" and "reason" in sp.args
+    ]
+    assert fx.radix_sized_out == sized_out + 1, reasons
+    assert reasons == [
+        "join0 fold density flag: fold off",
+        "join0 duplicate build keys: flip sides",
+    ]
+    assert fx._dag._radix_off == radix_off
     s.close()
 
 

@@ -380,10 +380,19 @@ class Tpch4:
         self.fx = mesh4(self.dep.cluster)
         self.dep.create_tables()
         self.dep.load(self.data)
+        self.patch = pytest.MonkeyPatch()
         if sf30_estimates:
             # the motion choice reads estimates only: SF30's row counts
             for table, rows in SF30.items():
                 self.dep.cluster.catalog.get(table).stats["rows"] = rows
+            # the join's formulation reads static widths only: SF30's
+            # 2^21 padded customers a device are past the radix table's
+            # bound (P <= 4096). The toy's 256 (P = 16) are held to a
+            # bound scaled down with them, so fragment 1 takes the
+            # four-chip cell's formulation
+            from opentenbase_tpu.ops import pallas_join
+
+            self.patch.setattr(pallas_join, "MAX_PARTITIONS", 8)
 
     def text(self, params: dict) -> str:
         stmt = self.mix["statements"]["q3"]
@@ -405,6 +414,7 @@ class Tpch4:
         return rows
 
     def close(self):
+        self.patch.undo()
         self.dep.close()
 
 
@@ -452,8 +462,19 @@ def test_q3_over_the_wire_equals_the_reference(tpch4, pi):
     if variant == "sf30_plan":
         assert "program_dag_exchange" in programs, programs
         assert after["last_mode"][-1] == "gsort"  # whole groups a device
+        # fragment 1's customer join: admitted by the estimates, sent to
+        # sort-merge by its width (the final's co-sort is ``merge`` too);
+        # counted once a compiled program that holds it — the count pass
+        # and the exchange of this set of literals — and never on a
+        # cached re-bind (the span test may have run this set before)
+        assert after["last_join_modes"][-1] == "merge"
+        sized_out = (int(after["radix_sized_out"][-1])
+                     - int(before["radix_sized_out"][-1]))
+        assert sized_out == (2 if "program_dag_count" in programs else 0)
+        assert int(after["radix_sized_out"][-1]) >= 2
     else:
         assert "program_dag_broadcast" in programs, programs
+        assert int(after["radix_sized_out"][-1]) == 0
 
 
 @pytest.mark.parametrize("tpch4", ["sf30_plan"], indirect=True)

@@ -169,6 +169,8 @@ def test_explain_analyze_fused_phases(sess):
     assert "fused_statements" in events
     # the MXU group reduce's launches by lane plan (ISSUE 28)
     assert {"mxu_plans_bounded", "mxu_plans_full"} <= events
+    # joins the shape rule sent from a radix table to sort-merge (ISSUE 32)
+    assert "radix_sized_out" in events
     # no timing row is left in the view: the spans and columns carry them
     assert not any(e.endswith(("_ms", "]")) for e in events)
 

@@ -147,6 +147,58 @@ def test_bind_span_reports_the_lane_plan(wire):
     assert after[0] == before[0] + 1 and after[1] == before[1]
 
 
+def test_radix_shape_rule_counter_and_bind_args(wire, monkeypatch):
+    """``radix_sized_out`` advances once for a program whose join the
+    estimates admitted to a radix table and the shape rule sent to
+    sort-merge, never on a cached re-bind and not at all for a
+    dimension-sized build; the program's ``fused.bind`` span (and the
+    launch that traced it) carry the formulation with its widths."""
+    from opentenbase_tpu.ops import pallas_join
+
+    cluster, client = wire
+
+    def sized_out():
+        rows = dict(
+            client.execute("select event, detail from pg_stat_fused").rows
+        )
+        return int(rows["radix_sized_out"]), rows["last_join_modes"]
+
+    def spans(tr, name):
+        return [sp for sp in tr.spans if sp.name == name]
+
+    # o's 200 sparse keys against li's 400: not dense, so the fold's
+    # flag hands the join to the radix gate (build * 2 <= probe)
+    wide = ("select o.c, count(*) from li join o on li.k = o.k "
+            "where li.d < 7 group by o.c order by o.c")
+    small = ("select o.c, max(li.q) from li join o on li.k = o.k "
+             "where li.d < 9 group by o.c order by o.c")
+    before, _modes = sized_out()
+    # every table is past a bound of zero partitions: what SF30's 2^21
+    # customers a chip are to the real one (P <= 4096)
+    monkeypatch.setattr(pallas_join, "MAX_PARTITIONS", 0)
+    first = _traced(cluster, client, wide)
+    after, modes = sized_out()
+    assert after == before + 1 and modes == "merge"
+    # the launch that traced the program says what the trace chose
+    launch = spans(first, "fused.launch")[-1]
+    assert launch.args["join_modes"] == "merge"
+    assert re.match(r"^join0=merge:\d+x\d+$", launch.args["joins"])
+    again = _traced(cluster, client, wide)
+    assert sized_out() == (after, "merge")  # a cached re-bind
+    bind = spans(again, "fused.bind")[-1]
+    assert bind.args["cache"] == "hit"
+    assert bind.args["join_modes"] == "merge"
+    assert bind.args["joins"] == launch.args["joins"]
+    build, probe = map(int, bind.args["joins"].split(":")[1].split("x"))
+    assert 0 < build < probe
+    # flight 1's shape: a dimension-sized build keeps its table
+    monkeypatch.undo()
+    tr = _traced(cluster, client, small)
+    assert sized_out() == (after, "radix")
+    assert spans(tr, "fused.launch")[-1].args["joins"].startswith(
+        "join0=radix:")
+
+
 @pytest.fixture(scope="module")
 def profiled(wire, tmp_path_factory):
     """The three statements under ``jax.profiler.start_trace`` with
@@ -208,6 +260,11 @@ def test_profiler_events_equal_the_query_trace(profiled, kind):
         c["spans"]["fused.launch"]["count"] == c["launches"]
         for c in classed if c["launches"]
     )
+    # the join's launch says which formulation at which static widths
+    joins = [j for c in classed for j in c["joins"]]
+    assert len(joins) == 1 and re.match(
+        r"^join0=(radix|merge|fold):\d+x\d+$", joins[0]), joins
+    assert "joins ['join0=" in profile.render(report)
 
 
 def test_ledger_columns_filled_with_tracing_off(wire):
