@@ -1,0 +1,40 @@
+"""The star cell's whole run (``--rehearse`` on the CPU at a tiny scale)
+with the timed path broken underneath: an answer altered, fact rows left
+out, a dimension left short (``faults_star.py``, which plants the same
+three on the chip). Each comes out not agreeing; unbroken the run agrees
+through the DAG's ``grouped`` final, and the control (float32 sums in the
+program's place) does not."""
+
+import pytest
+
+import faults_star
+from test_run_faults import drive
+
+
+def test_unbroken_run_agrees_and_control_does_not(capsys):
+    line = drive(faults_star.CELL, capsys, control=True)
+    assert line["rehearsal"] and line["correct"] is False  # never true here
+    assert line["rehearsal_agrees"], line["compared"]
+    assert line["metrics"] == {}
+    counts = line["rehearsal_counts"]
+    assert set(counts["by_kind"]) == {"q21", "q31", "q41"}
+    assert len(set(counts["by_kind"].values())) == 1  # whole rotations
+    assert counts["paths"]["last_mode"] == "grouped"
+    assert "fold" in counts["paths"]["last_join_modes"].split(",")
+    assert line["control"]["correct"] is False
+    c = line["control"]["compared"]
+    assert c["wrong_statements"]["value"] == 0
+    assert c["sum_gap"]["value"] > c["sum_gap"]["limit"]
+
+
+@pytest.mark.parametrize("fault", list(faults_star.FAULTS))
+def test_a_planted_fault_fails(fault, capsys):
+    with faults_star.FAULTS[fault]():
+        line = drive(faults_star.CELL, capsys)
+    assert line["rehearsal_agrees"] is False
+    c = line["compared"]
+    if fault == "altered":
+        assert c["sum_gap"]["value"] > 1e-7
+    else:
+        assert (c["sum_gap"]["value"] > 1e-3
+                or c["wrong_statements"]["value"] > 0)

@@ -2695,8 +2695,18 @@ class DagRunner:
                 if gcap_known is not None and gcap_known != gcap:
                     gcap = gcap_known
                     continue  # recompile/lookup at the exact capacity
+            gargs = {}
+            if mode in ("grouped", "grouped_topk"):
+                # the capacity the program was compiled for, and its keys
+                ntext = sum(g.type.is_text for g in agg.group_exprs)
+                gargs = {
+                    "groups": gcap,
+                    "group_keys": f"{len(agg.group_exprs)} ({ntext} text)",
+                }
             outs = self._fetch(
-                self._launch(prog, arrays, params, snap, mode=mode),
+                self._launch(
+                    prog, arrays, params, snap, mode=mode, **gargs
+                ),
                 "result",
             )
             self.last_mode = mode
@@ -4652,7 +4662,12 @@ class DagRunner:
                 @_staged
                 def block(blocks, st):
                     env, mask, n, flags = ev(blocks, params, snap)
-                    st.to("final/grouped/reduce")
+                    # (the scalar final is one stage under the name the
+                    # accepted cells' traces read, though it groups nothing)
+                    st.to(
+                        "final/grouped/keys" if grouped
+                        else "final/grouped/reduce"
+                    )
                     flags = [jnp.reshape(f, (1,)) for f in flags]
                     keys = [_bcast(fn(env, params), n) for fn in gfns]
                     vals = [
@@ -4668,15 +4683,19 @@ class DagRunner:
                             for d, v in outs
                         ], flags
                     if use_packed:
+                        st.to("final/grouped/pack")
                         packed, pack_ok = _pack_group_keys(keys, mask)
+                        st.to("final/grouped/sort")
                         perm, seg, ngroups = agg_ops._group_ids_impl(
                             [(packed, None)], mask
                         )
                         flags = flags + [jnp.reshape(~pack_ok, (1,))]
                     else:
+                        st.to("final/grouped/sort")
                         perm, seg, ngroups = agg_ops._group_ids_impl(
                             keys, mask
                         )
+                    st.to("final/grouped/segreduce")
                     out_keys, out_vals, gvalid = agg_ops._group_reduce_impl(
                         keys, vals, perm, seg, gcap, tuple(specs)
                     )

@@ -402,6 +402,7 @@ def reduce(trace: dict) -> dict:
                 "retries": 0, "programs_ms": {}, "scopes_ms": {},
                 "unscoped_ops_ms": {}, "device_busy_ms": 0.0,
                 "idle_ms": {}, "join_modes": {}, "joins": {},
+                "groups": {}, "group_keys": {},
                 "fragments": {},
                 "all_to_all": {},
             }
@@ -439,13 +440,12 @@ def reduce(trace: dict) -> dict:
                 c["launches"] += 1
                 if "retry_of" in n["args"]:
                     c["retries"] += 1
-                jm = n["args"].get("join_modes")
-                if jm:
-                    c["join_modes"][jm] = c["join_modes"].get(jm, 0) + 1
-                # each join's formulation with its static widths a device
-                jn = n["args"].get("joins")
-                if jn:
-                    c["joins"][jn] = c["joins"].get(jn, 0) + 1
+                # each join's formulation with its static widths a
+                # device; a grouped final's capacity and its keys
+                for arg in ("join_modes", "joins", "groups", "group_keys"):
+                    v = n["args"].get(arg)
+                    if v:
+                        c[arg][str(v)] = c[arg].get(str(v), 0) + 1
             elif name == "fused.wait":
                 c["syncs"] += 1
 
@@ -612,6 +612,8 @@ def render(report: dict) -> str:
             + (f", join_modes {sorted(c['join_modes'])}"
                if c["join_modes"] else "")
             + (f", joins {sorted(c['joins'])}" if c["joins"] else "")
+            + (f", groups {sorted(c['groups'])} of group_keys "
+               f"{sorted(c['group_keys'])}" if c["groups"] else "")
         )
         out.append("  span                  count   total ms    self ms"
                    "  (per statement)")
