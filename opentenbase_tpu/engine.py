@@ -8605,6 +8605,11 @@ def _sv_fused(c: Cluster):
     # joins whose build the estimates admitted to a radix table and
     # whose static width sent them to sort-merge (once a compiled program)
     rows.append(("radix_sized_out", str(fx.radix_sized_out)))
+    # accepted grouped finals of the DAG that addressed their groups by
+    # the packed key, and those the key's range or the aggregates sent
+    # to the sort formulation
+    rows.append(("grouped_direct", str(fx.grouped_direct)))
+    rows.append(("grouped_sorted", str(fx.grouped_sorted)))
     dag = fx._dag
     if dag is not None:
         rows.append(("completed", str(dag.completed)))

@@ -1158,6 +1158,12 @@ class FusedExecutor:
         # compiled program, never on a cached re-bind
         # (pg_stat_fused radix_sized_out)
         self.radix_sized_out = 0
+        # accepted grouped finals of the DAG by formulation: addressed
+        # directly by the packed key, or sorted because the key's range
+        # or the aggregates' kinds left no choice (fused_dag._run_final;
+        # pg_stat_fused grouped_direct / grouped_sorted)
+        self.grouped_direct = 0
+        self.grouped_sorted = 0
         self._mxu_binds: dict = {}  # id(plan) -> (plan, stats, _MxuBind)
         # the statement path's one way to call a jitted program
         # (fused.launch span, launch/retry accounting); the DAG runner

@@ -70,6 +70,12 @@ from opentenbase_tpu.storage.table import ColumnBatch
 from opentenbase_tpu.utils.hashing import combine_hashes, hash32_jnp
 
 OPTIMISTIC_GROUP_CAP = 1 << 16
+# Direct-addressed grouped finals (ops/agg._direct_group_reduce_impl):
+# where the packed group key's live range fits this many slots the key
+# IS the slot and the reduction is one-hot matmuls; a wider range takes
+# the next power of two up to the bound, past it the sort formulation.
+DIRECT_START_SLOTS = 1 << 13
+DIRECT_MAX_SLOTS = 1 << 16
 
 from opentenbase_tpu.ops import join as join_ops
 from opentenbase_tpu.plan import batchplan
@@ -236,14 +242,19 @@ def _pack_group_keys(keys, mask):
     """Pack integer group keys into ONE int64 sort key using runtime
     per-key ranges (data-dependent VALUES, not shapes — no recompile):
     packed = sum((k_i - min_i) * stride_i), NULLs in a dedicated bucket.
-    Returns (packed, ok): when the combined range overflows int64, ok is
-    False and the caller retries with per-key sorting. Cuts the grouped
-    aggregation from one argsort per key part to a single argsort."""
+    Returns (packed, ok, layout): when the combined range overflows
+    int64, ok is False and the caller retries with per-key sorting. Cuts
+    the grouped aggregation from one argsort per key part to a single
+    argsort. ``layout`` is what ``_unpack_group_keys`` needs to read a
+    packed value back: per key ``(min, stride, range, nullable)`` and
+    last the product of the ranges (the packed values' span; garbage
+    where ok is False)."""
     stride = jnp.int64(1)
     prod = jnp.float64(1.0)
     ok = jnp.asarray(True)
     packed = jnp.zeros(mask.shape[0], dtype=jnp.int64)
     big = jnp.int64(2**62)
+    layout: list = []
     for d, v in keys:
         live = mask if v is None else (mask & v)
         d64 = d.astype(jnp.int64)
@@ -264,10 +275,87 @@ def _pack_group_keys(keys, mask):
             r = rng + 1
             rf = rngf + 1.0
         packed = packed + x * stride  # dead rows may wrap: masked anyway
+        layout.append((mn, stride, rng, v is not None))
         stride = stride * r
         prod = prod * jnp.maximum(rf, 1.0)
     ok = ok & (prod < jnp.float64(2**62))
-    return packed, ok
+    return packed, ok, layout + [stride]
+
+
+def _packable_keys(agg) -> bool:
+    """Every group key an integer by dtype (dictionary-coded text
+    included): what ``_pack_group_keys`` packs."""
+    return all(
+        g.type.id in _JOINABLE_KEY_TYPES or g.type.is_text
+        for g in agg.group_exprs
+    )
+
+
+def _direct_grouping(agg) -> bool:
+    """Whether a grouped final may address its groups directly: every
+    key packable (integer-family or dictionary-coded text) and every
+    aggregate a sum / count / count(*) the one-hot matmul sums exactly
+    (``ops/agg.direct_group_eligible``). The packed range, which only
+    the program sees, decides the rest."""
+    if agg is None or not agg.group_exprs or not _packable_keys(agg):
+        return False
+    return agg_ops.direct_group_eligible(
+        [a.func if a.arg is not None else "count_star" for a in agg.aggs],
+        [None if a.arg is None else a.arg.type.np_dtype for a in agg.aggs],
+    )
+
+
+def _direct_grouped(keys, vals, mask, gslots: int, specs: tuple, st):
+    """The grouped final by direct addressing: the packed key is the
+    slot. Inside the capacity every live row's packed value is its own
+    group's (injective by ``_pack_group_keys``'s construction: no hash,
+    no collision check); past it no row counts and the runner, told the
+    span, re-runs wider or sorted. Returns ``_group_reduce_impl``'s
+    (out_keys, out_vals, gvalid) at length ``gslots``, the group count
+    and the span."""
+    st.to("final/grouped/pack")
+    packed, pack_ok, layout = _pack_group_keys(keys, mask)
+    st.to("final/grouped/slot")
+    span = jnp.where(pack_ok, layout[-1], jnp.int64(2**62))
+    slot = jnp.where(mask & (span <= gslots), packed, gslots).astype(
+        jnp.int32
+    )
+    st.done()  # (the reduce names its own stages)
+    out_vals, gvalid, ngroups = agg_ops._direct_group_reduce_impl(
+        vals, slot, gslots, specs
+    )
+    st.to("final/grouped/recombine")
+    out_keys = [
+        (d, gvalid if v is None else v & gvalid)
+        for d, v in _unpack_group_keys(
+            layout, gslots, [d.dtype for d, _v in keys]
+        )
+    ]
+    return out_keys, out_vals, gvalid, ngroups, span
+
+
+def _unpack_group_keys(layout, cap: int, dtypes):
+    """The group keys of the packed values 0..cap-1 (the inverse of
+    ``_pack_group_keys`` under its ``layout``, for a span inside
+    ``cap``): per key (data, valid), valid None for a key that has no
+    NULL bucket. Every stride and range of such a layout is at most
+    ``cap``, so the divisions run in int32 (the TPU emulates a 64-bit
+    one in thousands of ops); a wider span's are clipped, its keys
+    garbage that the caller's span check discards."""
+    slots = jnp.arange(cap, dtype=jnp.int32)
+    out = []
+    for (mn, stride, rng, nullable), dtype in zip(layout[:-1], dtypes):
+        stride, rng = (
+            jnp.clip(x, 1, cap).astype(jnp.int32) for x in (stride, rng)
+        )
+        q = (slots // stride) % (rng + 1 if nullable else rng)
+        key = mn + q.astype(jnp.int64)
+        if nullable:
+            valid = q < rng  # the bucket past the range is NULL
+            out.append((jnp.where(valid, key, 0).astype(dtype), valid))
+        else:
+            out.append((key.astype(dtype), None))
+    return out
 
 
 _PACKABLE_SORT_TYPES = (
@@ -2585,6 +2673,10 @@ class DagRunner:
         # a doomed packed program
         packing = self._packing.get(skey, True)
         n_dup = _count_inner_joins(root)
+        # direct-addressed grouping wherever the rule allows it, at the
+        # capacity remembered with ``gcap``; 0: the sort formulation
+        eligible = _direct_grouping(agg)
+        gslots = DIRECT_START_SLOTS if eligible else 0
 
         while True:
             # per-orientation mode selection: gseg (segment-reduce over
@@ -2648,10 +2740,16 @@ class DagRunner:
             ) and not self._narrow_off.get(skey)
             robust = bool(self._robust_on.get(skey))
             fo = self._offs(skey)
+            # (gseg, gsort and gagg are other functions; a final whose
+            # packing overflowed sorts key by key)
+            direct = gslots if packing and gs is None and ga is None and (
+                bg is None or not use_topk
+            ) else 0
             fkey = (
-                "final", skey, orientation, gcap, D, sig, packing,
+                "final", skey, orientation, None if direct else gcap, D,
+                sig, packing,
                 tk if use_topk else None, bg is not None, psum,
-                gs is not None, ga is not None, narrow, fo, robust,
+                gs is not None, ga is not None, narrow, fo, robust, direct,
             )
             def compile_final():
                 if gs is not None:
@@ -2680,7 +2778,7 @@ class DagRunner:
                     frag, agg, root, exchanged, orientation, gcap, D,
                     packing,
                     topk=tk if use_topk else None, bg=bg, psum=psum,
-                    fo=fo,
+                    fo=fo, gslots=direct,
                 )
 
             prog, comp, mode, jinfo, params = self._bind(
@@ -2692,15 +2790,24 @@ class DagRunner:
                     _params_sig(params),
                 )
                 gcap_known = self._caps.get(gcapkey)
+                gslots_known = self._caps.get(("gslots",) + gcapkey[1:])
+                rebind = False
+                if direct and gslots_known not in (None, gslots):
+                    gslots = gslots_known  # the slots that held, or 0
+                    rebind = True
                 if gcap_known is not None and gcap_known != gcap:
                     gcap = gcap_known
+                    rebind = rebind or not direct
+                if rebind:
                     continue  # recompile/lookup at the exact capacity
             gargs = {}
             if mode in ("grouped", "grouped_topk"):
-                # the capacity the program was compiled for, and its keys
+                # the formulation the program holds, the capacity it was
+                # compiled for, and its keys
                 ntext = sum(g.type.is_text for g in agg.group_exprs)
                 gargs = {
-                    "groups": gcap,
+                    "grouping": f"direct/{direct}" if direct else "sort",
+                    "groups": direct or gcap,
                     "group_keys": f"{len(agg.group_exprs)} ({ntext} text)",
                 }
             outs = self._fetch(
@@ -2713,6 +2820,8 @@ class DagRunner:
             self.last_folded = jinfo.folded
             okf = None
             ngroups = None
+            if direct:
+                *outs, span = outs
             if mode in ("gseg", "gsort", "gagg"):
                 out_keys, out_vals, gvalid, okf, flags = outs
             elif mode == "grouped_topk":
@@ -2736,6 +2845,19 @@ class DagRunner:
                     continue
                 orientation = self._on_flag(skey, orientation, flip, jinfo)
                 gcapkey = None  # keyed per orientation
+                continue
+            if direct and (span := int(np.asarray(span).max())) > direct:
+                # a device's packed keys span more slots than the
+                # program has (no row was counted): the power of two
+                # that holds them, or past the bound the sort
+                gslots = (
+                    filt_ops.bucket_size(span)
+                    if span <= DIRECT_MAX_SLOTS else 0
+                )
+                self._retry(
+                    f"packed group keys span {span} > {direct} slots: "
+                    + (f"direct/{gslots}" if gslots else "sort")
+                )
                 continue
             if okf is not None and not bool(np.asarray(okf).all()):
                 if mode in ("gsort", "gagg") and narrow:
@@ -2787,11 +2909,19 @@ class DagRunner:
                 )
             if mode in ("grouped", "grouped_topk"):
                 actual = int(np.asarray(ngroups).max())
-                if actual >= gcap:
+                if not direct and actual >= gcap:
                     self._retry(f"group capacity {gcap} < {actual + 1}")
                     gcap = filt_ops.bucket_size(actual + 1)
                     continue
-                self._cap_store(gcapkey, gcap)
+                if direct:
+                    self.fx.grouped_direct += 1
+                else:
+                    self.fx.grouped_sorted += 1
+                    self._cap_store(gcapkey, gcap)
+                if eligible and (direct or not gslots):
+                    # (what the span decided, not what another mode or
+                    # an overflowed packing kept from being asked)
+                    self._cap_store(("gslots",) + gcapkey[1:], direct)
                 self._orientations[skey] = orientation
                 if mode == "grouped_topk" and not complete:
                     out_keys = jax.tree.map(lambda x: x[:1], out_keys)
@@ -4608,8 +4738,11 @@ class DagRunner:
     def _compile_final(
         self, frag, agg, root, exchanged, orientation, gcap, D,
         packing: bool = True, topk=None, bg=None, psum: bool = False,
-        fo=frozenset(),
+        fo=frozenset(), gslots: int = 0,
     ):
+        """``gslots`` > 0 (``_run_final`` asks only where
+        ``_direct_grouping`` allows): the grouped final addresses
+        ``gslots`` slots by the packed key instead of sorting."""
         comp = ExprCompiler(lift_consts=True)
         b = _Builder(
             self.fx, comp, orientation, root,
@@ -4653,10 +4786,7 @@ class DagRunner:
             # packed single-sort grouping applies to all-integer keys
             # (dtype is static); a runtime range-overflow flag retries
             # with per-key sorting
-            use_packed = packing and grouped and all(
-                g.type.id in _JOINABLE_KEY_TYPES or g.type.is_text
-                for g in agg.group_exprs
-            )
+            use_packed = packing and grouped and _packable_keys(agg)
 
             def program(arrays, params, snap):
                 @_staged
@@ -4682,23 +4812,37 @@ class DagRunner:
                             (jnp.reshape(d, (1,)), jnp.reshape(v, (1,)))
                             for d, v in outs
                         ], flags
-                    if use_packed:
-                        st.to("final/grouped/pack")
-                        packed, pack_ok = _pack_group_keys(keys, mask)
-                        st.to("final/grouped/sort")
-                        perm, seg, ngroups = agg_ops._group_ids_impl(
-                            [(packed, None)], mask
+                    if gslots:
+                        out_keys, out_vals, gvalid, ngroups, span = (
+                            _direct_grouped(
+                                keys, vals, mask, gslots, tuple(specs), st
+                            )
                         )
-                        flags = flags + [jnp.reshape(~pack_ok, (1,))]
                     else:
-                        st.to("final/grouped/sort")
-                        perm, seg, ngroups = agg_ops._group_ids_impl(
-                            keys, mask
+                        if use_packed:
+                            st.to("final/grouped/pack")
+                            packed, pack_ok, _layout = _pack_group_keys(
+                                keys, mask
+                            )
+                            st.to("final/grouped/sort")
+                            perm, seg, ngroups = agg_ops._group_ids_impl(
+                                [(packed, None)], mask
+                            )
+                            flags = flags + [jnp.reshape(~pack_ok, (1,))]
+                        else:
+                            st.to("final/grouped/sort")
+                            perm, seg, ngroups = agg_ops._group_ids_impl(
+                                keys, mask
+                            )
+                        st.to("final/grouped/segreduce")
+                        out_keys, out_vals, gvalid = (
+                            agg_ops._group_reduce_impl(
+                                keys, vals, perm, seg, gcap, tuple(specs)
+                            )
                         )
-                    st.to("final/grouped/segreduce")
-                    out_keys, out_vals, gvalid = agg_ops._group_reduce_impl(
-                        keys, vals, perm, seg, gcap, tuple(specs)
-                    )
+                    # (the direct final's last output is its packed
+                    # span, one a device, for the runner's capacity)
+                    tail = (span.reshape(1),) if gslots else ()
                     if topk is not None:
                         kk, sspecs, _m = topk
                         sortcols = [
@@ -4724,15 +4868,18 @@ class DagRunner:
                             ngroups.reshape(1),
                             jnp.reshape(ok, (1,)),
                             flags,
-                        )
+                        ) + tail
                     return (
                         jax.tree.map(lambda x: x[None], out_keys),
                         jax.tree.map(lambda x: x[None], out_vals),
                         gvalid[None],
                         ngroups.reshape(1),
                         flags,
-                    )
+                    ) + tail
 
+                # the sort formulation's last flag is its packing's
+                npack = 1 if use_packed and not gslots else 0
+                tail_specs = (P("dn"),) if gslots else ()
                 if grouped and topk is not None:
                     out_specs = (
                         [(P("dn"), P("dn"))] * nkeys,
@@ -4740,16 +4887,16 @@ class DagRunner:
                         P("dn"),
                         P("dn"),
                         P("dn"),
-                        [P("dn")] * (nflags + (1 if use_packed else 0)),
-                    )
+                        [P("dn")] * (nflags + npack),
+                    ) + tail_specs
                 elif grouped:
                     out_specs = (
                         [(P("dn"), P("dn"))] * nkeys,
                         [(P("dn"), P("dn"))] * naggs,
                         P("dn"),
                         P("dn"),
-                        [P("dn")] * (nflags + (1 if use_packed else 0)),
-                    )
+                        [P("dn")] * (nflags + npack),
+                    ) + tail_specs
                 else:
                     out_specs = (
                         [(P("dn"), P("dn"))] * naggs,

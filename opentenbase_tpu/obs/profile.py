@@ -402,7 +402,7 @@ def reduce(trace: dict) -> dict:
                 "retries": 0, "programs_ms": {}, "scopes_ms": {},
                 "unscoped_ops_ms": {}, "device_busy_ms": 0.0,
                 "idle_ms": {}, "join_modes": {}, "joins": {},
-                "groups": {}, "group_keys": {},
+                "grouping": {}, "groups": {}, "group_keys": {},
                 "fragments": {},
                 "all_to_all": {},
             }
@@ -441,8 +441,10 @@ def reduce(trace: dict) -> dict:
                 if "retry_of" in n["args"]:
                     c["retries"] += 1
                 # each join's formulation with its static widths a
-                # device; a grouped final's capacity and its keys
-                for arg in ("join_modes", "joins", "groups", "group_keys"):
+                # device; a grouped final's formulation (``direct/<slots>``
+                # or ``sort``), capacity and keys
+                for arg in ("join_modes", "joins", "grouping", "groups",
+                            "group_keys"):
                     v = n["args"].get(arg)
                     if v:
                         c[arg][str(v)] = c[arg].get(str(v), 0) + 1
@@ -612,6 +614,7 @@ def render(report: dict) -> str:
             + (f", join_modes {sorted(c['join_modes'])}"
                if c["join_modes"] else "")
             + (f", joins {sorted(c['joins'])}" if c["joins"] else "")
+            + (f", grouping {sorted(c['grouping'])}" if c["grouping"] else "")
             + (f", groups {sorted(c['groups'])} of group_keys "
                f"{sorted(c['group_keys'])}" if c["groups"] else "")
         )

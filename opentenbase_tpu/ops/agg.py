@@ -288,6 +288,18 @@ def mxu_lanes_dtype_wide(key_cols, specs, arg_cols) -> int:
     return k
 
 
+def _superblocks(n: int, most: int) -> tuple:
+    """``n`` rows as scan steps of ``sb`` blocks of ``_MXU_BLOCK``:
+    (sb, rows a step, steps, rows padded to). At most ``most`` blocks a
+    step and never more than the rows need: a shard with one block of
+    rows must not pad to (and one-hot-matmul over) a full superblock of
+    zeros."""
+    sb = max(1, min(most, -(-n // _MXU_BLOCK)))
+    super_rows = sb * _MXU_BLOCK
+    ns = max(-(-n // super_rows), 1)
+    return sb, super_rows, ns, ns * super_rows
+
+
 def _mxu_group_reduce_impl(
     keys, vals, slot, num_groups: int, specs: tuple,
     bounds: Optional[MxuBounds] = None,
@@ -319,11 +331,8 @@ def _mxu_group_reduce_impl(
     # rows must not pad to (and one-hot-matmul over) 256 blocks of
     # zeros — the fixed floor made every small GROUP BY pay a
     # million-row scan
-    nb_needed = max(-(-n // _MXU_BLOCK), 1)
-    sb = min(256, nb_needed)  # per-step f32 partials: [sb, cap, K]
-    super_rows = sb * _MXU_BLOCK
-    ns = max(-(-n // super_rows), 1)
-    padded = ns * super_rows
+    # per-step f32 partials: [sb, cap, K]
+    sb, super_rows, ns, padded = _superblocks(n, 256)
     if padded != n:
         slot = jnp.pad(slot, (0, padded - n), constant_values=cap)
 
@@ -409,18 +418,12 @@ def _mxu_recombine(totals, plan, keys, slot, cap, pad0, specs):
     got = cnt > 0
     safe_cnt = jnp.maximum(cnt, 1)
 
-    def recombine(start, nl):
-        acc = totals[:, start + nl - 1]
-        for l in range(nl - 2, -1, -1):
-            acc = jnp.left_shift(acc, _LIMB_BITS) + totals[:, start + l]
-        return acc
-
     out_keys = []
     khats = []
     for (start, nl), vidx, (data, valid) in zip(
         plan.key_slices, plan.key_valid, keys
     ):
-        khat = recombine(start, nl) // safe_cnt
+        khat = _recombine_limbs(totals, start, nl) // safe_cnt
         khats.append(khat)
         d = khat.astype(data.dtype)
         if vidx is None:
@@ -444,6 +447,21 @@ def _mxu_recombine(totals, plan, keys, slot, cap, pad0, specs):
             vis & (d != jnp.take(khat, gslot, axis=0))
         )
 
+    out_vals = _mxu_recombine_vals(totals, plan, specs, cnt, got)
+    ngroups = jnp.sum(got, dtype=jnp.int32)
+    return out_keys, out_vals, got, ngroups, collision
+
+
+def _recombine_limbs(totals, start: int, nl: int):
+    """The value whose ``nl`` limb totals start at lane ``start``."""
+    acc = totals[:, start + nl - 1]
+    for l in range(nl - 2, -1, -1):
+        acc = jnp.left_shift(acc, _LIMB_BITS) + totals[:, start + l]
+    return acc
+
+
+def _mxu_recombine_vals(totals, plan, specs, cnt, got) -> list:
+    """Each spec's (value, valid) from the ``[cap, K]`` lane totals."""
     out_vals = []
     for spec, aid in zip(specs, plan.arg_ids):
         if spec == "count_star":
@@ -454,12 +472,11 @@ def _mxu_recombine(totals, plan, keys, slot, cap, pad0, specs):
         if spec == "count":
             out_vals.append((nonnull.astype(jnp.int64), got))
             continue
-        out_vals.append(
-            (recombine(*plan.arg_slices[aid]), (nonnull > 0) & got)
-        )
-
-    ngroups = jnp.sum(got, dtype=jnp.int32)
-    return out_keys, out_vals, got, ngroups, collision
+        out_vals.append((
+            _recombine_limbs(totals, *plan.arg_slices[aid]),
+            (nonnull > 0) & got,
+        ))
+    return out_vals
 
 
 def mxu_group_eligible(keys, vals, specs) -> bool:
@@ -476,6 +493,162 @@ def mxu_group_eligible(keys, vals, specs) -> bool:
             if jnp.issubdtype(val[0].dtype, jnp.floating):
                 return False
     return True
+
+
+# Direct-addressed grouping (the DAG's grouped final): the slot IS the
+# packed key, so the only question is the capacity.
+# low slots a row of the second-level one-hot: slot = hi * L + lo (32:
+# 69 ms for 67.1M rows at 8,192 slots on a v5e, 365 ms at 128, 493 ms
+# flat; PERF.md, PR 34)
+_DIRECT_LOW = 32
+# one-hot and operand elements a scan step may hold ([sb, B, H + K*L]):
+# the superblock's height follows the capacity
+_DIRECT_STEP_ELEMS = 1 << 26
+
+
+def direct_group_eligible(specs, arg_dtypes) -> bool:
+    """``mxu_group_eligible``'s rule on what the host knows before any
+    array exists: sum / count / count(*) only, sums over integers."""
+    for spec, dtype in zip(specs, arg_dtypes):
+        if spec not in ("sum", "count", "count_star"):
+            return False
+        if spec == "sum" and not np.issubdtype(dtype, np.integer):
+            return False
+    return True
+
+
+def _direct_lanes(plan: MxuLanePlan, cols, shape: tuple) -> list:
+    """The plan's lanes of one step as ``shape`` bf16 arrays (every
+    limb, flag and one is an integer of at most 8 bits: exact). A
+    64-bit column is cut from its two 32-bit words, so no shift runs
+    on an emulated int64."""
+    words: dict = {}
+
+    def word(ri, l):
+        v = cols[ri]
+        if v.dtype != jnp.int64:
+            return v, l
+        if (ri, l // 4) not in words:
+            words[ri, l // 4] = (
+                jnp.right_shift(v, 32) if l >= 4 else v
+            ).astype(jnp.int32)
+        return words[ri, l // 4], l % 4
+
+    lanes = []
+    for lane in plan.lanes:
+        if lane[0] == "ones":
+            lanes.append(jnp.ones(shape, dtype=jnp.bfloat16))
+        elif lane[0] == "f32":
+            lanes.append(cols[lane[1]].reshape(shape).astype(jnp.bfloat16))
+        else:
+            _kind, ri, l, masked = lane
+            v, l = word(ri, l)
+            v = jnp.right_shift(v.reshape(shape), _LIMB_BITS * l)
+            if masked:  # the unmasked top limb carries the sign
+                v = jnp.bitwise_and(v, _LIMB_MASK)
+            lanes.append(v.astype(jnp.bfloat16))
+    return lanes
+
+
+def _direct_group_reduce_impl(
+    vals, slot, cap: int, specs: tuple, low: Optional[int] = None,
+):
+    """Grouped sums and counts where the group's slot is known without a
+    sort or a hash: ``slot[i]`` in [0, cap) is row i's group (injective
+    by the caller's construction), ``cap`` (a power of two) for a row
+    that counts nowhere. One-hot matmuls over exact 8-bit limbs, the
+    blocks, the contraction and the exactness argument of
+    ``_mxu_group_reduce_impl`` word for word (limbs under 2^8 are
+    bf16-exact, a 4096-row block's f32 sums stay under 2^24, blocks add
+    up in int64), but with no key lanes, no collision check, and a
+    one-hot that does not grow with the capacity:
+
+    the slot splits into hi = slot // L and lo = slot mod L; a block
+    forms ``A[b, k*L + lo] = [lo_b = lo] * x_k[b]`` and multiplies
+    ``onehot_hi^T [H, B] . A [B, K*L]``. The one-hot work a row is
+    H + L*K compares instead of ``cap``, the MXU gets L*K columns
+    instead of K, and the superblock's height follows H + L*K, so
+    nothing of capacity x superblock rows exists.
+
+    Returns (out_vals, got, ngroups), lengths ``cap``."""
+    assert cap & (cap - 1) == 0, "slot capacity must be a power of two"
+    L = min(_DIRECT_LOW if low is None else low, cap)
+    H = cap // L
+    n = slot.shape[0]
+    plan = mxu_lane_plan(
+        [], specs,
+        [None if val is None else (val[0].dtype, val[1] is not None)
+         for val in vals],
+        None,
+    )
+    K = len(plan.lanes)
+    sb, super_rows, ns, padded = _superblocks(
+        n, min(256, _DIRECT_STEP_ELEMS // (_MXU_BLOCK * (H + K * L)))
+    )
+    slot = slot.astype(jnp.int32)
+    if padded != n:
+        slot = jnp.pad(slot, (0, padded - n), constant_values=cap)
+    raw = []
+    for _source, index, nl in plan.raws:
+        data, valid = vals[index]
+        if nl is None:
+            x = valid
+        else:
+            if valid is not None:  # canonical NULL payload: zero
+                data = jnp.where(valid, data, jnp.zeros((), data.dtype))
+            x = data.astype(jnp.int32 if nl <= 4 else jnp.int64)
+        if padded != n:
+            x = jnp.pad(x, (0, padded - n))
+        raw.append(x.reshape(ns, super_rows))
+    shift = L.bit_length() - 1
+    zero = jnp.zeros((), jnp.bfloat16)
+
+    def step(acc, xs):
+        sl = xs[0].reshape(sb, _MXU_BLOCK)
+        with scope("final/grouped/limbs"):
+            lb = jnp.stack(
+                _direct_lanes(plan, xs[1:], (sb, _MXU_BLOCK)), axis=-1
+            )  # [sb, B, K]
+        with scope("final/grouped/onehot"):
+            # a dead row's slot is cap: hi == H matches no column of the
+            # one-hot, so it adds nothing (the count lane included)
+            hi = jnp.right_shift(sl, shift)
+            lo = jnp.bitwise_and(sl, L - 1)
+            oh_hi = (
+                hi[..., None] == jnp.arange(H, dtype=jnp.int32)
+            ).astype(jnp.bfloat16)
+            oh_lo = lo[..., None] == jnp.arange(L, dtype=jnp.int32)
+            a = jnp.concatenate(
+                [jnp.where(oh_lo, lb[..., k:k + 1], zero) for k in range(K)],
+                axis=-1,
+            ) if L > 1 else lb  # [sb, B, K * L]
+            part = jnp.einsum(
+                "sbh,sbm->shm", oh_hi, a,
+                preferred_element_type=jnp.float32,
+            )  # every cell an exact integer under 2^24
+            # (at most 256 blocks of at most 4096 * 255 a cell, 2.7e8:
+            # the superblock's sums fit int32, one int64 convert a step)
+            return acc + jnp.sum(
+                part.astype(jnp.int32), axis=0
+            ).astype(jnp.int64), None
+
+    # (the carry derives from ``slot`` so that it varies over the mesh
+    # axis inside shard_map, as _mxu_group_reduce_impl's does)
+    acc0 = jnp.zeros((H, K * L), dtype=jnp.int64) + (
+        slot[0] * 0
+    ).astype(jnp.int64)
+    totals, _ = jax.lax.scan(
+        step, acc0, (slot.reshape(ns, super_rows), *raw)
+    )
+    with scope("final/grouped/recombine"):
+        totals = (
+            totals.reshape(H, K, L).transpose(0, 2, 1).reshape(cap, K)
+        )
+        cnt = totals[:, plan.ones]
+        got = cnt > 0
+        out_vals = _mxu_recombine_vals(totals, plan, specs, cnt, got)
+        # otb_lint: ignore[int32-width] -- a count of at most cap slots
+        return out_vals, got, jnp.sum(got, dtype=jnp.int32)
 
 
 def _group_ids_impl(keys, mask):

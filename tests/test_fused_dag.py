@@ -474,7 +474,7 @@ def test_packed_range_wrap_detected():
         np.array([-(2**62), 2**62 - 1, 0, 1], dtype=np.int64)
     )
     mask = jnp.ones(4, dtype=bool)
-    _packed, ok = _pack_group_keys([(a, None), (b, None)], mask)
+    _packed, ok, _layout = _pack_group_keys([(a, None), (b, None)], mask)
     assert not bool(np.asarray(ok)), "wrapping range must clear ok"
 
 

@@ -265,11 +265,13 @@ def test_profiler_events_equal_the_query_trace(profiled, kind):
     assert len(joins) == 1 and re.match(
         r"^join0=(radix|merge|fold):\d+x\d+$", joins[0]), joins
     assert "joins ['join0=" in profile.render(report)
-    # and its grouped final the capacity it was compiled for and its keys
-    assert [(c["groups"], c["group_keys"]) for c in classed
-            if c["groups"]] == [({"65536": 1}, {"1 (0 text)": 1})]
-    assert "groups ['65536'] of group_keys ['1 (0 text)']" in (
-        profile.render(report))
+    # and its grouped final the formulation it holds, the capacity it
+    # was compiled for and its keys
+    assert [(c["grouping"], c["groups"], c["group_keys"]) for c in classed
+            if c["groups"]] == [
+        ({"direct/8192": 1}, {"8192": 1}, {"1 (0 text)": 1})]
+    assert ("grouping ['direct/8192'], groups ['8192'] of group_keys "
+            "['1 (0 text)']") in profile.render(report)
 
 
 def test_ledger_columns_filled_with_tracing_off(wire):

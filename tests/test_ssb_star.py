@@ -4,11 +4,14 @@ the benchmark's cell builds, at a toy scale, its three statements over
 the wire against the benchmark's plain reference; the DAG runner
 answers with a dimension fold and the ``grouped`` final; a dimension
 with a hole leaves the fold and the answer holds; the grouped final's
-stages are named in the program and its launch says what it was sized
+stages are named in the program (the direct-addressed formulation's
+where the packed keys' range is small, the sort's where the data force
+it wide) and its launch says which it holds and what it was sized
 for."""
 
 import copy
 import os
+import re
 import sys
 
 import pytest
@@ -20,7 +23,9 @@ if os.path.join(ROOT, "benchmarks") not in sys.path:
 
 KINDS = ("q21", "q31", "q41")
 GROUP_KEYS = {"q21": "2 (1 text)", "q31": "3 (2 text)", "q41": "2 (1 text)"}
-STAGES = ("keys", "pack", "sort", "segreduce")
+DIRECT_STAGES = ("keys", "pack", "slot", "limbs", "onehot", "recombine")
+SORT_STAGES = ("keys", "pack", "sort", "segreduce")
+STAGES = tuple(dict.fromkeys(DIRECT_STAGES + SORT_STAGES))
 FACT_ROWS = 4000
 ROWS_PER_SF = 6_000_000
 
@@ -134,31 +139,82 @@ def launched(monkeypatch):
     return out
 
 
+def _traced_launch(star, sql):
+    star.dep.sql("set trace_queries = on")
+    try:
+        res = star.dep.sql(sql)
+    finally:
+        star.dep.sql("set trace_queries = off")
+    tr = next(x for x in reversed(star.dep.cluster.tracer.last(4))
+              if x.query == sql)
+    return res, [s for s in tr.spans if s.name == "fused.launch"]
+
+
+def _counters(star) -> tuple:
+    rows = star.fused_rows()
+    return (int(rows["grouped_direct"][-1]), int(rows["grouped_sorted"][-1]))
+
+
 @pytest.mark.parametrize("kind", ["q21", "q31"])
 def test_grouped_final_names_its_stages_and_its_size(star, kind, launched):
-    """The four stage scopes are on the lowered program's ops (and the
-    one they replace is not); the launch span carries the group
-    capacity the program was compiled for and its keys."""
+    """The packed keys of a star statement span a few thousand slots at
+    most: the program holds the direct-addressed formulation's stages
+    and none of the sort's (nor the one scope both replaced); the
+    launch span says so with the slot capacity and the keys, and
+    pg_stat_fused counts the final as direct."""
     from opentenbase_tpu.executor import fused_dag
 
     sql = star.text(kind)
-    star.dep.sql("set trace_queries = on")
-    try:
-        star.dep.sql(sql)
-    finally:
-        star.dep.sql("set trace_queries = off")
+    before = _counters(star)
+    _res, (sp,) = _traced_launch(star, sql)
     prog, args, _span_args = launched[-1]
     assert prog.__name__ == "program_dag_grouped"
     text = prog.lower(*args).as_text(debug_info=True)
-    for stage in STAGES:
-        assert f"otb/final/grouped/{stage}/" in text, stage
-    assert "otb/final/grouped/reduce" not in text
-    tr = next(x for x in reversed(star.dep.cluster.tracer.last(4))
-              if x.query == sql)
-    (sp,) = [s for s in tr.spans if s.name == "fused.launch"]
+    for stage in DIRECT_STAGES:
+        assert f"otb/final/grouped/{stage}" in text, stage
+    for stage in ("sort", "segreduce", "reduce"):
+        assert f"otb/final/grouped/{stage}" not in text, stage
+    # (the fold sorts its dimension: no sort and no scatter in the FINAL)
+    assert not re.search(r'"[^"]*otb/final/[^"]*(sort|scatter)', text)
     assert sp.args["mode"] == "grouped"
-    assert sp.args["groups"] == fused_dag.OPTIMISTIC_GROUP_CAP
+    assert sp.args["grouping"] == f"direct/{fused_dag.DIRECT_START_SLOTS}"
+    assert sp.args["groups"] == fused_dag.DIRECT_START_SLOTS
     assert sp.args["group_keys"] == GROUP_KEYS[kind]
+    assert "retry_of" not in sp.args
+    assert _counters(star) == (before[0] + 1, before[1])
+
+
+def test_a_group_key_of_high_cardinality_takes_the_sort(
+        star, launched, monkeypatch):
+    """The same function, the other formulation: an order key's range is
+    past the bound (here a toy one), so the first answer is refused on
+    its span, the sort formulation's program answers (its stages, none
+    of the direct's) and is counted as such; the repeat asks for it at
+    once."""
+    from opentenbase_tpu.executor import fused_dag
+
+    monkeypatch.setattr(fused_dag, "DIRECT_START_SLOTS", 64)
+    monkeypatch.setattr(fused_dag, "DIRECT_MAX_SLOTS", 64)
+    sql = ("select lo_orderkey, sum(lo_revenue) from lineorder, dates "
+           "where lo_orderdate = d_datekey group by lo_orderkey "
+           "order by lo_orderkey")
+    before = _counters(star)
+    res, spans = _traced_launch(star, sql)
+    assert [sp.args["grouping"] for sp in spans] == ["direct/64", "sort"]
+    assert "span" in spans[1].args["reason"]
+    assert spans[1].args["groups"] == fused_dag.OPTIMISTIC_GROUP_CAP
+    lo = star.data.blocks[0]["lineorder"]
+    assert len(res.rows) == len(set(lo["lo_orderkey"].tolist())) > 64
+    assert sum(r[1] for r in res.rows) == int(lo["lo_revenue"].sum())
+    prog, args, _span_args = launched[-1]
+    text = prog.lower(*args).as_text(debug_info=True)
+    for stage in SORT_STAGES:
+        assert f"otb/final/grouped/{stage}/" in text, stage
+    for stage in ("slot", "limbs", "onehot", "recombine"):
+        assert f"otb/final/grouped/{stage}" not in text, stage
+    _res, (again,) = _traced_launch(star, sql)
+    assert again.args["grouping"] == "sort" and "retry_of" not in again.args
+    assert _counters(star) == (before[0], before[1] + 2)
 
 
 def test_ungrouped_final_keeps_its_scope_and_carries_no_group_args(
