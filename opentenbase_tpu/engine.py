@@ -8605,6 +8605,10 @@ def _sv_fused(c: Cluster):
     # joins whose build the estimates admitted to a radix table and
     # whose static width sent them to sort-merge (once a compiled program)
     rows.append(("radix_sized_out", str(fx.radix_sized_out)))
+    # joins with more than one key pair that ran as device lookups (one
+    # pair drives, the others are checked on the matched row or sorted
+    # with it), once a compiled program that holds one
+    rows.append(("multi_key_joins", str(fx.multi_key_joins)))
     # accepted grouped finals of the DAG that addressed their groups by
     # the packed key, and those the key's range or the aggregates sent
     # to the sort formulation

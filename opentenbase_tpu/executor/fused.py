@@ -1158,6 +1158,9 @@ class FusedExecutor:
         # compiled program, never on a cached re-bind
         # (pg_stat_fused radix_sized_out)
         self.radix_sized_out = 0
+        # joins with more than one key pair the DAG runner took, once a
+        # traced program that holds one (pg_stat_fused multi_key_joins)
+        self.multi_key_joins = 0
         # accepted grouped finals of the DAG by formulation: addressed
         # directly by the packed key, or sorted because the key's range
         # or the aggregates' kinds left no choice (fused_dag._run_final;

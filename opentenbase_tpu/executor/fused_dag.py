@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import wraps
+from functools import partial, wraps
 from typing import Callable, Optional
 
 import numpy as np
@@ -702,6 +702,79 @@ def _chain_leaf(node, folded_ids=None, est=None):
         return None
 
 
+def _drive_pair(runner, node: "L.Join", build_right: bool) -> int:
+    """Which key pair of an inner join with several drives its lookup:
+    the one whose build-side key ANALYZE counted the most distinct
+    values for (a key of its table where there is one: ``c_custkey``,
+    1.5M values, before ``c_nationkey``, 25) — the likeliest to be
+    unique, which every lookup formulation needs of its build side, and
+    the only one that can be dense. The first pair without statistics.
+    A function of the node, the build side and the catalog alone, so
+    the gate, the builder and the hoist agree and a flip of the build
+    side chooses again. The other pairs are equalities a match must
+    also pass (``_Builder._build_join``)."""
+    keys = node.right_keys if build_right else node.left_keys
+    if len(keys) < 2 or runner is None:
+        return 0
+    from opentenbase_tpu.plan import costs
+
+    bnode = node.right if build_right else node.left
+    producers = getattr(runner, "_producers", None)
+    if producers:  # (a mesh: the statistics are the producers' tables')
+        bnode = _inline_sources(bnode, producers)
+    ndvs = [
+        costs.expr_ndv(k, bnode, runner.fx.catalog) or 0.0 for k in keys
+    ]
+    return ndvs.index(max(ndvs))
+
+
+def _all_equal(pairs, mask):
+    """``mask`` less the rows on which a key pair differs or holds a
+    NULL (``pairs``: [((left data, valid), (right data, valid))] over
+    the same rows)."""
+    for (ld, lv), (rd, rv) in pairs:
+        keep = ld.astype(jnp.int64) == rd.astype(jnp.int64)
+        for v in (lv, rv):
+            if v is not None:
+                keep = keep & v
+        mask = mask & keep
+    return mask
+
+
+def _spread_dead_keys(pk, pmask, bk, bmask):
+    """Probe keys for a fold whose probe rows come out of an earlier
+    join: every row that join left unmatched carries ONE garbage key
+    (its ``bidx`` was clipped to build row 0), so six in seven rows of
+    a Q5 probe the same fold slot, and which slot is the data's. With
+    them the probe's two fused gathers took 2,465 or 3,249 ms by seed,
+    the same to the millisecond on a second run of a seed (my chip
+    runs, PR 35; 1,229 ms in a join whose probe keys are spread): a Q5
+    cost 9.99 or 10.76 s by seed. Dead rows match nothing whatever
+    their key, so they probe with keys spread over the build's range
+    instead: 2,686 ms on every seed, six seeds within 0.02 %. (Why the
+    one-key probe is data-dependent is not known: a gather alone costs
+    0.579 s for 67.1M indices whatever their pattern, PERF.md §7.)"""
+    pd, pv = pk
+    bd, bv = bk
+    live = pmask if pv is None else (pmask & pv)
+    breal = bmask if bv is None else (bmask & bv)
+    base = jnp.min(jnp.where(breal, bd.astype(jnp.int64), jnp.int64(2**62)))
+    spread = jnp.arange(pd.shape[0], dtype=jnp.int32) % jnp.int32(
+        max(bd.shape[0], 1)
+    )
+    return (
+        jnp.where(live, pd.astype(jnp.int64), base + spread.astype(jnp.int64)),
+        pv,
+    )
+
+
+def _key_name(k, i: int) -> str:
+    """A join key for a span arg: its column's name, or ``expr<i>``."""
+    while isinstance(k, E.CastE):
+        k = k.operand
+    return k.name if isinstance(k, E.Col) and k.name else f"expr{i}"
+
+
 def _fold_gate(runner, node: "L.Join", ji: int, build_right: bool,
                fold_off, folded_ids=None) -> bool:
     """THE dimension-fold gate — one definition shared by the builder
@@ -728,7 +801,9 @@ def _fold_gate(runner, node: "L.Join", ji: int, build_right: bool,
     )
     if chain is None:
         return False
-    bkey = (node.right_keys if build_right else node.left_keys)[0]
+    bkey = (node.right_keys if build_right else node.left_keys)[
+        _drive_pair(runner, node, build_right)
+    ]
     if not _expr_cols(bkey) <= set(chain[1]):
         return False
     try:
@@ -1264,19 +1339,20 @@ class _Builder:
             folded_ids=self.folded_ids,
         )
 
-    def _repl_scan_leaves(self, node) -> bool:
+    def _repl_scan_leaves(self, node, every: bool = False) -> bool:
         """True when ``node``'s subtree scans a REPLICATED table
-        directly. On a multi-device mesh such a scan places the one
-        replica's rows on ONE device — fine alone (each row processed
-        once), but a join side built from it sees only a fraction of
-        the rows per device. The reference never faces this: every
-        datanode holds a full copy of a replicated table
-        (pgxc/locator.c LOCATOR_TYPE_REPLICATED)."""
+        directly (``every``: when it scans nothing else). On a
+        multi-device mesh such a scan places the one replica's rows on
+        ONE device — fine alone (each row processed once), but a join
+        side built from it sees only a fraction of the rows per device.
+        The reference never faces this: every datanode holds a full
+        copy of a replicated table (pgxc/locator.c
+        LOCATOR_TYPE_REPLICATED)."""
         try:
             leaves = list(_walk_leaves(node))
         except DagUnsupported:
             return False
-        return any(
+        return (all if every else any)(
             isinstance(lf, L.Scan)
             and self.fx.catalog.get(lf.table).dist.is_replicated
             for lf in leaves
@@ -1459,17 +1535,18 @@ class _Builder:
     def _build_join(self, node: L.Join, exchanged: dict, D: int) -> Callable:
         if node.join_type not in ("inner", "semi", "anti"):
             raise DagUnsupported(node.join_type)
-        if len(node.left_keys) != 1 or len(node.right_keys) != 1:
-            raise DagUnsupported("multi-key join")
-        for k in (node.left_keys[0], node.right_keys[0]):
+        npairs = len(node.left_keys)
+        if npairs == 0 or npairs != len(node.right_keys):
+            raise DagUnsupported(f"{node.join_type} join without keys")
+        for k in node.left_keys + node.right_keys:
             if k.type.id not in _JOINABLE_KEY_TYPES:
                 raise DagUnsupported(f"join key type {k.type.id}")
         left = self.build(node.left, exchanged, D)
         right = self.build(node.right, exchanged, D)
         ldids = [c.dict_id for c in node.left.schema]
         rdids = [c.dict_id for c in node.right.schema]
-        lkfn = self.comp.compile(node.left_keys[0], ldids)
-        rkfn = self.comp.compile(node.right_keys[0], rdids)
+        lkfns = [self.comp.compile(k, ldids) for k in node.left_keys]
+        rkfns = [self.comp.compile(k, rdids) for k in node.right_keys]
         resfn = None
         if node.residual is not None:
             jdids = [c.dict_id for c in node.schema]
@@ -1479,12 +1556,18 @@ class _Builder:
         fold = False
         use_radix = False
         bstrip_fn = None
+        # a join with several key pairs: ONE pair drives the lookup (the
+        # fold's and the radix table's key; the first key of the
+        # sort-merge), every other pair is an equality the matched row
+        # must also pass — SQL's conjunction, NULL keys matching nothing
+        drive = 0
         if jt == "inner":
             ji = self.njoin
             self.njoin += 1
             build_right = (
                 self.orientation[ji] if ji < len(self.orientation) else "R"
             ) == "R"
+            drive = _drive_pair(self.runner, node, build_right)
             fold = self._fold_eligible(node, ji, build_right)
             if fold:
                 self.folded.add(ji)
@@ -1549,7 +1632,13 @@ class _Builder:
                 b_complete = _subtree_replicated(
                     bnode2, self.fx, motions
                 )
-            if self._repl_scan_leaves(pnode2) and not b_complete:
+            # (a probe subtree that built with a sharded leaf in it has
+            # that leaf's rows: its replicated scans were build sides
+            # made complete above, or it raised here already)
+            if (
+                self._repl_scan_leaves(pnode2, every=True)
+                and not b_complete
+            ):
                 raise DagUnsupported(
                     "replicated probe vs sharded build on mesh"
                 )
@@ -1581,8 +1670,39 @@ class _Builder:
             if sized_out:
                 builder.fx.radix_sized_out += 1
 
+        lkfn, rkfn = lkfns[drive], rkfns[drive]
+        others = [i for i in range(npairs) if i != drive]
+        nleft = len(node.left.schema)
+        # ``keys=<pairs>:<the build side's driving key>`` on the join's
+        # record, for a join with more than one pair
+        keys_arg = ""
+        if others:
+            bkeys = node.right_keys if build_right else node.left_keys
+            keys_arg = f" keys={npairs}:{_key_name(bkeys[drive], drive)}"
+
         def note_widths(bn: int, pn: int) -> None:
-            builder.joins[jtag] = f"{'+'.join(sorted(traced))}:{bn}x{pn}"
+            builder.joins[jtag] = (
+                f"{'+'.join(sorted(traced))}:{bn}x{pn}{keys_arg}"
+            )
+
+        def other_pairs(lenv, ln, renv, rn, params) -> list:
+            """[(left key, right key)] of every pair but the driving
+            one, each side over its own rows: the further sort keys of
+            the sort-merge lookup."""
+            return [
+                (_bcast(lkfns[i](lenv, params), ln),
+                 _bcast(rkfns[i](renv, params), rn))
+                for i in others
+            ]
+
+        def pairs_equal(env, mask, n, params, formulation: str):
+            """The pairs the lookup did not see, checked where the
+            residual is: over the joined row the lookup matched."""
+            with scope(f"{jtag}/{formulation}/residual"):
+                return _all_equal(
+                    other_pairs(env[:nleft], n, env[nleft:], n, params),
+                    mask,
+                )
 
         def run(blocks, params, snap):
             if fold:
@@ -1603,6 +1723,11 @@ class _Builder:
                 # the duplicate leaf read); slot validity: the full
                 # build mask (filters + nested fold matches)
                 _lenv, bvis, _bvn, _bf = bstrip_fn(blocks, params, snap)
+                if others:
+                    # (in the branch a join with several pairs alone
+                    # takes: every other fold's program keeps its text)
+                    with scope(f"{jtag}/fold/spread"):
+                        pk = _spread_dead_keys(pk, pmask, bk, bmask)
                 with scope(f"{jtag}/fold"):
                     matched, bidx, dup = _lookup_dense(
                         pk, pmask, bk, bvis, bmask, presorted=presorted
@@ -1627,6 +1752,9 @@ class _Builder:
                 )
                 mask = pmask & matched
                 n = pn
+                if others:
+                    builder.fx.multi_key_joins += 1  # (at trace time)
+                    mask = pairs_equal(env, mask, n, params, "fold")
                 if resfn is not None:
                     d, v = resfn(env, params)
                     keep = d if v is None else (d & v)
@@ -1637,10 +1765,20 @@ class _Builder:
             flags = lflags + rflags
             lk = _bcast(lkfn(lenv, params), ln)
             rk = _bcast(rkfn(renv, params), rn)
+            # the sort-merge lookup over every pair: rows meet where all
+            # their keys are equal, so a build side that repeats the
+            # driving key but not the whole tuple is still a lookup
+            merge = lookup
+            if others:
+                builder.fx.multi_key_joins += 1  # (at trace time)
+                xs = other_pairs(lenv, ln, renv, rn, params)
+                if jt == "inner" and not build_right:
+                    xs = [(r, l) for l, r in xs]
+                merge = partial(lookup, extra=xs)  # (probe, build) pairs
             if jt in ("semi", "anti"):
                 # existence probe: build-side duplicates are harmless
                 with scope(f"{jtag}/merge"):
-                    matched, _bidx, _dup = lookup(
+                    matched, _bidx, _dup = merge(
                         lk, lmask, rk, rmask, check_dup=False
                     )
                 mask = lmask & (matched if jt == "semi" else ~matched)
@@ -1655,14 +1793,16 @@ class _Builder:
                     bk, bmask, benv = lk, lmask, lenv
                     bn = ln
                 if use_radix:
+                    # (a table is keyed on the driving pair alone; the
+                    # shape rule's fallback sorts on every pair)
                     matched, bidx, dup = _lookup_radix(
-                        pk, pmask, bk, bmask, radix_budget, lookup,
+                        pk, pmask, bk, bmask, radix_budget, merge,
                         pallas_probe=pallas_probe,
                         note_mode=note_mode, tag=jtag, forced=forced,
                     )
                 else:
                     with scope(f"{jtag}/merge"):
-                        matched, bidx, dup = lookup(
+                        matched, bidx, dup = merge(
                             pk, pmask, bk, bmask, check_dup=True
                         )
                 flags = flags + [dup]
@@ -1686,6 +1826,8 @@ class _Builder:
                 )
                 mask = pmask & matched
                 n = pn
+                if others and jmode == "radix":
+                    mask = pairs_equal(env, mask, n, params, "radix")
             if resfn is not None:
                 d, v = resfn(env, params)
                 keep = d if v is None else (d & v)
@@ -1858,10 +2000,12 @@ class DagRunner:
     @staticmethod
     def _join_args(prog) -> dict:
         """A program's join record as span args (None where it has no
-        join, or was not traced yet)."""
+        join, or was not traced yet). Joins are set apart by ``;``: the
+        profiler cuts a TraceMe's arguments at every comma, and a
+        statement with several joins kept only its first in a trace."""
         return {
             "join_modes": "+".join(sorted(prog.join_modes)) or None,
-            "joins": ",".join(
+            "joins": ";".join(
                 f"{k}={v}" for k, v in sorted(prog.joins.items())
             ) or None,
         }
@@ -3657,7 +3801,9 @@ class DagRunner:
             )
             for s in self._offs(skey)
         )
-        bkey = (top.right_keys if build_right else top.left_keys)[0]
+        bkey = (top.right_keys if build_right else top.left_keys)[
+            _drive_pair(self, top, build_right)
+        ]
         pkey = (
             "prep", skey, tuple(orientation), D, fo_local, sig,
             versions,
@@ -5251,7 +5397,7 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, presorted=False):
     return matched, bidx, ~dense
 
 
-def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool):
+def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):
     """Equi-join primitive by double sort — the TPU formulation.
 
     ``searchsorted`` (a vectorized binary search) costs ~30s per 60M
@@ -5268,13 +5414,25 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool):
     build row by position, ``take(sokey, pbpos)``, was 91 of this
     function's 174 ms at 2^21 x 2^23 rows. The one ``cummax`` that finds
     the last real build row carries that row's run (high word) and its
-    original position (low word) instead."""
+    original position (low word) instead.
+
+    ``extra``: the further key pairs of a join with several, as
+    [((probe data, valid), (build data, valid))]: each is one more sort
+    key after the first, so a run is a run of rows equal on EVERY pair,
+    a NULL in any key keeps its row out, and ``dup`` means two real
+    build rows equal on the whole tuple (a build side that repeats its
+    first key alone is still looked up exactly)."""
     pd, pv = pk
     bd, bv = bk
     nb = bd.shape[0]
     npr = pd.shape[0]
     breal = bmask if bv is None else (bmask & bv)
     preal = pmask if pv is None else (pmask & pv)
+    for (_xpd, xpv), (_xbd, xbv) in extra:
+        if xbv is not None:
+            breal = breal & xbv
+        if xpv is not None:
+            preal = preal & xpv
     # two sort keys — the raw key keeps its FULL int64 range (no *2
     # encode), the side byte orders real-build < real-probe < dead
     # within each key run
@@ -5291,14 +5449,21 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool):
         # can address both sides with one operand
         jnp.arange(nb, nb + npr, dtype=jnp.int32),
     ])
+    xkeys = tuple(
+        jnp.concatenate([xbd.astype(jnp.int64), xpd.astype(jnp.int64)])
+        for (xpd, _xpv), (xbd, _xbv) in extra
+    )
     with jax.named_scope("sort"):
-        skey, sside, sokey = jax.lax.sort(
-            (key, side, okey), num_keys=2, is_stable=False
+        skey, *sxkeys, sside, sokey = jax.lax.sort(
+            (key,) + xkeys + (side, okey), num_keys=2 + len(xkeys),
+            is_stable=False,
         )
     M = nb + npr
-    boundary = jnp.concatenate([
-        jnp.ones(1, jnp.bool_), skey[1:] != skey[:-1]
-    ])
+    first = jnp.ones(1, jnp.bool_)
+    differs = skey[1:] != skey[:-1]
+    for sx in sxkeys:
+        differs = differs | (sx[1:] != sx[:-1])
+    boundary = jnp.concatenate([first, differs])
     isb = sside == 0
     if check_dup and M > 1:
         dup = jnp.any(isb[1:] & isb[:-1] & ~boundary[1:])
@@ -5332,10 +5497,13 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool):
     return matched, bidx, dup
 
 
-def _lookup(pk, pmask, bk, bmask, check_dup: bool):
+def _lookup(pk, pmask, bk, bmask, check_dup: bool, extra=()):
     """Sorted-lookup equi-join primitive. Probe keys pk=(data, valid)
     [np] against build keys bk [nb]; returns (matched [np] bool,
     bidx [np] int, dup 0-d bool).
+
+    A join with several key pairs (``extra``) takes the double sort on
+    every backend: a binary search has one key.
 
     Dead/NULL build rows participate in the sort but are flagged
     not-real; the composite stable sort (reals first within equal keys)
@@ -5343,6 +5511,8 @@ def _lookup(pk, pmask, bk, bmask, check_dup: bool):
     one exists, so no sentinel values are needed and no collision can
     produce a false or missed match. ``dup`` is exact: adjacent equal
     keys where both rows are real."""
+    if extra:
+        return _lookup_sortmerge(pk, pmask, bk, bmask, check_dup, extra)
     pd, pv = pk
     bd, bv = bk
     nb = bd.shape[0]

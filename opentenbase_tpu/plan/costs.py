@@ -108,10 +108,8 @@ def expr_ndv(
 ) -> Optional[float]:
     """Distinct-value estimate of an expression over a subtree's output,
     traced through Project/Filter/Join down to base-table stats."""
-    bc = e
-    while isinstance(bc, E.CastE):
-        bc = bc.operand
-    if not isinstance(bc, E.Col):
+    bc = _bare_col(e)
+    if bc is None:
         return None
     ndv = _col_ndv(plan, bc.index, catalog)
     if ndv is None:
@@ -119,30 +117,57 @@ def expr_ndv(
     return min(ndv, estimate_rows(plan, catalog, memo))
 
 
+def key_is_unique(e: E.TExpr, plan: L.LogicalPlan, catalog) -> bool:
+    """ANALYZE's verdict that an expression is a key of the base table
+    it comes from: a bare column with (nearly) as many distinct values
+    as its table has rows — the same 0.9 share past which ANALYZE
+    itself extrapolates a sample's ndv to the table. False without
+    statistics."""
+    bc = _bare_col(e)
+    if bc is None:
+        return False
+    ndv, rows = _col_stats(plan, bc.index, catalog)
+    return bool(ndv) and bool(rows) and ndv >= 0.9 * rows
+
+
+def _bare_col(e: E.TExpr) -> Optional[E.Col]:
+    """The column an expression is, casts aside; None for anything else."""
+    while isinstance(e, E.CastE):
+        e = e.operand
+    return e if isinstance(e, E.Col) else None
+
+
 def _col_ndv(plan: L.LogicalPlan, idx: int, catalog) -> Optional[float]:
+    return _col_stats(plan, idx, catalog)[0]
+
+
+def _col_stats(plan: L.LogicalPlan, idx: int, catalog) -> tuple:
+    """(ndv, rows) of the base-table column behind output column
+    ``idx``, as ANALYZE measured them; (None, None) where it is no bare
+    column of a table with statistics."""
     if isinstance(plan, L.Scan):
         meta = _meta(catalog, plan.table)
         if meta is None:
-            return None
+            return None, None
         ndv = meta.stats.get("ndv", {}).get(plan.columns[idx])
-        return float(ndv) if ndv else None
+        return (float(ndv) if ndv else None), meta.stats.get("rows")
     if isinstance(plan, L.Filter):
-        return _col_ndv(plan.child, idx, catalog)
+        return _col_stats(plan.child, idx, catalog)
     if isinstance(plan, L.Project):
         ex = plan.exprs[idx]
         while isinstance(ex, E.CastE):
             ex = ex.operand
         if isinstance(ex, E.Col):
-            return _col_ndv(plan.child, ex.index, catalog)
-        return None
+            return _col_stats(plan.child, ex.index, catalog)
+        return None, None
     if isinstance(plan, L.Join):
         nleft = len(plan.left.schema)
         if idx < nleft or plan.join_type in ("semi", "anti"):
-            return _col_ndv(plan.left, idx, catalog)
-        return _col_ndv(plan.right, idx - nleft, catalog)
+            return _col_stats(plan.left, idx, catalog)
+        return _col_stats(plan.right, idx - nleft, catalog)
     if isinstance(plan, (L.Sort, L.Limit, L.Distinct)):
-        return _col_ndv(plan.child, idx, catalog)
-    return None
+        return _col_stats(plan.child, idx, catalog)
+    return None, None
 
 
 def _selectivity(

@@ -509,12 +509,15 @@ class Distributor:
             rc = self._to_single(right, rdist)
             return rebuild(lc, rc), Dist.single(COORDINATOR)
 
-        # both replicated: every node holds both inputs entirely — run on
-        # exactly one (preferred-node read, locator.c REPLICATED select)
+        # both replicated: every node that holds both inputs entirely
+        # holds their join entirely — it stays replicated (read from one
+        # preferred node, locator.c REPLICATED select, wherever it is
+        # delivered), so a chain of replicated tables joins a sharded
+        # one in place like a single replicated table does
         if ldist.kind == "replicated" and rdist.kind == "replicated":
             common = [n for n in ldist.nodes if n in rdist.nodes]
             if common:
-                return rebuild(left, right), Dist.single(common[0])
+                return rebuild(left, right), Dist.replicated(common)
 
         out_key_positions = self._join_out_keys(plan, ldist, jt)
 

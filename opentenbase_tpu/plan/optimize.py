@@ -193,17 +193,33 @@ def _greedy_order(join: L.Join, flat, catalog) -> Optional[L.LogicalPlan]:
             ue = usable_edges(j)
             if not ue:
                 continue
-            ndv = costs.DEFAULT_NDV
-            for _item, pk, jk, swapped in ue:
-                pn = costs.expr_ndv(
-                    _remap_expr(pk, pos), cur, catalog, memo
-                ) or costs.DEFAULT_NDV
-                jn = costs.expr_ndv(
-                    _shift_cols(jk, -inputs[j][1]), inputs[j][0],
-                    catalog, memo,
-                ) or costs.DEFAULT_NDV
-                ndv = max(ndv, pn, jn)
-            score = cur_rows * est[j] / ndv
+            # a key pair is priced by its measured ndv (the larger
+            # side's); DEFAULT_NDV only where ANALYZE gave neither side
+            # one — as a floor it priced a 25-value key as having 200
+            # and sent a cyclic predicate graph through the many-to-many
+            # edge at toy scales (TPC-H Q5's nation key)
+            ndv = 0.0
+            keyed = False
+            for _item, pk, jk, _swapped in ue:
+                pk = _remap_expr(pk, pos)
+                jk = _shift_cols(jk, -inputs[j][1])
+                pn = costs.expr_ndv(pk, cur, catalog, memo)
+                jn = costs.expr_ndv(jk, inputs[j][0], catalog, memo)
+                ndv = max(
+                    ndv, max(pn or 0.0, jn or 0.0) or costs.DEFAULT_NDV
+                )
+                keyed = (
+                    keyed
+                    or costs.key_is_unique(pk, cur, catalog)
+                    or costs.key_is_unique(jk, inputs[j][0], catalog)
+                )
+            # a join one of whose sides is a key (key = foreign key)
+            # goes before one where neither is, whatever the sizes: a
+            # many-to-many step is the one shape the device's lookup
+            # joins cannot run, and by rows alone it wins at small
+            # scales (Q5 below SF0.1: 25 * lineitem < supplier *
+            # customer rows) and loses above them
+            score = (not keyed, cur_rows * est[j] / ndv)
             if best_score is None or score < best_score:
                 best_j, best_score, best_edges = j, score, ue
         if best_j is None:
