@@ -1695,6 +1695,13 @@ class _Builder:
                 for i in others
             ]
 
+        def joined(penv, gathered) -> list:
+            """The joined row: left columns, then right."""
+            return (
+                list(penv) + gathered if build_right
+                else gathered + list(penv)
+            )
+
         def pairs_equal(env, mask, n, params, formulation: str):
             """The pairs the lookup did not see, checked where the
             residual is: over the joined row the lookup matched."""
@@ -1728,28 +1735,20 @@ class _Builder:
                     # takes: every other fold's program keeps its text)
                     with scope(f"{jtag}/fold/spread"):
                         pk = _spread_dead_keys(pk, pmask, bk, bmask)
+                # (benv comes back in key order: the slot IS the build
+                # row, and gseg's segment id beside its group keys)
                 with scope(f"{jtag}/fold"):
-                    matched, bidx, dup = _lookup_dense(
-                        pk, pmask, bk, bvis, bmask, presorted=presorted
+                    matched, bidx, dup, benv = _lookup_dense(
+                        pk, pmask, bk, bvis, bmask, benv,
+                        presorted=presorted,
                     )
                 flags = flags + [dup]
                 if do_capture:
                     builder.captured = (bidx, benv, bn)
                 note_widths(bn, pn)
                 with scope(f"{jtag}/fold/gather"):
-                    gathered = [
-                        (
-                            jnp.take(d, bidx, axis=0),
-                            None if v is None
-                            else jnp.take(v, bidx, axis=0),
-                        )
-                        for d, v in benv
-                    ]
-                env = (
-                    list(penv) + gathered
-                    if build_right
-                    else gathered + list(penv)
-                )
+                    gathered = _take_rows(benv, bidx, "clip")
+                env = joined(penv, gathered)
                 mask = pmask & matched
                 n = pn
                 if others:
@@ -1811,19 +1810,8 @@ class _Builder:
                 note_widths(bn, pn)
                 jmode = "radix" if "radix" in traced else "merge"
                 with scope(f"{jtag}/{jmode}/gather"):
-                    gathered = [
-                        (
-                            jnp.take(d, bidx, axis=0),
-                            None if v is None
-                            else jnp.take(v, bidx, axis=0),
-                        )
-                        for d, v in benv
-                    ]
-                env = (
-                    list(penv) + gathered
-                    if build_right
-                    else gathered + list(penv)
-                )
+                    gathered = _take_rows(benv, bidx)
+                env = joined(penv, gathered)
                 mask = pmask & matched
                 n = pn
                 if others and jmode == "radix":
@@ -5337,16 +5325,31 @@ def _first_true(flags) -> Optional[int]:
     return None
 
 
-def _lookup_dense(pk, pmask, bk, bvis, bfull, presorted=False):
+def _take_rows(env, idx, mode=None):
+    """Every (data, validity) column of ``env`` at the rows ``idx``
+    (``mode``: ``jnp.take``'s for an index out of range)."""
+    return [
+        (jnp.take(d, idx, axis=0, mode=mode),
+         None if v is None else jnp.take(v, idx, axis=0, mode=mode))
+        for d, v in env
+    ]
+
+
+def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False):
     """Equi-join primitive for a small dense-keyed build side.
 
     Sort the build rows by key (cheap — the build side is small by the
     fold gate), then verify the VISIBLE keys form a gap-free unique
     range [base, base+cnt): sorted position i must hold key base+i.
-    When they do, the sorted arrays ARE a perfect-hash table and every
-    probe row finds its build row with pure arithmetic: slot =
-    key - base. One small sort + one gather replaces the sort-merge
-    path's two full-probe-width sorts.
+    When they do, the build side IN KEY ORDER is a perfect-hash table
+    and every probe row finds its build row with pure arithmetic:
+    slot = key - base. ``bfull`` and the build columns ``benv``
+    ([(data, validity)]) are put in that order ONCE, at the build's
+    width (scope ``order``; a ``presorted`` build, a fold-prep
+    program's, is in it already), so the probe pays one gather for its
+    match bit and one a gathered word. Reaching them through the sort
+    permutation (``take(sidx, slot)``) was a second probe-width gather
+    a fold: 578 of 1,230 ms at 67.1M rows (ledger, PR 35, star cell).
 
     The density domain is ``bvis`` (storage visibility only); query
     predicates arrive separately as ``bfull`` and act as SLOT validity
@@ -5354,8 +5357,9 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, presorted=False):
     rows just match nothing (otherwise any selective dim filter would
     punch gaps and defeat the fold). Duplicates and gaps both break
     the position identity, so the single ``notdense`` flag subsumes
-    the dup check. Returns (matched [np] bool, bidx [np] int,
-    notdense 0-d bool)."""
+    the dup check. Returns (matched [np] bool, slot [np] int32: the
+    build row's index INTO the returned columns, notdense 0-d bool,
+    ``benv`` in key order)."""
     pd, pv = pk
     bd, bv = bk
     nb = bd.shape[0]
@@ -5365,6 +5369,7 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, presorted=False):
             jnp.zeros(npr, jnp.bool_),
             jnp.zeros(npr, jnp.int32),
             jnp.asarray(False),
+            benv,
         )
     breal = bvis if bv is None else (bvis & bv)
     preal = pmask if pv is None else (pmask & pv)
@@ -5375,13 +5380,21 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, presorted=False):
         # position-identity check below still fully verifies the claim
         # (an out-of-place or dead row breaks sk[i] == base + i)
         sk = bkey
-        sidx = jnp.arange(nb, dtype=jnp.int32)
     else:
         with jax.named_scope("build"):
             sk, sidx = jax.lax.sort(
                 (bkey, jnp.arange(nb, dtype=jnp.int32)), num_keys=1,
                 is_stable=False,
             )
+        # (``mode="clip"``, here and wherever the slot gathers: every
+        # index is in range, and ``jnp.take``'s fill for one that is
+        # not is a select over each result, which XLA fuses across
+        # sibling gathers; a table out of such a fusion stays outside
+        # memory space S(1) and the probe's gather from it costs 14-22
+        # ns an element for 8.6: a Q5 9.0 s for 6.5, my chip runs, PR 36)
+        with jax.named_scope("order"):
+            bfull = jnp.take(bfull, sidx, mode="clip")
+            benv = _take_rows(benv, sidx, "clip")
     cnt = jnp.sum(breal, dtype=jnp.int32)
     iota = jnp.arange(nb, dtype=jnp.int64)
     base = sk[0]
@@ -5392,9 +5405,8 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, presorted=False):
         slot = pd.astype(jnp.int64) - base
         inr = (slot >= 0) & (slot < cnt.astype(jnp.int64))
         sloti = jnp.clip(slot, 0, max(nb - 1, 0)).astype(jnp.int32)
-        bidx = jnp.take(sidx, sloti)
-        matched = inr & preal & jnp.take(bfull, bidx)
-    return matched, bidx, ~dense
+        matched = inr & preal & jnp.take(bfull, sloti, mode="clip")
+    return matched, sloti, ~dense, benv
 
 
 def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):

@@ -12,10 +12,20 @@ One process: it owns the chip for its lifetime and starts no child.
 Bound its run time from outside (the chip tool's --timeout).
 
 Writes JSON lines to stdout and a summary dict at the end.
+
+``--only fold_probe`` prices a dimension fold's probe alone, both ways
+(PR 36): the build side left in storage order and reached through the
+sort permutation a probe row (``take(sidx, slot)``, then the match bit
+and W columns through it), against the build side put in key order
+once at its own width and reached by the slot itself; and that
+ordering as W + 1 gathers by the permutation against W + 1 payload
+operands of the build sort. Inputs are committed to the chip, a
+sixteenth of the probe rows runs first.
 """
 
 from __future__ import annotations
 
+import argparse
 import json
 import time
 
@@ -24,30 +34,122 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def bench(name, fn, *args, reps=2):
+def bench(name, fn, *args, reps=2, check=None, **rec):
+    """Time ``fn(*args)``; ``check``: called with the first call's
+    result; ``rec``: further fields of the printed line."""
     try:
         t_c0 = time.perf_counter()
         out = fn(*args)
         jax.block_until_ready(out)
         compile_s = time.perf_counter() - t_c0
+        if check is not None:
+            check(out)
         best = float("inf")
         for _ in range(reps):
             t0 = time.perf_counter()
             out = fn(*args)
             jax.block_until_ready(out)
             best = min(best, time.perf_counter() - t0)
-        rec = {"name": name, "best_s": round(best, 4),
+        rec = {**rec, "name": name, "best_s": round(best, 5),
                "compile_s": round(compile_s, 1)}
     except Exception as e:  # keep measuring the rest
-        rec = {"name": name, "error": repr(e)[:200]}
+        rec = {**rec, "name": name, "error": repr(e)[:200]}
     print(json.dumps(rec), flush=True)
     return rec
 
 
+def fold_probe(dev, probe_rows: int, builds, words) -> None:
+    """The fold's probe by permutation and by slot, and the build
+    side's ordering by gather and by sort payload."""
+    jax.config.update("jax_enable_x64", True)  # the build key is int64
+    key = jax.random.PRNGKey(36)
+
+    def total(matched, cols):
+        return [jnp.sum(matched, dtype=jnp.int32)] + [
+            jnp.sum(jnp.where(matched, c, 0), dtype=jnp.int32) for c in cols
+        ]
+
+    def by_permutation(slot, sidx, bfull, cols):
+        bidx = jnp.take(sidx, slot)
+        return total(jnp.take(bfull, bidx), [jnp.take(c, bidx) for c in cols])
+
+    def by_slot(slot, sidx, bfull, cols):
+        # (as fused_dag._lookup_dense gathers: indices known in range)
+        bfull = jnp.take(bfull, sidx, mode="clip")
+        cols = [jnp.take(c, sidx, mode="clip") for c in cols]
+        return total(jnp.take(bfull, slot, mode="clip"),
+                     [jnp.take(c, slot, mode="clip") for c in cols])
+
+    def build_sort(bkey, bfull, cols):
+        return jax.lax.sort(
+            (bkey, jnp.arange(bkey.shape[0], dtype=jnp.int32)), num_keys=1,
+            is_stable=False,
+        )
+
+    def order_take(bkey, bfull, cols):
+        sk, sidx = build_sort(bkey, bfull, cols)
+        return [sk, jnp.take(bfull, sidx, mode="clip")] + [
+            jnp.take(c, sidx, mode="clip") for c in cols]
+
+    def order_sort(bkey, bfull, cols):
+        return jax.lax.sort((bkey, bfull) + tuple(cols), num_keys=1,
+                            is_stable=False)
+
+    def on_chip(out):
+        # an input that is not committed to the chip is computed on its
+        # host, at the host's speed (PRs 30 and 34 each lost calls)
+        if {d.platform for d in out[0].devices()} != {dev.platform}:
+            raise SystemExit("timed on the host's CPU, not on the chip")
+
+    def timed(name, fn, *args, **rec):
+        return bench(name, jax.jit(fn), *args, check=on_chip, **rec).get(
+            "best_s", float("inf"))
+
+    for nb in builds:
+        perm = jax.random.permutation(key, nb).astype(jnp.int32)
+        bkey = perm.astype(jnp.int64) + 1  # dense keys in storage order
+        sidx = jnp.argsort(perm).astype(jnp.int32)
+        bfull = (perm % 3) != 0
+        slot = jax.random.randint(key, (probe_rows,), 0, nb, jnp.int32)
+        bkey, sidx, bfull, slot = jax.device_put(
+            (bkey, sidx, bfull, slot), dev)
+        part = slot[: probe_rows // 16]
+        rec = {"case": "fold_probe", "build": nb, "probe": probe_rows}
+        timed(f"fold_order_sort_alone_{nb}", build_sort, bkey, bfull, [],
+              **rec)
+        for w in words:
+            cols = jax.device_put(
+                [perm * (i + 3) for i in range(w)], dev)
+            rec["words"] = w
+            for form, fn in (("permutation", by_permutation),
+                             ("slot", by_slot)):
+                small = timed(f"fold_probe_{form}_16th_{nb}_w{w}", fn,
+                              part, sidx, bfull, cols, **rec)
+                if small * 16 <= 8.0:
+                    timed(f"fold_probe_{form}_{nb}_w{w}", fn,
+                          slot, sidx, bfull, cols, **rec)
+            for form, fn in (("take", order_take), ("sort", order_sort)):
+                timed(f"fold_order_{form}_{nb}_w{w}", fn, bkey, bfull,
+                      cols, **rec)
+        del slot, part
+
+
 def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--only", choices=["fold_probe"])
+    ap.add_argument("--probe-rows", type=int, default=67_108_864)
+    ap.add_argument("--builds", default="32768,524288,1048576,2097152")
+    ap.add_argument("--words", default="0,1,2")
+    args = ap.parse_args()
     dev = jax.devices()[0]
     print(json.dumps({"platform": dev.platform, "kind": dev.device_kind}),
           flush=True)
+    if args.only == "fold_probe":
+        fold_probe(dev, args.probe_rows,
+                   [int(x) for x in args.builds.split(",")],
+                   [int(x) for x in args.words.split(",")])
+        print(json.dumps({"name": "done"}), flush=True)
+        return
     key = jax.random.PRNGKey(0)
 
     # --- transfer speed re-check (100MB) ---
