@@ -22,8 +22,10 @@ reads a JAX profiler trace instead (the directory given to
 ``jax.profiler.start_trace``, or one ``.xplane.pb``) and prints where
 each statement class spent its time: the ``otb:`` spans every timed
 site writes into the profiler's trace, device time by program and by
-``otb/`` scope, idle gaps by cause (obs/profile.py). No coordinator is
-contacted.
+``otb/`` scope (direct + what the ops the compiler made inherit from
+the op that reads them), the costliest ops with their ns an element,
+GB/s and each operand's memory space (``@S(1)`` on chip, ``@hbm``),
+idle gaps by cause (obs/profile.py). No coordinator is contacted.
 """
 
 from __future__ import annotations

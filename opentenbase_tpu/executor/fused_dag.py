@@ -4926,11 +4926,11 @@ class DagRunner:
                 @_staged
                 def block(blocks, st):
                     env, mask, n, flags = ev(blocks, params, snap)
-                    # (the scalar final is one stage under the name the
-                    # accepted cells' traces read, though it groups nothing)
+                    # (the scalar final groups nothing: one stage of its
+                    # own name; scope names re-key no plain XLA program)
                     st.to(
                         "final/grouped/keys" if grouped
-                        else "final/grouped/reduce"
+                        else "final/scalar/reduce"
                     )
                     flags = [jnp.reshape(f, (1,)) for f in flags]
                     keys = [_bcast(fn(env, params), n) for fn in gfns]

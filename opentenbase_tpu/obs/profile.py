@@ -21,12 +21,73 @@ by the ``otb:query`` span):
   the collective waited on, or overlapped, a peer's work) and the rest
   (exposed: every other chip was in the collective or idle too).
 
+- every device op with a stage, its sizes and where its operands lie,
+  read from the program's text (below).
+
 Two steps, so the arithmetic can be checked on a small recorded trace
 kept as JSON: ``load`` turns the file into plain lists, ``reduce`` does
 the rest. Nothing here runs on a statement's path, and nothing of JAX
 or of the benchmark is imported: the file is decoded by the few lines
 of protobuf wire format below (``jax.profiler.ProfileData`` does not
 expose the per-operation metadata that holds the scope).
+
+**The program text.** An ``XLA Ops`` event's name is its instruction's
+whole HLO line: result name, shapes with element type, dimensions and
+layout (memory space ``S(<n>)``, none is HBM), opcode, a fusion's kind
+or a custom call's target, and each operand as shape and ``%name``.
+``reduce`` parses it once an op of a program (text it does not know
+leaves the op what it was). The profiler also stores a program's whole
+optimized HLO module in the trace (plane ``/host:metadata``, one event
+metadata a program named ``<module>(<program id>)`` as its ``XLA
+Modules`` events are, stat ``Hlo Proto``), but on a v5e only for a
+program the traced process compiled itself: one loaded from the
+persistent compile cache, as a benchmark cell's are after its first
+run, comes without. Where the module is there ``load`` decodes it
+(``programs``) and it is the graph: every instruction, those that never
+run as ops too (``get-tuple-element``, ``bitcast``, ``tuple``,
+parameters), and what each fusion's callee holds. Where it is not, the
+graph is the op lines', and an operand that is no op event (a line
+gives its name and shape, not what it reads) is bound to the result it
+most likely reads (``_graph``: a tuple's element by exact shape and
+layout, a ``bitcast`` by type and element count, the latest such result
+before its reader). An op belongs to the program run that encloses it
+on its chip: ``%fusion.2`` is another op in each program.
+
+**A stage for every op.** An op whose own ``op_name`` gives an ``otb/``
+scope keeps it (``scopes_ms``: the DIRECT time, what every earlier
+report held). Any other op takes a stage from the graph, by one rule:
+
+1. from inside, where the program's module is there: a fusion whose
+   callee's instructions carry scopes takes the scope most of them
+   carry (ties: the first by name);
+2. else from the ops that READ it (compiler-made splits, relayouts and
+   copies prepare an input for the op they serve), found through
+   instructions that are no op events; where its readers' stages
+   differ, the reader that starts first in the program run gives its;
+3. where no reader has a stage, from the ops it reads, the same way;
+4. repeated until nothing moves, readers before producers in every
+   round, each round judged on the stages of the round before, so that
+   chains (split -> relayout -> scoped op) resolve and the result does
+   not depend on the order ops are visited in.
+
+``scopes_inherited_ms`` holds what each scope took in this way,
+``inherited_ops`` each such op once with the op it took its stage from,
+``unscoped_ops_ms`` / ``unscoped_ms`` / ``unscoped_pct`` what the graph
+could not place. Direct + inherited + unscoped is the device op self
+time.
+
+**What an op read, from where, at what rate.** ``ops`` lists a
+statement class's costliest ops: program, op, stage, self ms, result
+elements and ns an element, bytes moved as GB/s and as a share of the
+chip's HBM bandwidth (the device plane states it), and each operand as
+``dtype[dims]@S(1)`` or ``@hbm``; ``scope_rates`` sums the same by
+scope, with ``operands_outside_s1``: the ops that gather from a table
+(the first operand of an op whose ``op_name`` ends in ``gather``; with
+the module, a ``gather``'s first operand followed to the fusion's
+parameter) which lies outside ``S(1)``, and their ms. A gather costs
+8.6 ns an element from a table in ``S(1)`` and 14-22 from one outside
+(PERF.md, the chip record). Bytes are the compiler's own count for the
+op where the event carries one, else result + operands by the shapes.
 """
 
 from __future__ import annotations
@@ -49,6 +110,7 @@ SPLIT_SPANS = (
     "fused.wait", "fused.collect",
 )
 ALL_TO_ALL = "all_to_all"  # last word of the exchange's collective scope
+TOP_OPS = 16  # rows of a statement class's costliest-ops table
 # JAX's own name-stack frames: an ``otb/`` scope ends where one starts
 _FRAMES = frozenset((
     "while", "body", "cond", "closed_call", "shard_map", "pallas_call",
@@ -145,8 +207,10 @@ def _stat(buf, stat_names: dict):
             value = v
         elif no == 4:
             value = _signed(v)
-        elif no in (5, 6):
+        elif no == 5:
             value = _text(v)
+        elif no == 6:
+            value = v  # bytes stay bytes: a program's HLO module
         elif no == 7:
             value = stat_names.get(v, str(v))
     return name, value
@@ -154,7 +218,7 @@ def _stat(buf, stat_names: dict):
 
 def _plane(buf) -> dict:
     name = ""
-    lines, emeta, smeta = [], [], {}
+    lines, emeta, smeta, pstats = [], [], {}, []
     for no, wt, v in _fields(buf):
         if no == 2:
             name = _text(v)
@@ -162,6 +226,8 @@ def _plane(buf) -> dict:
             lines.append(v)
         elif no == 4:
             emeta.append(v)
+        elif no == 6:
+            pstats.append(v)
         elif no == 5:
             key, meta = None, None
             for n2, _w, v2 in _fields(v):
@@ -191,7 +257,8 @@ def _plane(buf) -> dict:
                 stats[k] = val
         events[key] = (ename, stats)
     return {"name": name, "lines": lines, "events": events,
-            "stats": smeta}
+            "stats": smeta,
+            "plane_stats": dict(_stat(v, smeta) for v in pstats)}
 
 
 def _line(buf, plane: dict, keep) -> dict:
@@ -246,16 +313,277 @@ def scope_of(op_name) -> str:
     return "/".join(words)
 
 
+# ---------------------------------------------------------------------------
+# the program text: an op event's name is its instruction's HLO line
+# ---------------------------------------------------------------------------
+
+# ops whose operands and results are whole tuples of buffers they hand
+# on, not bytes they move in their own time
+_CONTROL = frozenset(("while", "conditional", "call"))
+PEAK_HBM = "peak_hbm_bw_gigabytes_per_second"  # a device plane's own stat
+_NAME = re.compile(r"(?:ROOT )?%?([\w.\-]+) = ")
+_ARRAY = re.compile(r"([a-z][a-z0-9]*)\[([^\]]*)\](\{[^{}]*\})?")
+_SPACE = re.compile(r"S\((\d+)\)")
+_OPCODE = re.compile(r" ?([a-z][\w\-]*)\(")
+_OPERAND = re.compile(r" ?%?([\w.\-]+)")
+_KIND = re.compile(r"\bkind=(\w+)|custom_call_target=\"([^\"]*)\"")
+_COMMENT = re.compile(r"/\*[^*]*\*/")  # ``/*index=5*/`` in a long tuple
+
+
+def _text_shape(text: str, i: int, out: list) -> int:
+    """Reads one shape of HLO text at ``i`` into ``out`` as its array
+    leaves ``[dtype, dims, memory space, layout]`` (a tuple flattened;
+    space 0 is HBM, ``S(<n>)`` in the layout n) and returns its end."""
+    if text[i] == "(":
+        i += 1
+        while text[i] != ")":
+            i = _text_shape(text, i, out)
+            if text[i] == ",":
+                i += 1
+            while text[i] == " ":
+                i += 1
+        return i + 1
+    m = _ARRAY.match(text, i)
+    if m is None:
+        raise ValueError(text[i:i + 20])
+    dims = [int(d.lstrip("<=")) for d in m.group(2).split(",") if d]
+    layout = m.group(3) or ""
+    space = _SPACE.search(layout)
+    out.append(
+        [m.group(1), dims, int(space.group(1)) if space else 0, layout]
+    )
+    return m.end()
+
+
+def hlo_line(text: str):
+    """An op event's name, where it is the instruction's HLO line
+    (``%fusion.6 = pred[2097152]{0:T(1024)(128)(4,1)S(1)} fusion(
+    pred[2097152]{...} %x, ...), kind=kCustom, ...``), as ``(name,
+    record, {operand: its leaves})``: ``op`` the opcode, ``kind`` a
+    fusion's kind or a custom call's target, ``shapes`` the result's
+    leaves, ``operands`` their names. None for text that is none."""
+    text = _COMMENT.sub("", text)
+    try:
+        m = _NAME.match(text)
+        if m is None:
+            return None
+        shapes: list = []
+        i = _text_shape(text, m.end(), shapes)
+        op = _OPCODE.match(text, i)
+        if op is None:
+            return None
+        i = op.end()
+        operands, stubs = [], {}
+        while text[i] != ")":
+            leaves: list = []
+            if text[i] != "%":
+                i = _text_shape(text, i, leaves)
+            o = _OPERAND.match(text, i)
+            if o is None:
+                return None
+            operands.append(o.group(1))
+            if leaves:
+                stubs[o.group(1)] = leaves
+            i = o.end()
+            if text[i] == ",":
+                i += 1
+            while text[i] == " ":
+                i += 1
+        kind = _KIND.search(text, i)
+        return m.group(1), {
+            "op": op.group(1), "shapes": shapes, "operands": operands,
+            "kind": (kind.group(1) or kind.group(2)) if kind else "",
+        }, stubs
+    except (ValueError, IndexError):
+        return None
+
+
+# the same from a program's HLO module, where the trace holds one: the
+# profiler stores it for a program the traced process compiled itself
+# (a v5e run: a program loaded from the persistent compile cache comes
+# without, so a benchmark cell's warm runs have none)
+
+METADATA_PLANE = "/host:metadata"
+HLO_PROTO = "Hlo Proto"  # the stat of a program's event metadata there
+# xla_data.proto PrimitiveType
+_DTYPES = {
+    1: "pred", 2: "s8", 3: "s16", 4: "s32", 5: "s64", 6: "u8", 7: "u16",
+    8: "u32", 9: "u64", 10: "f16", 11: "f32", 12: "f64", 14: "opaque",
+    15: "c64", 16: "bf16", 17: "token", 18: "c128", 19: "f8e5m2",
+    20: "f8e4m3fn", 21: "s4", 22: "u4", 23: "f8e4m3b11fnuz",
+    24: "f8e5m2fnuz", 25: "f8e4m3fnuz", 26: "s2", 27: "u2",
+}
+_TUPLE = 13
+# what of a fusion's callee is worth a label in the costliest-ops table
+_HEAVY = frozenset((
+    "gather", "scatter", "sort", "reduce", "reduce-window", "dot",
+    "transpose", "concatenate", "dynamic-slice", "dynamic-update-slice",
+))
+# instructions a gather's table passes through unchanged on its way
+# from the fusion's parameter
+_VIEWS = frozenset(("bitcast", "convert", "copy", "reshape"))
+
+
+def _ints(v) -> list:
+    """A repeated int64 field: one varint, or a packed run of them."""
+    if isinstance(v, int):
+        return [_signed(v)]
+    out, i, n = [], 0, len(v)
+    while i < n:
+        x = shift = 0
+        while True:
+            b = v[i]
+            i += 1
+            x |= (b & 0x7F) << shift
+            if b < 0x80:
+                break
+            shift += 7
+        out.append(_signed(x))
+    return out
+
+
+def _leaves(buf, out: list) -> list:
+    """A ShapeProto as the array leaves ``_text_shape`` gives (the
+    layout's text left empty)."""
+    etype, dims, space, subs = 0, [], 0, []
+    for no, _wt, v in _fields(buf):
+        if no == 2:
+            etype = v
+        elif no == 3:
+            dims.extend(_ints(v))
+        elif no == 4:
+            subs.append(v)
+        elif no == 5:
+            for n2, _w, v2 in _fields(v):
+                if n2 == 8:
+                    space = v2
+    if etype == _TUPLE:
+        for sub in subs:
+            _leaves(sub, out)
+    else:
+        out.append([_DTYPES.get(etype, f"type{etype}"), dims, space, ""])
+    return out
+
+
+def _instruction(buf) -> dict:
+    ins = {"name": "", "op": "", "kind": "", "shapes": [], "scope": "",
+           "id": None, "operand_ids": [], "calls": []}
+    for no, _wt, v in _fields(buf):
+        if no == 1:
+            ins["name"] = _text(v)
+        elif no == 2:
+            ins["op"] = _text(v)
+        elif no == 3:
+            _leaves(v, ins["shapes"])
+        elif no == 7:
+            for n2, _w, v2 in _fields(v):
+                if n2 == 2:
+                    ins["scope"] = scope_of(_text(v2))
+        elif no == 9:
+            ins["param"] = v
+        elif no in (11, 28) and len(v):  # fusion kind, custom-call target
+            ins["kind"] = _text(v)
+        elif no == 35:
+            ins["id"] = v
+        elif no == 36:
+            ins["operand_ids"].extend(_ints(v))
+        elif no == 38:
+            ins["calls"].extend(_ints(v))
+    return ins
+
+
+def _tables(members: list) -> list:
+    """Which parameters of a fused computation are gathered FROM: each
+    ``gather``'s first operand, followed through views to a parameter."""
+    by_name = {m["name"]: m for m in members}
+    found = set()
+    for m in members:
+        if m["op"] != "gather" or not m["operands"]:
+            continue
+        src = by_name.get(m["operands"][0])
+        while src is not None and src["op"] in _VIEWS and src["operands"]:
+            src = by_name.get(src["operands"][0])
+        if src is not None and src["op"] == "parameter":
+            found.add(src.get("param", 0))
+    return sorted(found)
+
+
+def hlo_module(buf) -> dict:
+    """One ``HloProto`` as ``{instruction name: record}`` over all of its
+    computations (names are unique a module): ``op`` the opcode, ``kind``
+    a fusion's kind or a custom call's target, ``shapes`` the result's
+    array leaves, ``operands`` their names, ``scope`` its own ``otb/``
+    scope; a fusion also says what its callee holds: ``inner`` the
+    scopes of its instructions with their counts, ``holds`` its heavy
+    opcodes, ``tables`` the operands a gather inside reads from."""
+    module = b""
+    for no, _wt, v in _fields(buf):
+        if no == 1:
+            module = v
+    comps: dict = {}
+    for no, _wt, comp in _fields(module):
+        if no != 3:
+            continue
+        cid, by_id = None, {}
+        for n2, _w, v2 in _fields(comp):
+            if n2 == 5:
+                cid = v2
+            elif n2 == 2:
+                ins = _instruction(v2)
+                by_id[ins.pop("id")] = ins
+        for ins in by_id.values():
+            ins["operands"] = [
+                by_id[i]["name"] for i in ins.pop("operand_ids")
+                if i in by_id
+            ]
+        comps[cid] = list(by_id.values())
+    instrs: dict = {}
+    for members in comps.values():
+        for ins in members:
+            calls = ins.pop("calls")
+            if ins["op"] == "fusion" and calls and calls[0] in comps:
+                callee = comps[calls[0]]
+                inner: dict = {}
+                for m in callee:
+                    if m["scope"]:
+                        inner[m["scope"]] = inner.get(m["scope"], 0) + 1
+                if inner:
+                    ins["inner"] = inner
+                holds = sorted({m["op"] for m in callee} & _HEAVY)
+                if holds:
+                    ins["holds"] = holds
+                tables = _tables(callee)
+                if tables:
+                    ins["tables"] = tables
+            elif ins["op"] == "gather":
+                ins["tables"] = [0]
+            instrs[ins["name"]] = ins
+    for ins in instrs.values():
+        del ins["name"]
+    return instrs
+
+
 def load(path: str) -> dict:
-    """Device planes' module and op lines (each op with its scope) and,
-    of the host planes, the ``otb:`` spans with their args."""
+    """Device planes' module and op lines (each op with its scope, the
+    primitive its ``op_name`` ends in and the bytes the compiler counts
+    it to access) and the chip's HBM bandwidth as the plane states it,
+    of the host planes the ``otb:`` spans with their args, and under
+    ``programs`` the instructions of each program whose HLO module the
+    trace holds."""
     with open(path, "rb") as f:
         data = memoryview(f.read())
-    planes = []
+    planes, programs = [], {}
     for no, _wt, v in _fields(data):
         if no != 1:
             continue
         plane = _plane(v)
+        if plane["name"] == METADATA_PLANE:
+            # one event metadata a program, ``<module>(<program id>)`` as
+            # its ``XLA Modules`` events are named
+            for name, stats in plane["events"].values():
+                if stats.get(HLO_PROTO) is not None:
+                    programs[name] = hlo_module(stats[HLO_PROTO])
+            continue
         device = plane["name"].startswith(DEVICE_PREFIX)
 
         def keep(line: str, event: str):
@@ -272,11 +600,22 @@ def load(path: str) -> dict:
                 continue
             if device and line["name"] == OPS_LINE:
                 for ev in line["events"]:
-                    ev[3] = {"scope": scope_of(ev[3].get("tf_op"))}
+                    stats = ev[3]
+                    op_name = str(stats.get("tf_op") or "")
+                    ev[3] = {"scope": scope_of(op_name)}
+                    if "/" in op_name:
+                        ev[3]["prim"] = (
+                            op_name.split(":")[0].rsplit("/", 1)[-1]
+                        )
+                    if stats.get("bytes_accessed"):
+                        ev[3]["bytes"] = stats["bytes_accessed"]
             lines.append(line)
         if lines:
-            planes.append({"name": plane["name"], "lines": lines})
-    return {"planes": planes}
+            kept = {"name": plane["name"], "lines": lines}
+            if device and plane["plane_stats"].get(PEAK_HBM):
+                kept["hbm_gb_per_s"] = plane["plane_stats"][PEAK_HBM]
+            planes.append(kept)
+    return {"planes": planes, "programs": programs}
 
 
 # ---------------------------------------------------------------------------
@@ -330,14 +669,14 @@ def _innermost(node: dict, out: list) -> list:
 
 
 def _self_times(op_events: list) -> list:
-    """(name, scope, start, self_ns) of each device op: an op that
-    encloses others (a while loop and its body) keeps only what they
-    do not cover."""
+    """(name, scope, start, self_ns, args) of each device op: an op
+    that encloses others (a while loop and its body) keeps only what
+    they do not cover."""
     evs = sorted(op_events, key=lambda e: (e[1], -e[2]))
     out = []
     stack: list = []
     for name, s, d, a in evs:
-        rec = [name, a.get("scope", ""), s, d]
+        rec = [name, a.get("scope", ""), s, d, a]
         while stack and stack[-1][0] < s + d:
             stack.pop()
         if stack:
@@ -351,6 +690,221 @@ def _module_name(name: str) -> str:
     return name.split("(")[0]
 
 
+def _op(name: str) -> str:
+    """An op event's instruction (``%fusion.6``): what stands before
+    `` = `` in its HLO line."""
+    return name.split(" = ")[0]
+
+
+def _graph(instrs, seen: dict) -> dict:
+    """The instructions of one program by name: its HLO module where
+    the trace held one; else (and for an op the module does not name)
+    what each op event's own HLO line says, with a record for every
+    operand that is no op event. Such an operand is a parameter, a
+    constant, a ``get-tuple-element`` or a view, and a line says only
+    its name and shape, so it is bound to the result it most likely
+    reads, going through the ops in the order they start in a program
+    run: as a tuple's element to the latest tuple-shaped result so far
+    with a leaf of exactly its type, dimensions and layout (async
+    starts apart: their ``-done`` names them); else, where its name
+    says ``bitcast``, as a view to the latest result of its type and
+    element count that nothing has read yet (failing that, the
+    latest). Anything else binds to nothing."""
+    graph = dict(instrs or {})
+    parsed = []
+    for op, (text, _scope, start, args) in seen.items():
+        if op.lstrip("%") in graph:
+            continue
+        got = hlo_line(text)
+        if got is None:
+            continue
+        name, rec, stubs = got
+        if args.get("prim") == "gather" or rec["op"] == "gather":
+            rec["tables"] = [0]  # gather(table, indices)
+        graph[name] = rec
+        parsed.append((start, name, rec, stubs))
+    events = {op.lstrip("%") for op in seen}
+    elements: dict = {}  # a leaf -> the last tuple-shaped result with it
+    wholes: dict = {}  # (type, element count) -> results, oldest first
+    read = set()
+    for _start, name, rec, stubs in sorted(parsed, key=lambda p: p[:2]):
+        read.update(o for o in rec["operands"] if o in events)
+        for operand, leaves in stubs.items():
+            if operand in events or operand in graph:
+                continue
+            source = None
+            if len(leaves) == 1:
+                source = elements.get(_leaf_key(leaves[0]))
+                if source is None and operand.startswith("bitcast"):
+                    same = wholes.get((leaves[0][0], _elements(leaves[0])), ())
+                    source = next(
+                        (r for r in reversed(same) if r not in read),
+                        same[-1] if same else None,
+                    )
+            if source is not None:
+                read.add(source)
+            graph[operand] = {
+                "op": "", "kind": "", "shapes": leaves,
+                "operands": [source] if source else [],
+            }
+        if rec["op"].endswith("-start"):
+            continue
+        for leaf in rec["shapes"]:
+            if len(rec["shapes"]) > 1:
+                elements[_leaf_key(leaf)] = name
+            wholes.setdefault((leaf[0], _elements(leaf)), []).append(name)
+    return graph
+
+
+def _leaf_key(leaf) -> tuple:
+    return leaf[0], tuple(leaf[1]), leaf[3]
+
+
+def _stages(graph: dict, seen: dict) -> dict:
+    """``{op: (scope, the op it came from, how)}`` for every op event of
+    a program whose own ``op_name`` gave no scope and the graph could
+    place (the module docstring has the rule). ``seen`` is ``{op: (its
+    text, its own scope, its first start in a program run, its event's
+    args)}``."""
+    users: dict = {}
+    for name, ins in graph.items():
+        for operand in ins["operands"]:
+            users.setdefault(operand, []).append(name)
+    events = {op.lstrip("%"): op for op in seen}
+
+    def nearest(name: str, edges) -> list:
+        """The op events reached from ``name`` along ``edges`` through
+        instructions that are no events."""
+        found, done, stack = [], {name}, list(edges(name))
+        while stack:
+            n = stack.pop()
+            if n in done:
+                continue
+            done.add(n)
+            if n in events:
+                found.append(events[n])
+            else:
+                stack.extend(edges(n))
+        return found
+
+    staged: dict = {}
+    todo = []
+    for op, (_text, scope, _start, _args) in seen.items():
+        if scope:
+            continue
+        inner = graph.get(op.lstrip("%"), {}).get("inner")
+        if inner:
+            best = min(inner, key=lambda k: (-inner[k], k))
+            staged[op] = (best, op, "inside")
+        else:
+            todo.append(op)
+    readers = {
+        op: nearest(op.lstrip("%"), lambda n: users.get(n, ()))
+        for op in todo
+    }
+    producers = {
+        op: nearest(
+            op.lstrip("%"), lambda n: graph.get(n, {}).get("operands", ())
+        )
+        for op in todo
+    }
+
+    def scope_at(op: str) -> str:
+        return seen[op][1] or staged.get(op, ("",))[0]
+
+    while True:
+        for edges, how in ((readers, "reader"), (producers, "producer")):
+            placed = {}
+            for op in todo:
+                if op in staged:
+                    continue
+                scoped = [o for o in edges[op] if scope_at(o)]
+                if scoped:
+                    first = min(scoped, key=lambda o: (seen[o][2], o))
+                    placed[op] = (scope_at(first), first, how)
+            if placed:
+                staged.update(placed)
+                break
+        else:
+            return staged
+
+
+def _bits(dtype: str) -> int:
+    if dtype == "pred":
+        return 8
+    m = re.search(r"\d+", dtype)
+    return int(m.group()) if m else 0
+
+
+def _elements(leaf) -> int:
+    n = 1
+    for d in leaf[1]:
+        n *= d
+    return n
+
+
+def _show(leaf) -> str:
+    """``dtype[dims]@S(1)`` or ``@hbm``."""
+    return "%s[%s]@%s" % (
+        leaf[0], ",".join(map(str, leaf[1])),
+        f"S({leaf[2]})" if leaf[2] else "hbm",
+    )
+
+
+def _describe(graph: dict, op: str, counted) -> dict:
+    """What one op wrote and read: its label, result and operands as
+    text, result elements (its widest leaf), bytes moved (``counted``,
+    the compiler's own count where the trace carries one: it takes a
+    slice for a slice; else result + operands by the shapes, as for a
+    custom call), and whether an operand a gather reads from lies
+    outside memory space ``S(1)``."""
+    ins = graph.get(op.lstrip("%"))
+    if ins is None:
+        return {"opcode": "", "result": [], "operands": [], "tables": [],
+                "elements": 0, "bytes": counted or 0, "hbm_bytes": 0,
+                "outside_s1": False}
+    label = ins["op"]
+    if ins.get("kind"):
+        label += ":" + ins["kind"]
+    if ins.get("holds"):
+        label += "(" + ",".join(ins["holds"]) + ")"
+    operands = [
+        graph[o]["shapes"] if o in graph else [] for o in ins["operands"]
+    ]
+    leaves = ins["shapes"] + [x for part in operands for x in part]
+    moved = 0
+    if ins["op"] in _CONTROL or ins["op"].endswith(("-start", "-done")):
+        pass  # its body's ops moved them; an async copy runs beside the ops
+    elif counted:
+        moved = counted
+    else:
+        moved = sum(_elements(x) * _bits(x[0]) // 8 for x in leaves)
+    tables = [
+        operands[i] for i in ins.get("tables", ()) if i < len(operands)
+    ]
+    return {
+        "opcode": label,
+        "result": [_show(leaf) for leaf in ins["shapes"]],
+        "operands": [
+            "(" + ", ".join(map(_show, leaves)) + ")" if len(leaves) != 1
+            else _show(leaves[0]) for leaves in operands
+        ],
+        "tables": list(ins.get("tables", ())),
+        "elements": max(
+            [_elements(leaf) for leaf in ins["shapes"]], default=0
+        ) if moved else 0,
+        "bytes": moved,
+        # what of it went through HBM: the leaves that lie there, by
+        # the shapes (an operand in on-chip memory loads HBM with nothing)
+        "hbm_bytes": min(moved, sum(
+            _elements(x) * _bits(x[0]) // 8 for x in leaves if x[2] == 0
+        )),
+        "outside_s1": any(
+            leaf[2] != 1 for leaves in tables for leaf in leaves
+        ),
+    }
+
+
 def _add(d: dict, k, v) -> None:
     d[k] = d.get(k, 0.0) + v
 
@@ -362,6 +916,7 @@ def reduce(trace: dict) -> dict:
     ``statements`` is the divisor for a per-statement reading."""
     threads = []
     dev_planes = []
+    programs = trace.get("programs") or {}
     for p in trace["planes"]:
         if p["name"].startswith(DEVICE_PREFIX):
             if p["name"][len(DEVICE_PREFIX):].isdigit():
@@ -400,7 +955,9 @@ def reduce(trace: dict) -> dict:
             c = classes[key] = {
                 "statements": 0, "spans": {}, "launches": 0, "syncs": 0,
                 "retries": 0, "programs_ms": {}, "scopes_ms": {},
+                "scopes_inherited_ms": {}, "inherited_ops": {},
                 "unscoped_ops_ms": {}, "device_busy_ms": 0.0,
+                "ops": {}, "scope_rates": {},
                 "idle_ms": {}, "join_modes": {}, "joins": {},
                 "grouping": {}, "groups": {}, "group_keys": {},
                 "fragments": {},
@@ -479,26 +1036,81 @@ def reduce(trace: dict) -> dict:
                 ops = line["events"]
         # a program belongs to the statement its midpoint falls in; an
         # op to its program (same clock, exact)
-        mods = sorted((s, s + d, _module_name(n)) for n, s, d, _a in modules)
-        owner = [stmt_at((s + e) / 2.0) for s, e, _n in mods]
-        for (s, e, name), st in zip(mods, owner):
+        mods = sorted(
+            (s, s + d, _module_name(n), n) for n, s, d, _a in modules
+        )
+        owner = [stmt_at((s + e) / 2.0) for s, e, _n, _full in mods]
+        for (s, e, name, _full), st in zip(mods, owner):
             tgt = cls(st) if st is not None else outside
             _add(tgt["programs_ms"], name, (e - s) / 1e6)
         starts = [m[0] for m in mods]
-        for name, scope, s, self_ns in _self_times(ops):
+        # an op is its program's: ``%fusion.2`` is another op in each of
+        # a cell's programs, and the same op in every run of one
+        timed = []
+        seen: dict = {}  # program -> {op: (text, scope, start, args)}
+        for name, scope, s, self_ns, args in _self_times(ops):
             i = bisect.bisect_right(starts, s) - 1
-            st = (
-                owner[i] if i >= 0 and s <= mods[i][1] else stmt_at(s)
+            inside = i >= 0 and s <= mods[i][1]
+            prog = mods[i][3] if inside else ""
+            op = _op(name)
+            seen.setdefault(prog, {}).setdefault(
+                op, (name, scope, s - mods[i][0] if inside else s, args)
             )
+            timed.append(
+                (op, scope, self_ns / 1e6, prog,
+                 owner[i] if inside else stmt_at(s))
+            )
+        placed, told = {}, {}
+        for prog, its in seen.items():
+            graph = _graph(programs.get(prog), its)
+            placed[prog] = _stages(graph, its)
+            told[prog] = {
+                op: _describe(graph, op, args.get("bytes"))
+                for op, (_t, _s, _at, args) in its.items()
+            }
+        for op, scope, ms, prog, st in timed:
             if st is None:
-                outside["device_busy_ms"] += self_ns / 1e6
+                outside["device_busy_ms"] += ms
                 continue
             c = cls(st)
-            c["device_busy_ms"] += self_ns / 1e6
-            _add(c["scopes_ms"], scope or "(no scope)", self_ns / 1e6)
+            c["device_busy_ms"] += ms
+            _add(c["scopes_ms"], scope or "(no scope)", ms)
+            module = _module_name(prog)
+            stage, source = scope, None
             if not scope:
-                _add(c["unscoped_ops_ms"], name.split(" = ")[0][:60],
-                     self_ns / 1e6)
+                stage, source, how = placed[prog].get(op, ("", None, None))
+                if stage:
+                    _add(c["scopes_inherited_ms"], stage, ms)
+                    took = c["inherited_ops"].get((module, op))
+                    if took is None:
+                        took = c["inherited_ops"][module, op] = {
+                            "program": module, "op": op, "from": source,
+                            "scope": stage, "how": how, "ms": 0.0,
+                        }
+                    took["ms"] += ms
+                else:
+                    _add(c["unscoped_ops_ms"], op[:60], ms)
+            what = told[prog][op]
+            # one row for the same op of one module's programs (a
+            # literal-keyed program a parameter set), told by its shapes
+            key = (module, op, *what["result"], *what["operands"])
+            row = c["ops"].get(key)
+            if row is None:
+                row = c["ops"][key] = dict(
+                    what, program=module, op=op, scope=stage,
+                    inherited_from=source, ms=0.0, count=0,
+                )
+            row["ms"] += ms
+            row["count"] += 1
+            rate = c["scope_rates"].setdefault(
+                stage or "(no scope)",
+                {"ms": 0.0, "bytes": 0, "hbm_bytes": 0, "outside": {}},
+            )
+            rate["ms"] += ms
+            rate["bytes"] += what["bytes"]
+            rate["hbm_bytes"] += what["hbm_bytes"]
+            if what["outside_s1"]:
+                _add(rate["outside"], (module, op), ms)
         busy = _union([[s, s + d] for _n, s, d, _a in ops])
         a2a, work = [], []
         for _n, s, d, a in ops:
@@ -534,10 +1146,37 @@ def reduce(trace: dict) -> dict:
             )
             rec["total_ms"] += (e - s) / 1e6
             rec["hidden_ms"] += _overlap(s, e, others) / 1e6
+    peak = next(
+        (p["hbm_gb_per_s"] for p in dev_planes if "hbm_gb_per_s" in p), None
+    )
     for c in classes.values():
+        busy = c["device_busy_ms"]
+        c["unscoped_ms"] = sum(c["unscoped_ops_ms"].values())
+        c["unscoped_pct"] = 100.0 * c["unscoped_ms"] / busy if busy else 0.0
         c["unscoped_ops_ms"] = dict(sorted(
             c["unscoped_ops_ms"].items(), key=lambda kv: -kv[1]
         )[:8])
+        c["inherited_ops"] = sorted(
+            c["inherited_ops"].values(), key=lambda r: -r["ms"]
+        )
+        rows = sorted(c["ops"].values(), key=lambda r: -r["ms"])[:TOP_OPS]
+        for r in rows:
+            del r["outside_s1"]
+            cells = r["count"] * r["elements"]
+            r["ns_per_element"] = r["ms"] * 1e6 / cells if cells else None
+            r.update(_rate(r["bytes"] * r["count"],
+                           r.pop("hbm_bytes") * r["count"], r["ms"], peak))
+        c["ops"] = rows
+        c["scope_rates"] = {
+            k: dict(
+                _rate(v["bytes"], v["hbm_bytes"], v["ms"], peak),
+                ms=v["ms"], bytes=v["bytes"], operands_outside_s1={
+                    "ops": len(v["outside"]),
+                    "ms": sum(v["outside"].values()),
+                },
+            )
+            for k, v in c["scope_rates"].items()
+        }
         split = {
             s: c["spans"].get(s, {}).get("self_ms", 0.0)
             for s in SPLIT_SPANS
@@ -548,6 +1187,18 @@ def reduce(trace: dict) -> dict:
     return {
         "statements": len(stmts), "classes": classes, "outside": outside,
         "chips": len(dev_planes),
+    }
+
+
+def _rate(moved: float, through_hbm: float, ms: float, peak) -> dict:
+    """Bytes over milliseconds as GB/s, and where the trace states the
+    chip's HBM bandwidth, what went through HBM as a share of it."""
+    return {
+        "gb_per_s": moved / (ms * 1e6) if ms and moved else None,
+        "hbm_peak_pct": (
+            100.0 * through_hbm / (ms * 1e6) / peak
+            if ms and through_hbm and peak else None
+        ),
     }
 
 
@@ -570,7 +1221,7 @@ def _causes(idle: dict, lo: float, hi: float, modules: list,
     between two of its ops); the rest goes to the innermost span open
     on the serving thread over it, ``unattributed`` where none is."""
     rest = [[lo, hi]]
-    for s, e, name in modules:
+    for s, e, name, _full in modules:
         if e <= lo:
             continue
         if s >= hi:
@@ -591,6 +1242,91 @@ def _causes(idle: dict, lo: float, hi: float, modules: list,
             _add(idle, name, part / 1e6)
         if b - a - covered > 1e-6:
             _add(idle, "unattributed", (b - a - covered) / 1e6)
+
+
+def _num(v, spec: str) -> str:
+    return "-" if v is None else format(v, spec)
+
+
+def _render_scopes(c: dict, n: int) -> list:
+    """Device time by scope as direct + inherited, the ops the graph
+    placed and those it could not, and the costliest ops with what each
+    read from where (a report of before these keys existed renders its
+    direct times)."""
+    busy = c["device_busy_ms"]
+    direct = dict(c["scopes_ms"])
+    inherited = c.get("scopes_inherited_ms", {})
+    rates = c.get("scope_rates", {})
+    own = direct.pop("(no scope)", 0.0)
+    left = c.get("unscoped_ms", own)
+    out = ["  by scope (op self time, ms a statement): direct + inherited"
+           " = total, share of busy; GB/s moved (% of HBM peak); gathers"
+           " from a table outside S(1)"]
+    rows = [
+        (direct.get(k, 0.0) + inherited.get(k, 0.0), k)
+        for k in set(direct) | set(inherited)
+    ]
+    if own:
+        rows.append((left, "(no scope)"))
+    for v, k in sorted(rows, key=lambda r: (-r[0], r[1])):
+        r = rates.get(k, {})
+        far = r.get("operands_outside_s1", {})
+        first = 0.0 if k == "(no scope)" else direct.get(k, 0.0)
+        out.append(
+            f"    {k:<34} {first / n:>10.3f} + {(v - first) / n:>9.3f}"
+            f" = {v / n:>10.3f}  {100.0 * v / busy if busy else 0.0:5.1f} %"
+            f"  {_num(r.get('gb_per_s'), '7.1f')} GB/s"
+            f" ({_num(r.get('hbm_peak_pct'), '.1f')} %)"
+            + (f"  {far['ops']} outside S(1), {far['ms'] / n:.3f} ms"
+               if far.get("ops") else "")
+        )
+    if own:
+        out.append(
+            f"  ops whose own op_name gives no scope {own / n:.3f} ms: "
+            f"{(own - left) / n:.3f} placed by the graph, {left / n:.3f}"
+            f" ({100.0 * left / busy if busy else 0.0:.1f} % of busy) still"
+            " under none"
+        )
+    if c.get("inherited_ops"):
+        out.append("  ops the graph placed (op <- the op it took its stage "
+                   "from, stage, how):")
+        for r in c["inherited_ops"][:TOP_OPS]:
+            out.append(
+                f"    {r['op']:<28} <- {r['from']:<28} {r['scope']:<26}"
+                f" {r['how']:<8} {r['ms'] / n:>10.3f}"
+            )
+        rest = c["inherited_ops"][TOP_OPS:]
+        if rest:
+            out.append(f"    ... and {len(rest)} more, "
+                       f"{sum(r['ms'] for r in rest) / n:.3f}")
+    if c["unscoped_ops_ms"]:
+        out.append("  ops under no scope:")
+        for k, v in c["unscoped_ops_ms"].items():
+            out.append(f"    {k:<60} {v / n:>10.3f}")
+    if c.get("ops"):
+        out.append("  costliest ops: program, op, stage (<- the op it came "
+                   "from); self ms a statement, runs, ns an element of the "
+                   "result, GB/s (% of HBM peak); opcode -> result <- "
+                   "operands, each @S(<n>) or @hbm, * a gather's table")
+        for r in c["ops"]:
+            operands = ", ".join(
+                ("*" if i in r["tables"] else "") + o
+                for i, o in enumerate(r["operands"])
+            )
+            out.append(
+                f"    {r['program'][4:]:<24} {r['op']:<24} "
+                f"{r['scope'] or '(no scope)'}"
+                f"{' <- ' + r['inherited_from'] if r['inherited_from'] else ''}"
+            )
+            out.append(
+                f"      {r['ms'] / n:>10.3f} ms  x{r['count'] / n:.2f}"
+                f"  {_num(r['ns_per_element'], '.2f')} ns/el"
+                f"  {_num(r['gb_per_s'], '.1f')} GB/s"
+                f" ({_num(r['hbm_peak_pct'], '.1f')} %)"
+                + (f"  {r['opcode']} -> {', '.join(r['result'])}"
+                   f" <- {operands}" if r["opcode"] else "")
+            )
+    return out
 
 
 def render(report: dict) -> str:
@@ -662,14 +1398,7 @@ def render(report: dict) -> str:
         out.append(f"  device busy {busy / n:.3f} ms; by program:")
         for k, v in sorted(c["programs_ms"].items(), key=lambda kv: -kv[1]):
             out.append(f"    {k:<40} {v / n:>10.3f}")
-        out.append("  by scope (op self time):")
-        for k, v in sorted(c["scopes_ms"].items(), key=lambda kv: -kv[1]):
-            share = 100.0 * v / busy if busy else 0.0
-            out.append(f"    {k:<40} {v / n:>10.3f}  {share:5.1f} %")
-        if c["unscoped_ops_ms"]:
-            out.append("  ops under no scope:")
-            for k, v in c["unscoped_ops_ms"].items():
-                out.append(f"    {k:<60} {v / n:>10.3f}")
+        out.extend(_render_scopes(c, n))
         idle = sum(c["idle_ms"].values())
         out.append(f"  device idle inside statements {idle / n:.3f} ms:")
         for k, v in sorted(c["idle_ms"].items(), key=lambda kv: -kv[1]):

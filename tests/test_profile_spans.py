@@ -400,36 +400,47 @@ def test_scope_of_reads_the_stage_words():
     assert f("reduce_window_sum:") == "" and f(None) == ""
 
 
-def test_profile_reduction_on_a_recorded_trace():
-    """tests/data/profile_trace.json: two statements of one class on
-    one chip. Statement 1 runs one program with an idle gap between two
-    of its ops (inside the module); statement 2 runs two programs with
-    a gap between them while the host sits in ``fused.wait``, and a gap
-    after the last one while no inner span is open; a fourth program
-    runs outside any statement."""
+@pytest.fixture(scope="module")
+def recorded():
+    """tests/data/profile_trace.json and its reduction: three statements
+    on one chip. Class 77, statement 1 runs one program with an idle gap
+    between two of its ops (inside the module); statement 2 runs two
+    programs with a gap between them while the host sits in
+    ``fused.wait``, and a gap after the last one while no inner span is
+    open; a fourth program runs outside any statement. Class 88 is one
+    statement of three programs whose ops are the cases of the stage
+    inheritance (lines a v5e trace holds, cut to toy widths; the third
+    program comes with its HLO module)."""
     here = os.path.dirname(os.path.abspath(__file__))
     with open(os.path.join(here, "data", "profile_trace.json")) as f:
         trace = json.load(f)
-    report = profile.reduce(trace)
-    assert report["statements"] == 2
-    (key, c), = report["classes"].items()
-    assert key == "77"
+    return trace, profile.reduce(trace)
+
+
+def test_profile_reduction_on_a_recorded_trace(recorded):
+    _trace, report = recorded
+    assert report["statements"] == 3
+    assert sorted(report["classes"]) == ["77", "88"]
+    c = report["classes"]["77"]
     assert (c["launches"], c["syncs"], c["retries"]) == (3, 3, 1)
     assert c["join_modes"] == {"merge": 1, "fold+merge": 1}
     assert c["programs_ms"] == pytest.approx({
         "jit_program_scan_pallas": 6.0, "jit_program_dag_count": 1.0,
         "jit_program_dag_gsort": 4.0,
     })
-    # op self time: the while keeps what its body does not cover
-    assert c["scopes_ms"] == pytest.approx({
+    # op self time: the while keeps what its body does not cover; the
+    # DIRECT time by scope is what it was before ops took stages from
+    # the graph, to the last digit
+    assert c["scopes_ms"] == {
         "scan/decode": 2.0, "scan/kernel": 2.0, "agg/onehot": 0.5,
         "(no scope)": 1.0, "exchange/count": 0.5,
         "join0/merge/sort": 4.0,
-    })
-    assert c["device_busy_ms"] == pytest.approx(10.0)
-    assert c["unscoped_ops_ms"] == pytest.approx(
-        {"%while.1": 0.5, "%copy.9": 0.5}
-    )
+    }
+    assert c["device_busy_ms"] == 10.0
+    # their lines end in ``(...)``: nothing to read, nothing placed
+    assert c["unscoped_ops_ms"] == {"%while.1": 0.5, "%copy.9": 0.5}
+    assert c["scopes_inherited_ms"] == {} and c["inherited_ops"] == []
+    assert c["unscoped_ms"] == 1.0 and c["unscoped_pct"] == 10.0
     # each gap split over the innermost spans open across it; the part
     # a running program covers is the program's
     assert c["idle_ms"] == pytest.approx({
@@ -460,6 +471,279 @@ def test_profile_reduction_on_a_recorded_trace():
     text = profile.render(report)
     assert "in_program:jit_program_scan_pallas" in text
     assert "ops under no scope" in text
+
+
+#: op -> (the stage it takes, the op it takes it from, how), by program,
+#: in class 88 of the recorded trace; None: the graph cannot place it
+STAGE_CASES = {
+    "one_scoped_reader": (
+        "jit_program_dag_gsort", "%copy.7",
+        ("scan/kernel", "%kernel.1", "reader")),
+    # X64SplitLow -> bitcast -> relayout copy -> bitcast -> decode, as a
+    # Q6 has it: the copy first, the split a round later
+    "chain_of_two_relayout": (
+        "jit_program_dag_gsort", "%copy.1",
+        ("scan/decode", "%fusion.2", "reader")),
+    "chain_of_two_split": (
+        "jit_program_dag_gsort", "%custom-call.1",
+        ("scan/decode", "%copy.1", "reader")),
+    # %fusion.10 (scan/predicate) starts before %fusion.9 (scan/mvcc)
+    "readers_in_two_scopes_first_to_start_wins": (
+        "jit_program_dag_gsort", "%custom-call.8",
+        ("scan/predicate", "%fusion.10", "reader")),
+    "no_reader_a_scoped_producer": (
+        "jit_program_dag_gsort", "%copy.20",
+        ("scan/kernel", "%kernel.1", "producer")),
+    "neither_stays_unscoped": ("jit_program_dag_gsort", "%iota.3", None),
+    # the other program's %copy.1 is another op
+    "same_op_name_in_two_modules": (
+        "jit_program_dag_grouped", "%copy.1",
+        ("final/grouped/pack", "%fusion.12", "reader")),
+    "op_in_a_while_body": (
+        "jit_program_dag_gsort", "%dynamic-update-slice.47",
+        ("final/gsort/topk", "%while.25", "producer")),
+    # its readers name get-tuple-elements: bound by shape and order
+    "tuple_shaped_result": (
+        "jit_program_dag_gsort", "%reduce-window.2",
+        ("join1/merge/prefix", "%broadcast_select_fusion.2", "reader")),
+    "text_the_parser_does_not_know": (
+        "jit_program_dag_gsort", "%mystery.5", None),
+    "no_hlo_line_at_all": (
+        "jit_program_dag_gsort", "no HLO line at all", None),
+    # the trace holds program 903's module: a fusion from inside it ...
+    "module_fusion_from_inside": (
+        "jit_program_probe", "%fusion.1",
+        ("scan/decode", "%fusion.1", "inside")),
+    # ... and a reader found through the module's own bitcast
+    "module_reader_through_a_bitcast": (
+        "jit_program_probe", "%custom-call.2",
+        ("join0/fold/probe", "%fusion", "reader")),
+}
+
+
+@pytest.mark.parametrize("case", list(STAGE_CASES))
+def test_an_op_without_a_scope_takes_its_stage_from_the_graph(
+        recorded, case):
+    _trace, report = recorded
+    program, op, want = STAGE_CASES[case]
+    c = report["classes"]["88"]
+    got = [r for r in c["inherited_ops"]
+           if (r["program"], r["op"]) == (program, op)]
+    if want is None:
+        assert got == [] and c["unscoped_ops_ms"][op] > 0
+        return
+    (row,) = got  # listed once
+    assert (row["scope"], row["from"], row["how"]) == want
+    assert op not in c["unscoped_ops_ms"] or case.startswith("same_op")
+    text = profile.render(report)
+    assert f"{op:<28} <- {want[1]:<28} {want[0]}" in text
+
+
+def test_direct_inherited_and_unscoped_add_up_to_the_self_time(recorded):
+    """Per class: the scoped part of ``scopes_ms`` + what the graph
+    placed + what it could not = the device ops' self time, a ``while``
+    counted less its body; ``scope_rates`` holds the same total."""
+    _trace, report = recorded
+    for c in report["classes"].values():
+        direct = sum(v for k, v in c["scopes_ms"].items()
+                     if k != "(no scope)")
+        placed = sum(c["scopes_inherited_ms"].values())
+        assert placed == pytest.approx(
+            sum(r["ms"] for r in c["inherited_ops"]))
+        assert direct + placed + c["unscoped_ms"] == pytest.approx(
+            c["device_busy_ms"])
+        assert c["scopes_ms"].get("(no scope)", 0.0) == pytest.approx(
+            placed + c["unscoped_ms"])
+        assert sum(r["ms"] for r in c["scope_rates"].values()) \
+            == pytest.approx(c["device_busy_ms"])
+    c = report["classes"]["88"]
+    assert c["device_busy_ms"] == pytest.approx(30.0)
+    # %while.25 ran 4 ms round a body of 2
+    (loop,) = [r for r in c["ops"] if r["op"] == "%while.25"]
+    assert loop["ms"] == pytest.approx(2.0) and loop["bytes"] == 0
+    assert c["scopes_inherited_ms"] == pytest.approx({
+        "scan/decode": 3.0, "scan/kernel": 2.0, "scan/predicate": 1.0,
+        "join1/merge/prefix": 3.0, "final/gsort/topk": 1.0,
+        "final/grouped/pack": 1.0, "join0/fold/probe": 1.0,
+    })
+    assert c["unscoped_ms"] == pytest.approx(2.0)
+    assert c["unscoped_pct"] == pytest.approx(100.0 * 2.0 / 30.0)
+
+
+def test_costliest_ops_say_what_each_read_from_where(recorded):
+    """The table's rows: elements, ns an element, bytes (the compiler's
+    count where the event has one, else by the shapes), operands with
+    their memory space, and by scope the gathers whose table lies
+    outside ``S(1)``."""
+    _trace, report = recorded
+    c = report["classes"]["88"]
+    rows = {(r["program"], r["op"]): r for r in c["ops"]}
+    probe = rows["jit_program_dag_grouped", "%fusion.2"]
+    assert probe["scope"] == "join1/fold/probe"
+    assert probe["opcode"] == "fusion:kCustom"
+    assert probe["result"] == ["pred[1024]@hbm"]
+    assert probe["operands"] == ["pred[64]@S(1)", "s32[1024]@hbm"]
+    assert probe["tables"] == [0] and probe["elements"] == 1024
+    assert probe["ns_per_element"] == pytest.approx(2e6 / 1024)
+    assert probe["bytes"] == 1024 + 64 + 4 * 1024  # by the shapes
+    assert probe["gb_per_s"] == pytest.approx(5184 / 2e6)
+    # through HBM: the result and the indices; the table lies on chip
+    assert probe["hbm_peak_pct"] == pytest.approx(
+        100 * (1024 + 4 * 1024) / 2e6 / 819.1576375296)
+    gather = rows["jit_program_dag_grouped", "%fusion.9"]
+    assert gather["operands"][0] == "s32[64]@hbm"
+    rates = c["scope_rates"]
+    assert rates["join1/fold/probe"]["operands_outside_s1"] == {
+        "ops": 0, "ms": 0}
+    assert rates["join2/fold/gather"]["operands_outside_s1"] == {
+        "ops": 1, "ms": pytest.approx(3.0)}
+    # the event's own count wins over the shapes (3 results + 3 operands
+    # of 640 x 128 would be 1,884,160 B)
+    prefix = rows["jit_program_dag_gsort", "%broadcast_select_fusion.2"]
+    assert prefix["bytes"] == 1310720
+    scan = rows["jit_program_dag_gsort", "%reduce-window.2"]
+    assert scan["result"] == ["u32[640,128]@hbm"] * 2
+    assert scan["inherited_from"] == "%broadcast_select_fusion.2"
+    assert rates["join1/merge/prefix"]["bytes"] == 1310720 + 1310728
+    # with the module: what the fusion's callee holds, its table by the
+    # gather inside; on-chip operands alone load HBM with nothing
+    held = rows["jit_program_probe", "%fusion"]
+    assert held["opcode"] == "fusion:kCustom(gather,transpose)"
+    assert held["operands"] == ["s32[1048576]@S(1)", "s32[4194304]@S(1)"]
+    both = rates["join0/fold/probe"]  # with %custom-call.2: S(1) to S(1)
+    assert both["bytes"] == held["bytes"] + 4 * (4194304 + 1048576)
+    assert both["hbm_peak_pct"] == pytest.approx(  # %fusion's result alone
+        100 * 4 * 4194304 / 3e6 / 819.1576375296)
+    text = profile.render(report)
+    assert "<- *pred[64]@S(1), s32[1024]@hbm" in text
+    assert "1 outside S(1), 3.000 ms" in text
+    json.dumps(report)  # --json prints it
+
+
+REAL_LINES = {
+    # the one-chip Q3's gathers (ledger, PR 36, ``_fusion.6___pred_2097152
+    # __0:T_1024__128__4_1_S_1___fusion_pred_2...``)
+    "gather_table_in_s1": (
+        "%fusion.7 = pred[16777216]{0:T(1024)(128)(4,1)} fusion("
+        "pred[2097152]{0:T(1024)(128)(4,1)S(1)} %fusion.6, "
+        "s32[16777216]{0:T(1024)} %broadcast_clamp_fusion), kind=kCustom, "
+        "calls=%fused_computation.7",
+        ("fusion.7", "fusion", "kCustom", [["pred", [16777216], 0]],
+         [("fusion.6", [["pred", [2097152], 1]]),
+          ("broadcast_clamp_fusion", [["s32", [16777216], 0]])])),
+    "result_in_s1": (
+        "%fusion.6 = pred[2097152]{0:T(1024)(128)(4,1)S(1)} fusion("
+        "pred[2097152]{0:T(1024)(128)(4,1)S(1)} %reshape.314, "
+        "s32[2097152]{0:T(1024)S(1)} %broadcast_clamp_fusion.1), "
+        "kind=kCustom, calls=%fused_computation.6",
+        ("fusion.6", "fusion", "kCustom", [["pred", [2097152], 1]],
+         [("reshape.314", [["pred", [2097152], 1]]),
+          ("broadcast_clamp_fusion.1", [["s32", [2097152], 1]])])),
+    "tuple_shaped_reduce_window": (
+        "%reduce-window.4 = (u32[655360,128]{0,1:T(8,128)}, "
+        "u32[655360,128]{0,1:T(8,128)}) reduce-window("
+        "u32[655360,128]{0,1:T(8,128)} %get-tuple-element.1365, "
+        "u32[655360,128]{0,1:T(8,128)} %get-tuple-element.1226, "
+        "u32[]{:T(128)} %constant.125, u32[]{:T(128)} %constant.95), "
+        "window={size=1x128 pad=0_0x0_127}, to_apply=%region_15.30.clone",
+        ("reduce-window.4", "reduce-window", "",
+         [["u32", [655360, 128], 0]] * 2,
+         [("get-tuple-element.1365", [["u32", [655360, 128], 0]]),
+          ("get-tuple-element.1226", [["u32", [655360, 128], 0]]),
+          ("constant.125", [["u32", [], 0]]),
+          ("constant.95", [["u32", [], 0]])])),
+    "x64_split_custom_call": (
+        "%custom-call.12 = u32[2,33554432]{1,0:T(2,128)} custom-call("
+        "s64[2,33554432]{1,0:T(2,128)} %xmax.1), "
+        'custom_call_target="X64SplitHigh", sharding={replicated}, '
+        'frontend_attributes={xla.sdy.sharding="#sdy.sharding<@mesh, '
+        '[{\\"dn\\"}, {}]>"}',
+        ("custom-call.12", "custom-call", "X64SplitHigh",
+         [["u32", [2, 33554432], 0]],
+         [("xmax.1", [["s64", [2, 33554432], 0]])])),
+    "tuple_operand_of_an_async_done": (
+        "%slice-done.1 = pred[16777216]{0:T(1024)(128)(4,1)S(1)} "
+        "async-done(((pred[67108864]{0:T(1024)(128)(4,1)}), "
+        "pred[16777216]{0:T(1024)(128)(4,1)S(1)}, s32[]{:S(2)}) "
+        "%slice-start.1)",
+        ("slice-done.1", "async-done", "", [["pred", [16777216], 1]],
+         [("slice-start.1", [["pred", [67108864], 0],
+                             ["pred", [16777216], 1], ["s32", [], 2]])])),
+    "index_comments_in_a_long_tuple": (
+        "%broadcast_select_fusion.2 = (u32[640,128]{0,1:T(8,128)}, "
+        "u32[640,128]{0,1:T(8,128)}, /*index=2*/u32[640,128]{0,1:T(8,128)})"
+        " fusion(pred[640,128]{0,1:T(8,128)(4,1)S(1)} %copy-done.14), "
+        "kind=kLoop, calls=%fused_computation.2135",
+        ("broadcast_select_fusion.2", "fusion", "kLoop",
+         [["u32", [640, 128], 0]] * 3,
+         [("copy-done.14", [["pred", [640, 128], 1]])])),
+    "operands_elided": ("%while.1 = (s32[], f32[8]{0}) while(...)", None),
+    "no_instruction": ("ThreadpoolListener::Region", None),
+    "cut_short": ("%x = f32[2]{0} add(f32[2]{0} %a, f32[2]{0} %b", None),
+    "empty": ("", None),
+}
+
+
+@pytest.mark.parametrize("case", list(REAL_LINES))
+def test_hlo_line_reads_what_a_v5e_trace_holds(case):
+    """An op event's name on a v5e is its whole HLO line (my chip run,
+    PR 37, ``chiprun_out/pr37/*.xplane.pb``); text that is none gives
+    None and never raises."""
+    text, want = REAL_LINES[case]
+    got = profile.hlo_line(text)
+    if want is None:
+        assert got is None
+        return
+    name, rec, stubs = got
+    shapes = [leaf[:3] for leaf in rec["shapes"]]
+    operands = [
+        (o, [leaf[:3] for leaf in stubs[o]]) for o in rec["operands"]
+    ]
+    assert (name, rec["op"], rec["kind"], shapes, operands) == want
+    # the layout's text is kept whole: what binds a tuple's element
+    assert all(leaf[3].startswith("{") for leaf in rec["shapes"])
+
+
+def test_hlo_module_of_a_compiled_program():
+    """Where the trace holds a program's HLO module (a v5e keeps it for
+    a program the traced process compiled, not for one loaded from the
+    compile cache), ``load`` decodes it: here the CPU backend's, which
+    the profiler stores the same way."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def program_probe(x, i):
+        with jax.named_scope("otb/scan/decode"):
+            y = x * 2 + 1
+        with jax.named_scope("otb/join0/fold/probe"):
+            return jnp.take(y, i, mode="clip")
+
+    compiled = program_probe.lower(
+        jnp.arange(64, dtype=jnp.int32), jnp.arange(8, dtype=jnp.int32)
+    ).compile()
+    module = compiled.runtime_executable().hlo_modules()[0]
+    blob = module.as_serialized_hlo_module_proto()
+    size, head = len(blob), bytearray(b"\x0a")  # HloProto.hlo_module = 1
+    while True:
+        head.append((size & 0x7F) | (0x80 if size >> 7 else 0))
+        size >>= 7
+        if not size:
+            break
+    instrs = profile.hlo_module(memoryview(bytes(head) + blob))
+    text = module.to_string()
+    entry = text[text.index("ENTRY"):]
+    names = re.findall(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", entry, re.M)
+    assert names and set(names) <= set(instrs)
+    params = [i for i in instrs.values() if i["op"] == "parameter"]
+    assert {(64,), (8,)} <= {tuple(p["shapes"][0][1]) for p in params}
+    scopes = {i["scope"] for i in instrs.values()} \
+        | {s for i in instrs.values() for s in i.get("inner", ())}
+    assert {"scan/decode", "join0/fold/probe"} <= scopes
+    gathers = [i for i in instrs.values() if i.get("tables")]
+    assert gathers, "the gather names the operand it reads from"
+    for i in instrs.values():
+        assert set(i["operands"]) <= set(instrs)
 
 
 def test_ids_never_read_as_numbers():

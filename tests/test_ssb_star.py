@@ -220,15 +220,14 @@ def test_a_group_key_of_high_cardinality_takes_the_sort(
 def test_ungrouped_final_keeps_its_scope_and_carries_no_group_args(
         star, launched):
     """Flight 1's shape (a ``scalar`` final) goes through the same
-    block: its program's text must not move."""
+    block under a stage of its own name, ``final/scalar/reduce``."""
     star.dep.sql("select sum(lo_revenue) from lineorder, dates where "
                  "lo_orderdate = d_datekey and d_year = 1993")
     prog, args, span_args = launched[-1]
     assert prog.__name__ == "program_dag_scalar"
     text = prog.lower(*args).as_text(debug_info=True)
-    assert "otb/final/grouped/reduce/" in text
-    for stage in STAGES:
-        assert f"otb/final/grouped/{stage}/" not in text
+    assert "otb/final/scalar/reduce/" in text
+    assert "otb/final/grouped/" not in text
     assert span_args == {"mode": "scalar"}
 
 
