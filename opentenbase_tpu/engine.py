@@ -8609,6 +8609,11 @@ def _sv_fused(c: Cluster):
     # pair drives, the others are checked on the matched row or sorted
     # with it), once a compiled program that holds one
     rows.append(("multi_key_joins", str(fx.multi_key_joins)))
+    # dimension folds whose match bit rides in a build column the plan
+    # reads anyway (one probe-width gather less), and those that gather
+    # a bit of their own, once a compiled program that holds one
+    rows.append(("fold_bits_carried", str(fx.fold_bits["carried"])))
+    rows.append(("fold_bits_own", str(fx.fold_bits["own"])))
     # accepted grouped finals of the DAG that addressed their groups by
     # the packed key, and those the key's range or the aggregates sent
     # to the sort formulation

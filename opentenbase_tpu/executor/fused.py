@@ -1161,6 +1161,12 @@ class FusedExecutor:
         # joins with more than one key pair the DAG runner took, once a
         # traced program that holds one (pg_stat_fused multi_key_joins)
         self.multi_key_joins = 0
+        # folds by where their match bit comes from, once a traced
+        # program that holds one: riding in a build column the joined
+        # row's readers gather anyway, or gathered on its own (a
+        # dimension the plan only filters by; fused_dag._lookup_dense;
+        # pg_stat_fused fold_bits_carried / fold_bits_own)
+        self.fold_bits = {"carried": 0, "own": 0}
         # accepted grouped finals of the DAG by formulation: addressed
         # directly by the packed key, or sorted because the key's range
         # or the aggregates' kinds left no choice (fused_dag._run_final;

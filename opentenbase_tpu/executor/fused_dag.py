@@ -503,6 +503,22 @@ def _expr_cols(e, out=None):
     return out
 
 
+def _agg_reads(agg, keys: bool = True) -> Optional[set]:
+    """The positions of its input an aggregate's arguments (and, with
+    ``keys``, its group keys) read: what a final program requires of
+    the rows under it (``_Builder.build``). None, every one, without an
+    aggregate: the rows themselves are the answer."""
+    if agg is None:
+        return None
+    out: set = set()
+    for g in agg.group_exprs if keys else ():
+        _expr_cols(g, out)
+    for a in agg.aggs:
+        if a.arg is not None:
+            _expr_cols(a.arg, out)
+    return out
+
+
 def _detect_gsort(agg, root, orientation):
     """Eligibility for the co-sort join+group formulation (one
     ``lax.sort`` of concat(build, probe) keys + prefix scans — no
@@ -773,6 +789,26 @@ def _key_name(k, i: int) -> str:
     while isinstance(k, E.CastE):
         k = k.operand
     return k.name if isinstance(k, E.Col) and k.name else f"expr{i}"
+
+
+def _carrier(benv, cols, nb: int):
+    """The build column a fold's match bit rides in, or None: the first
+    of ``cols`` (positions of ``benv`` the joined row's readers fetch
+    at the probe's width anyway, in schema order) whose physical type
+    is a signed 32-bit integer (a dictionary code, an ``int``, a
+    ``date``), else the first 64-bit one (a ``bigint``, a fixed-point
+    ``decimal``: two compare words). No float and no bool: the mark is
+    a value the column is checked not to hold (``_lookup_dense``)."""
+    wide = None
+    for i in cols:
+        d = benv[i][0]
+        if d.shape != (nb,):
+            continue
+        if d.dtype == jnp.int32:
+            return i
+        if d.dtype == jnp.int64 and wide is None:
+            wide = i
+    return wide
 
 
 def _fold_gate(runner, node: "L.Join", ji: int, build_right: bool,
@@ -1479,9 +1515,21 @@ class _Builder:
         return run
 
     # -- recursive build ---------------------------------------------------
-    def build(self, node: L.LogicalPlan, exchanged: dict, D: int) -> Callable:
+    def build(
+        self, node: L.LogicalPlan, exchanged: dict, D: int,
+        required=frozenset(),
+    ) -> Callable:
+        """``required``: the positions of ``node.schema`` that whatever
+        consumes the closure's rows reads (None: every one), walked down
+        as ``plan/optimize._prune_node`` walks it. A fold chooses the
+        column its match bit rides in among those (``_build_join``); a
+        caller that cannot say passes nothing and its folds keep a bit
+        of their own, which costs a gather and is never wrong."""
         if isinstance(node, L.Filter):
-            child = self.build(node.child, exchanged, D)
+            child_req = None if required is None else (
+                set(required) | _expr_cols(node.predicate)
+            )
+            child = self.build(node.child, exchanged, D, child_req)
             dids = [c.dict_id for c in node.child.schema]
             pred = self.comp.compile(node.predicate, dids)
             stage = (
@@ -1499,7 +1547,11 @@ class _Builder:
             return run
 
         if isinstance(node, L.Project):
-            child = self.build(node.child, exchanged, D)
+            child_req: set = set()
+            for i, ex in enumerate(node.exprs):
+                if required is None or i in required:
+                    _expr_cols(ex, child_req)
+            child = self.build(node.child, exchanged, D, child_req)
             dids = [c.dict_id for c in node.child.schema]
             fns = [
                 self.comp.compile(
@@ -1528,11 +1580,13 @@ class _Builder:
             return self._leaf_exch(node, exchanged)
 
         if isinstance(node, L.Join):
-            return self._build_join(node, exchanged, D)
+            return self._build_join(node, exchanged, D, required)
 
         raise DagUnsupported(type(node).__name__)
 
-    def _build_join(self, node: L.Join, exchanged: dict, D: int) -> Callable:
+    def _build_join(
+        self, node: L.Join, exchanged: dict, D: int, required=frozenset()
+    ) -> Callable:
         if node.join_type not in ("inner", "semi", "anti"):
             raise DagUnsupported(node.join_type)
         npairs = len(node.left_keys)
@@ -1541,8 +1595,22 @@ class _Builder:
         for k in node.left_keys + node.right_keys:
             if k.type.id not in _JOINABLE_KEY_TYPES:
                 raise DagUnsupported(f"join key type {k.type.id}")
-        left = self.build(node.left, exchanged, D)
-        right = self.build(node.right, exchanged, D)
+        nleft = len(node.left.schema)
+        # positions of the joined row read AFTER the lookup: by whatever
+        # is above, and by this join's residual
+        after = set(
+            range(len(node.schema)) if required is None else required
+        )
+        if node.residual is not None:
+            _expr_cols(node.residual, after)
+        lreq = {i for i in after if i < nleft}
+        rreq = {i - nleft for i in after if i >= nleft}
+        for k in node.left_keys:
+            _expr_cols(k, lreq)
+        for k in node.right_keys:
+            _expr_cols(k, rreq)
+        left = self.build(node.left, exchanged, D, lreq)
+        right = self.build(node.right, exchanged, D, rreq)
         ldids = [c.dict_id for c in node.left.schema]
         rdids = [c.dict_id for c in node.right.schema]
         lkfns = [self.comp.compile(k, ldids) for k in node.left_keys]
@@ -1672,17 +1740,31 @@ class _Builder:
 
         lkfn, rkfn = lkfns[drive], rkfns[drive]
         others = [i for i in range(npairs) if i != drive]
-        nleft = len(node.left.schema)
+        # a fold's match bit rides in a build column the joined row's
+        # readers fetch anyway, where there is one (``_carrier``): the
+        # build side's columns read after the lookup, this join's other
+        # key pairs included (Q5's customer join reads ``c_nationkey``
+        # only there). The driving key alone is read before it.
+        bcols: list = []
+        bschema = (node.right if build_right else node.left).schema
+        bkeys = node.right_keys if build_right else node.left_keys
+        if fold:
+            boff = nleft if build_right else 0
+            seen = {
+                i - boff for i in after if 0 <= i - boff < len(bschema)
+            }
+            for i in others:
+                _expr_cols(bkeys[i], seen)
+            bcols = sorted(seen)
         # ``keys=<pairs>:<the build side's driving key>`` on the join's
         # record, for a join with more than one pair
         keys_arg = ""
         if others:
-            bkeys = node.right_keys if build_right else node.left_keys
             keys_arg = f" keys={npairs}:{_key_name(bkeys[drive], drive)}"
 
-        def note_widths(bn: int, pn: int) -> None:
+        def note_widths(bn: int, pn: int, bit: str = "") -> None:
             builder.joins[jtag] = (
-                f"{'+'.join(sorted(traced))}:{bn}x{pn}{keys_arg}"
+                f"{'+'.join(sorted(traced))}:{bn}x{pn}{bit}{keys_arg}"
             )
 
         def other_pairs(lenv, ln, renv, rn, params) -> list:
@@ -1736,18 +1818,43 @@ class _Builder:
                     with scope(f"{jtag}/fold/spread"):
                         pk = _spread_dead_keys(pk, pmask, bk, bmask)
                 # (benv comes back in key order: the slot IS the build
-                # row, and gseg's segment id beside its group keys)
+                # row, and gseg's segment id beside its group keys.)
+                # The probe costs ``max(W, 1)`` probe-width gathers for
+                # W gathered words: with a carrier the match bit is
+                # read off the carrier's word (``word``), and the
+                # bit's own gather is gone: 648 ms a fold at 67.1M rows,
+                # seven of them the costliest ops of a star rotation
+                # (ledger, PR 37, ssb_star_sf10_1chip.star breakdown:
+                # ``pred[67108864]`` fusions, 1.945 s a window each).
+                # Without one (a dimension the plan only filters by) the
+                # fold is traced op for op as before.
+                ci = _carrier(benv, bcols, bn)
                 with scope(f"{jtag}/fold"):
-                    matched, bidx, dup, benv = _lookup_dense(
+                    matched, bidx, dup, benv, word = _lookup_dense(
                         pk, pmask, bk, bvis, bmask, benv,
-                        presorted=presorted,
+                        presorted=presorted, carrier=ci,
                     )
                 flags = flags + [dup]
                 if do_capture:
                     builder.captured = (bidx, benv, bn)
-                note_widths(bn, pn)
+                bit = "own" if ci is None else (
+                    bschema[ci].name or f"col{ci}"
+                )
+                note_widths(bn, pn, f" bit={bit}")
+                builder.fx.fold_bits[  # (at trace time)
+                    "own" if ci is None else "carried"
+                ] += 1
                 with scope(f"{jtag}/fold/gather"):
-                    gathered = _take_rows(benv, bidx, "clip")
+                    gathered = _take_rows(
+                        [c for i, c in enumerate(benv) if i != ci],
+                        bidx, "clip",
+                    )
+                    if ci is not None:
+                        cv = benv[ci][1]
+                        gathered.insert(ci, (
+                            word, None if cv is None
+                            else jnp.take(cv, bidx, axis=0, mode="clip"),
+                        ))
                 env = joined(penv, gathered)
                 mask = pmask & matched
                 n = pn
@@ -2516,7 +2623,7 @@ class DagRunner:
             self.fx, comp, orientation, root, runner=self, D=D,
             fold_off=fo,
         )
-        ev = b.build(root, exchanged, D)
+        ev = b.build(root, exchanged, D, None)  # every column is sent
         mesh = self.fx.mesh
         ncols = len(root.schema)
         nflags = _count_inner_joins(root)
@@ -2596,7 +2703,7 @@ class DagRunner:
             self.fx, comp, orientation, root, runner=self, D=D,
             fold_off=fo,
         )
-        ev = b.build(root, exchanged, D)
+        ev = b.build(root, exchanged, D, set(hashpos))  # routed, not sent
         routed = self._routed_eval(ev, hashpos)
         mesh = self.fx.mesh
         nflags = _count_inner_joins(root)
@@ -2629,7 +2736,7 @@ class DagRunner:
             self.fx, comp, orientation, root, runner=self, D=D,
             fold_off=fo,
         )
-        ev = b.build(root, exchanged, D)
+        ev = b.build(root, exchanged, D, None)  # every column is sent
         routed = self._routed_eval(ev, hashpos)
         mesh = self.fx.mesh
         ncols = len(root.schema)
@@ -2900,7 +3007,7 @@ class DagRunner:
                         self.fx, comp, orientation, root, runner=self,
                         D=D, fold_off=fo,
                     )
-                    ev = b.build(root, exchanged, D)
+                    ev = b.build(root, exchanged, D, _agg_reads(agg))
                     return self._compile_gagg(
                         b, ev, comp, agg, root, tk, D,
                         _count_inner_joins(root), narrow=narrow,
@@ -3886,7 +3993,7 @@ class DagRunner:
             self.fx, comp, ori_local, bnode, runner=self, D=D,
             fold_off=fo_local,
         )
-        ev = b.build(bnode, exchanged, D)
+        ev = b.build(bnode, exchanged, D, None)  # every column rides
         chain = _chain_leaf(bnode, folded_ids=b.folded_ids)
         if chain is None:
             # a nested build join was runtime-disabled (fold_off):
@@ -3961,7 +4068,7 @@ class DagRunner:
             self.fx, comp, orientation, root, runner=self, D=D,
             fold_off=fo, window=(id(leaf), width),
         )
-        ev = b.build(root, exchanged, D)
+        ev = b.build(root, exchanged, D, _agg_reads(agg))
         dids = [c.dict_id for c in root.schema]
         gfns = [comp.compile(g, dids) for g in agg.group_exprs]
         specs, afns = _agg_specs(comp, agg, dids)
@@ -4375,6 +4482,8 @@ class DagRunner:
         build_cols = gs["build_cols"]
         bkey_col = gs["bkey_col"]
         residual = gs.get("residual")
+        # (what the co-sort reads of either side is not walked down: a
+        # fold under it keeps a match bit of its own)
         left_fn = b.build(join.left, exchanged, D)
         right_fn = b.build(join.right, exchanged, D)
         # the top join is this program's co-sort: a sort-merge
@@ -4883,11 +4992,14 @@ class DagRunner:
             capture_id=bg[0] if bg is not None else None,
             runner=self, D=D, fold_off=fo,
         )
-        ev = b.build(root, exchanged, D)
+        gseg = agg is not None and bg is not None and topk is not None
+        # (gseg's group keys are the captured build side's columns, at
+        # the build's width: only its aggregates read the joined rows)
+        ev = b.build(root, exchanged, D, _agg_reads(agg, keys=not gseg))
         mesh = self.fx.mesh
         nflags = _count_inner_joins(root)
 
-        if agg is not None and bg is not None and topk is not None:
+        if gseg:
             return self._compile_gseg(
                 b, ev, comp, agg, root, topk, psum, D, nflags
             ) + (b.jinfo(),)
@@ -5335,7 +5447,8 @@ def _take_rows(env, idx, mode=None):
     ]
 
 
-def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False):
+def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False,
+                  carrier=None):
     """Equi-join primitive for a small dense-keyed build side.
 
     Sort the build rows by key (cheap — the build side is small by the
@@ -5343,13 +5456,12 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False):
     range [base, base+cnt): sorted position i must hold key base+i.
     When they do, the build side IN KEY ORDER is a perfect-hash table
     and every probe row finds its build row with pure arithmetic:
-    slot = key - base. ``bfull`` and the build columns ``benv``
-    ([(data, validity)]) are put in that order ONCE, at the build's
-    width (scope ``order``; a ``presorted`` build, a fold-prep
-    program's, is in it already), so the probe pays one gather for its
-    match bit and one a gathered word. Reaching them through the sort
-    permutation (``take(sidx, slot)``) was a second probe-width gather
-    a fold: 578 of 1,230 ms at 67.1M rows (ledger, PR 35, star cell).
+    slot = key - base. The build columns ``benv`` ([(data, validity)])
+    are put in that order ONCE, at the build's width (scope ``order``;
+    a ``presorted`` build, a fold-prep program's, is in it already).
+    Reaching them through the sort permutation (``take(sidx, slot)``)
+    was a second probe-width gather a fold: 578 of 1,230 ms at 67.1M
+    rows (ledger, PR 35, star cell).
 
     The density domain is ``bvis`` (storage visibility only); query
     predicates arrive separately as ``bfull`` and act as SLOT validity
@@ -5357,9 +5469,32 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False):
     rows just match nothing (otherwise any selective dim filter would
     punch gaps and defeat the fold). Duplicates and gaps both break
     the position identity, so the single ``notdense`` flag subsumes
-    the dup check. Returns (matched [np] bool, slot [np] int32: the
-    build row's index INTO the returned columns, notdense 0-d bool,
-    ``benv`` in key order)."""
+    the dup check.
+
+    Where the match bit comes from. ``carrier`` None: ``bfull`` is
+    ordered with the columns and gathered by the slot, a probe-width
+    gather of its own: 648 ms at 67.1M rows, 9.66 ns an element, the
+    seven costliest device ops of a star rotation (ledger, PR 37,
+    ``ssb_star_sf10_1chip.star`` ``breakdown``: seven
+    ``pred[67108864]`` fusions of 1.945 s a window each). ``carrier``
+    = i: ``benv[i]`` is a signed-integer column the join gathers by the
+    slot anyway, and the bit rides in it: a build row that fails
+    ``bfull`` is marked with ``SENT``, the dtype's minimum, at the
+    build's width and BEFORE the ``order`` gather (what the probe reads
+    stays the single output of a gather fusion, which the compiler
+    places in memory space S(1): PR 36), ``bfull`` itself is no longer
+    ordered, and the probe reads the match off the gathered word: ``W``
+    gathers at either width, not ``1 + W``. A NULL's data word is
+    don't-care and is zeroed so that it cannot read as ``SENT``; its
+    validity plane rides as before. A passing row that HOLDS ``SENT``
+    would read as unmatched: it raises the flag, and the runner answers
+    as for a build that is not dense (fold off for that join, the next
+    formulation is exact).
+
+    Returns (matched [np] bool, slot [np] int32: the build row's index
+    INTO the returned columns, flag 0-d bool, ``benv`` in key order
+    (the carrier's data marked), the carrier's data at every probe
+    row's slot [np] or None)."""
     pd, pv = pk
     bd, bv = bk
     nb = bd.shape[0]
@@ -5370,11 +5505,23 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False):
             jnp.zeros(npr, jnp.int32),
             jnp.asarray(False),
             benv,
+            None if carrier is None
+            else jnp.zeros(npr, benv[carrier][0].dtype),
         )
     breal = bvis if bv is None else (bvis & bv)
     preal = pmask if pv is None else (pmask & pv)
     BIG = jnp.int64(2**62)
     bkey = jnp.where(breal, bd.astype(jnp.int64), BIG)
+    clash = None
+    if carrier is not None:
+        cd, cv = benv[carrier]
+        SENT = jnp.asarray(jnp.iinfo(cd.dtype).min, cd.dtype)
+        with jax.named_scope("mark"):
+            if cv is not None:
+                cd = jnp.where(cv, cd, jnp.zeros((), cd.dtype))
+            clash = jnp.any(bfull & (cd == SENT))
+            benv = list(benv)
+            benv[carrier] = (jnp.where(bfull, cd, SENT), cv)
     if presorted:
         # a fold-prep program already key-sorted these rows; the
         # position-identity check below still fully verifies the claim
@@ -5393,7 +5540,8 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False):
         # memory space S(1) and the probe's gather from it costs 14-22
         # ns an element for 8.6: a Q5 9.0 s for 6.5, my chip runs, PR 36)
         with jax.named_scope("order"):
-            bfull = jnp.take(bfull, sidx, mode="clip")
+            if carrier is None:
+                bfull = jnp.take(bfull, sidx, mode="clip")
             benv = _take_rows(benv, sidx, "clip")
     cnt = jnp.sum(breal, dtype=jnp.int32)
     iota = jnp.arange(nb, dtype=jnp.int64)
@@ -5405,8 +5553,14 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False):
         slot = pd.astype(jnp.int64) - base
         inr = (slot >= 0) & (slot < cnt.astype(jnp.int64))
         sloti = jnp.clip(slot, 0, max(nb - 1, 0)).astype(jnp.int32)
-        matched = inr & preal & jnp.take(bfull, sloti, mode="clip")
-    return matched, sloti, ~dense, benv
+        if carrier is None:
+            word = None
+            matched = inr & preal & jnp.take(bfull, sloti, mode="clip")
+        else:
+            word = jnp.take(benv[carrier][0], sloti, mode="clip")
+            matched = inr & preal & (word != SENT)
+    flag = ~dense if clash is None else (~dense | clash)
+    return matched, sloti, flag, benv, word
 
 
 def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):

@@ -399,6 +399,13 @@ def test_windowed_gagg_hoisted_build_prep(monkeypatch):
     assert ("prep",) == tuple(
         k[0] for k in runner._programs if k[0] == "prep"
     ), "prep program was not compiled (hoist did not engage)"
+    # the presorted build carries the top fold's match bit in a column
+    # the final reads (marked on rows already in key order: no gather)
+    records = [
+        rec for entry in runner._programs.values() for prog in entry
+        for rec in getattr(prog, "joins", {}).values()
+    ]
+    assert "fold:1024x1024 bit=cat" in records, records
     final_idx, batch = res
     ex = LocalExecutor(
         c.catalog, {}, c.gts.snapshot_ts(),

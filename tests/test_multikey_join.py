@@ -271,27 +271,44 @@ CELLS = {
     "ssb_star_sf10_1chip": "star_q21_q31_q41",
 }
 # kind -> digests of the programs its statement launches, in order.
-# Re-pinned ON PURPOSE by PR 36 (ROADMAP.md Design 2d), for the programs
-# that hold a fold and for no other: q21, q31, q41 (two or three folded
-# dimensions each) and the third of q3's four (the gagg that folds
-# ``customer`` onto ``orders``). Their fold now puts the build side in
-# key order once (``join<i>/fold/order``) and probes by the slot
-# itself, a probe-width gather less a fold, its gathers with
-# ``mode="clip"`` (tests/test_fold_slot_probe.py holds the count; both
-# moved the cells on the chip, PERF.md §6 PR 36). Flight 1's three, q3's
-# count, broadcast and sort-merge programs and SORTMERGE_DIGEST hold no
-# fold and did not move.
+# Re-pinned ON PURPOSE by PR 38 (PR 36 before it, ROADMAP.md Design 2d),
+# for the programs that hold a fold WITH A CARRIER and for no other:
+# q21, q31, q41 (``part`` / ``customer`` and ``supplier`` / ``customer``
+# bring a dictionary code the final groups by) and the third of q3's
+# four. A fold whose dimension the plan reads an integer column of after
+# the lookup no longer gathers its match bit: rows the dimension's
+# filter drops are marked in that column at the build's width and the
+# probe reads the match off the word it gathers anyway, a probe-width
+# gather less a fold (tests/test_fold_slot_probe.py holds the count;
+# PERF.md §6 PR 38 has what it moved on the chip). q3's third program
+# is the eight-device toy's FIRST attempt, a ``gagg`` that folds
+# ``orders`` onto ``lineitem`` and reads ``o_orderdate`` (its density
+# flag refuses it on every run, as before, and the fourth answers); no
+# program the benchmark's Q3 cells time has a carried fold:
+# ``test_one_chip_q3_keeps_the_program_it_times`` below. Flight 1's
+# three, q3's count, broadcast and radix programs and SORTMERGE_DIGEST
+# hold no carried fold and did not move.
 PROGRAM_DIGESTS = {
  'q11': ['program_dag_scalar:3adf94616349d696'],
  'q12': ['program_dag_scalar:5cc378cd711b20d2'],
  'q13': ['program_dag_scalar:e68a2a2291412bb5'],
- 'q21': ['program_dag_grouped:c06591ac33bf65a9'],
+ 'q21': ['program_dag_grouped:643be789d9f7aa64'],
  'q3': ['program_dag_count:2dc092c5d0bfe138',
         'program_dag_broadcast:a1292c7f75dd922a',
-        'program_dag_gagg:d50c15f762019a9d',
+        'program_dag_gagg:e28c1f408faeb3b1',
         'program_dag_gagg:0cd905f5a3621d3f'],
- 'q31': ['program_dag_grouped:28b354dd43fa0b1c'],
- 'q41': ['program_dag_grouped:968082385a17c4dc']}
+ 'q31': ['program_dag_grouped:e390f5619239e334'],
+ 'q41': ['program_dag_grouped:025ca4f4abcf563b']}
+# kind -> where each fold's match bit comes from (``bit=`` of the
+# launch's ``joins``): the first 32-bit integer column of the dimension
+# the plan reads after the lookup, its own gather where there is none
+# (at this scale ``supplier`` and ``customer`` come before ``dates``,
+# which sort-merges; on the chip ``dates`` is ``join0``, a radix table)
+FOLD_BITS = {
+ 'q21': {'join0': 'own', 'join1': 'p_brand1'},
+ 'q31': {'join0': 's_nation', 'join1': 'c_nation'},
+ 'q41': {'join0': 'own', 'join1': 'c_nation', 'join3': 'own'},
+}
 # TPC-H's and SSB's row counts and distinct values at SF1 (a key's ndv
 # is its table's rows); what scales is multiplied by the scale factor
 SF1_STATS = {
@@ -410,6 +427,7 @@ class Cell:
         monkeypatch.setattr(fused.Launcher, "__call__", call)
         self.dep.sql(self.texts[kind])
         monkeypatch.setattr(fused.Launcher, "__call__", real)
+        self.launched = [prog for prog, _built in seen]
         return [
             prog.__name__ + ":" + hashlib.sha256(
                 prog.lower(*built).as_text(debug_info=False).encode()
@@ -448,8 +466,84 @@ def test_cells_programs_lower_to_the_text_they_had(cells, kind, monkeypatch):
     the benchmark's own deployments at a toy scale: every program they
     launch lowers to the pinned text (the last PR to move one on purpose
     says so beside ``PROGRAM_DIGESTS``)."""
-    assert cells(kind).program_digests(kind, monkeypatch) == \
-        PROGRAM_DIGESTS[kind]
+    cell = cells(kind)
+    assert cell.program_digests(kind, monkeypatch) == PROGRAM_DIGESTS[kind]
+    if kind in FOLD_BITS:
+        (prog,) = cell.launched
+        assert {
+            j: rec.split(" bit=")[1] for j, rec in prog.joins.items()
+            if rec.startswith("fold:")
+        } == FOLD_BITS[kind]
+
+
+def test_one_chip_q3_keeps_the_program_it_times(monkeypatch):
+    """``tpch_sf10_1chip.join`` on its one device, at SF10's statistics:
+    the ``gsort`` every timed Q3 runs folds ``customer``, which the plan
+    only filters by, so its match bit keeps a gather of its own and the
+    program the text its parent lowered (PR 37, commit 60ccd05). The
+    first attempt, a ``gagg`` that folds ``orders`` too and is refused
+    by its density flag once a warm-up, reads ``o_orderdate``: its bit
+    rides there and its text moved with PR 38."""
+    from opentenbase_tpu.executor import fused
+
+    real = fused.build_mesh
+    monkeypatch.setattr(
+        fused, "build_mesh", lambda _devs=None: real(jax.devices()[:1]))
+    cell = Cell("tpch_sf10_1chip")
+    try:
+        cell.set_stats(10)
+        digests = cell.program_digests("q3", monkeypatch)
+        assert [d.split(":")[0] for d in digests] == [
+            "program_dag_gagg", "program_dag_gsort"]
+        assert digests[1] == "program_dag_gsort:cd9296a4bf022cdc"
+        first, timed = cell.launched
+        assert first.joins == {"join0": "fold:128x1024 bit=own",
+                               "join1": "fold:1024x8192 bit=o_orderdate"}
+        assert timed.joins == {"join0": "fold:128x1024 bit=own"}
+        assert timed.join_modes == {"fold", "merge"}
+    finally:
+        cell.dep.close()
+
+
+def test_q5s_two_big_folds_carry_their_bits():
+    """TPC-H Q5 over its cell's deployment at a toy scale (60 suppliers:
+    the least at which the snowflake arm folds as it does at SF10): the
+    arm's folds (``join1`` onto ``supplier``, ``join2`` onto
+    ``lineitem``) read ``n_name`` and ``s_nationkey`` after the lookup
+    and carry their bit in the 32-bit dictionary code; the customer fold
+    (``join4``) reads ``c_nationkey`` only in its own second key pair,
+    which is a read after the lookup all the same; ``region`` is only
+    filtered by. And the answer is the reference's."""
+    from test_tpch_q5 import Q5
+
+    q = Q5(fact_rows=36_000)
+    try:
+        res = q.dep.sql(q.text("ASIA", 1994))
+        rows = q.fused_rows()
+        assert rows["last_programs"][-1].split(",")[-1] == \
+            "program_dag_grouped"
+        runner = q.dep.cluster.fused_executor()._dag
+        (joins,) = [
+            entry[0].joins for key, entry in runner._programs.items()
+            if key[0] == "final" and "fold" in entry[0].joins["join4"]
+            and "fold" not in entry[0].joins["join3"]
+        ]  # (the one that answered: ``orders`` is no dense range)
+        assert {j: rec.split(":")[0] + rec[rec.index(" bit="):]
+                for j, rec in joins.items() if " bit=" in rec} == {
+            "join0": "fold bit=own", "join1": "fold bit=n_name",
+            "join2": "fold bit=n_name",
+            "join4": "fold bit=c_nationkey keys=2:c_custkey",
+        }
+        assert int(rows["fold_bits_carried"][-1]) >= 3
+        ref = q.data.module.reference(
+            "q5", {"region": "ASIA", "year": 1994}, q.data.blocks,
+            q.data.glob, exact=True,
+        )
+        got = q.compare.compare_statement(res.rows, ref)
+        assert ref["rows"] and got["wrong"] is None, got
+        assert got["sum_gap"] <= 1e-12
+    finally:
+        q.close()
 
 
 @pytest.mark.parametrize("kind", sorted(KIND_CELL))

@@ -40,10 +40,11 @@ class Q5:
     """The configuration's six tables on 2 datanodes behind the wire
     server, data and reference from benchmarks/datasets/tpch_q5.py."""
 
-    def __init__(self, devices: int = 1):
+    def __init__(self, devices: int = 1, fact_rows: int = FACT_ROWS):
         """``devices``: how many of the test's eight virtual devices the
         coordinator's mesh takes: the configuration's one chip, where
-        the fragments inline to one program (the default), or more."""
+        the fragments inline to one program (the default), or more.
+        ``fact_rows``: ``lineitem``'s; the other tables scale with it."""
         import jax
         from harness import compare, loader, traffic
         from opentenbase_tpu.executor import fused
@@ -62,7 +63,7 @@ class Q5:
         self.mix = traffic.read_mix("q5")
         assert self.mix["rotation"] == ["q5"]
         assert self.mix["statements"]["q5"]["parameter_sets"] == 2
-        self.data = loader.generate(cfg, SEED, FACT_ROWS / ROWS_PER_SF)
+        self.data = loader.generate(cfg, SEED, fact_rows / ROWS_PER_SF)
         self.dep = loader.Deployment(cfg)
         self.dep.create_tables()
         self.dep.load(self.data)

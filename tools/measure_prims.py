@@ -13,14 +13,18 @@ Bound its run time from outside (the chip tool's --timeout).
 
 Writes JSON lines to stdout and a summary dict at the end.
 
-``--only fold_probe`` prices a dimension fold's probe alone, both ways
-(PR 36): the build side left in storage order and reached through the
-sort permutation a probe row (``take(sidx, slot)``, then the match bit
-and W columns through it), against the build side put in key order
-once at its own width and reached by the slot itself; and that
-ordering as W + 1 gathers by the permutation against W + 1 payload
-operands of the build sort. Inputs are committed to the chip, a
-sixteenth of the probe rows runs first.
+``--only fold_probe`` prices a dimension fold's probe alone, three ways
+(PRs 36 and 38): the build side left in storage order and reached
+through the sort permutation a probe row (``take(sidx, slot)``, then
+the match bit and W columns through it), against the build side put in
+key order once at its own width and reached by the slot itself (form
+``slot``: 1 + W probe-width gathers), against the same with the match
+bit riding in the first gathered word (form ``carried``, W >= 1: rows
+that fail the filter marked with INT32_MIN at the build's width, W
+gathers at either width); and the ordering as W + 1 gathers by the
+permutation against W + 1 payload operands of the build sort. Same
+inputs and widths for every form, committed to the chip; a sixteenth
+of the probe rows runs first.
 """
 
 from __future__ import annotations
@@ -80,6 +84,15 @@ def fold_probe(dev, probe_rows: int, builds, words) -> None:
         return total(jnp.take(bfull, slot, mode="clip"),
                      [jnp.take(c, slot, mode="clip") for c in cols])
 
+    def carried(slot, sidx, bfull, cols):
+        # (as _lookup_dense with a carrier: the mark before the order
+        # gather, the match read off the word the probe gathers anyway)
+        sent = jnp.iinfo(jnp.int32).min
+        cols = [jnp.where(bfull, cols[0], sent)] + list(cols[1:])
+        cols = [jnp.take(c, sidx, mode="clip") for c in cols]
+        got = [jnp.take(c, slot, mode="clip") for c in cols]
+        return total(got[0] != sent, got)
+
     def build_sort(bkey, bfull, cols):
         return jax.lax.sort(
             (bkey, jnp.arange(bkey.shape[0], dtype=jnp.int32)), num_keys=1,
@@ -122,7 +135,9 @@ def fold_probe(dev, probe_rows: int, builds, words) -> None:
                 [perm * (i + 3) for i in range(w)], dev)
             rec["words"] = w
             for form, fn in (("permutation", by_permutation),
-                             ("slot", by_slot)):
+                             ("slot", by_slot), ("carried", carried)):
+                if form == "carried" and not w:
+                    continue  # no word to ride in
                 small = timed(f"fold_probe_{form}_16th_{nb}_w{w}", fn,
                               part, sidx, bfull, cols, **rec)
                 if small * 16 <= 8.0:
