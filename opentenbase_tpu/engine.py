@@ -8619,6 +8619,11 @@ def _sv_fused(c: Cluster):
     # to the sort formulation
     rows.append(("grouped_direct", str(fx.grouped_direct)))
     rows.append(("grouped_sorted", str(fx.grouped_sorted)))
+    # accepted one-sort grouped top-k finals, and the group keys their
+    # programs kept out of the sort key for a kept key determines them
+    # (recovered at the output rows), once a compiled program
+    rows.append(("gagg_finals", str(fx.gagg_finals)))
+    rows.append(("gagg_keys_dropped", str(fx.gagg_keys_dropped)))
     dag = fx._dag
     if dag is not None:
         rows.append(("completed", str(dag.completed)))

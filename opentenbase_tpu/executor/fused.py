@@ -1173,6 +1173,12 @@ class FusedExecutor:
         # pg_stat_fused grouped_direct / grouped_sorted)
         self.grouped_direct = 0
         self.grouped_sorted = 0
+        # accepted finals of the one-sort grouped top-k (gagg), and the
+        # group keys its programs left out of the packed sort key because
+        # a kept key determines them (fused_dag._fd_reduce), once a
+        # traced program (pg_stat_fused gagg_finals / gagg_keys_dropped)
+        self.gagg_finals = 0
+        self.gagg_keys_dropped = 0
         self._mxu_binds: dict = {}  # id(plan) -> (plan, stats, _MxuBind)
         # the statement path's one way to call a jitted program
         # (fused.launch span, launch/retry accounting); the DAG runner

@@ -593,15 +593,37 @@ def _detect_gagg(agg, topk):
     return True
 
 
+def _fd_closure(fd: dict, p: int) -> set:
+    """Every position that determines ``p`` through ``fd``, directly or
+    by way of others."""
+    seen: set = set()
+    todo = list(fd.get(p, ()))
+    while todo:
+        q = todo.pop()
+        if q != p and q not in seen:
+            seen.add(q)
+            todo.extend(fd.get(q, ()))
+    return seen
+
+
 def _fd_map(root, orientation):
     """Functional dependencies between output columns, in root.schema
-    positions: {determined: determining}. Every verified-unique inner
-    join makes its build-side columns functions of the probe key (the
-    dup/density flags guarantee uniqueness at runtime — a program that
-    RETURNS without flags proved its FDs). Lets grouped aggregation
+    positions: {determined: {determining, ...}}, each determinant alone
+    enough. Every verified-unique inner join makes its build-side
+    columns functions of the probe key (the dup/density flags guarantee
+    uniqueness at runtime — a program that RETURNS without flags proved
+    its FDs) and, the key pair being an equivalence on the rows that
+    survive the join, of the build side's own key too: what either key
+    determines the other does. A Project closes the dependencies
+    transitively before it drops a determinant (TPC-H Q10 groups by
+    ``c_custkey`` and six attributes of it over a projection that has
+    neither ``o_custkey`` nor ``c_nationkey``). Lets grouped aggregation
     pack a determinant subset of the GROUP BY keys (the reference
     derives the same through unique-index functional dependency,
-    check_functional_grouping, src/backend/catalog/pg_constraint.c)."""
+    check_functional_grouping, src/backend/catalog/pg_constraint.c, and
+    remove_useless_groupby_columns, optimizer/plan/planner.c). No proof,
+    no entry: a join on several pairs, an outer join, a key that is an
+    expression leave their columns undetermined."""
     counter = [0]
 
     # walk mirrors _Builder.build: recurse BOTH children of every join
@@ -622,9 +644,12 @@ def _fd_map(root, orientation):
             for o, ex in enumerate(node.exprs):
                 if not isinstance(ex, E.Col):
                     continue
-                q = cfd.get(ex.index)
-                if q is not None and q in pos_of and pos_of[q] != o:
-                    out[o] = pos_of[q]
+                by = {
+                    pos_of[q] for q in _fd_closure(cfd, ex.index)
+                    if q in pos_of
+                } - {o}
+                if by:
+                    out[o] = by
             return out
         if isinstance(node, L.Join):
             if node.join_type in ("semi", "anti"):
@@ -634,9 +659,9 @@ def _fd_map(root, orientation):
             lfd = walk(node.left)
             rfd = walk(node.right)
             nl = len(node.left.schema)
-            out = dict(lfd)
+            out = {k: set(v) for k, v in lfd.items()}
             out.update({
-                k + nl: v + nl for k, v in rfd.items()
+                k + nl: {q + nl for q in v} for k, v in rfd.items()
             })
             if node.join_type != "inner":
                 return out
@@ -647,17 +672,23 @@ def _fd_map(root, orientation):
             ) == "R"
             if len(node.left_keys) != 1:
                 return out
-            pkey = (
-                node.left_keys[0] if build_right else node.right_keys[0]
+            pkey, bkey = (
+                (node.left_keys[0], node.right_keys[0]) if build_right
+                else (node.right_keys[0], node.left_keys[0])
             )
             if not isinstance(pkey, E.Col):
                 return out
             pkpos = pkey.index + (0 if build_right else nl)
             lo, hi = (nl, nl + len(node.right.schema)) if build_right \
                 else (0, nl)
+            by = {pkpos}
+            if isinstance(bkey, E.Col):
+                # the pair is an equivalence on the joined rows
+                bkpos = bkey.index + lo
+                by.add(bkpos)
+                out.setdefault(pkpos, set()).add(bkpos)
             for p in range(lo, hi):
-                if p != pkpos:
-                    out[p] = pkpos
+                out.setdefault(p, set()).update(by - {p})
             return out
         return {}
 
@@ -1018,7 +1049,9 @@ def _fd_reduce(root, orientation, agg):
     """(kept, dropped) group-expr indices after removing keys
     functionally determined (transitively) by another present key —
     the ONE fixpoint shared by gagg and wgagg (a one-sided change
-    would silently group the windowed and in-core paths differently)."""
+    would silently group the windowed and in-core paths differently).
+    A key goes only while a key that determines it stays: of two that
+    determine each other the first is dropped, the second kept."""
     fd = _fd_map(root, orientation)
     nkeys = len(agg.group_exprs)
     colpos = {
@@ -1034,18 +1067,33 @@ def _fd_reduce(root, orientation, agg):
         for i, p in colpos.items():
             if i in drop:
                 continue
-            q = fd.get(p)
-            seen = set()
-            while q is not None and q not in present and q not in seen:
-                seen.add(q)
-                q = fd.get(q)
-            if (
-                q is not None and q in present
-                and present[q] != i and present[q] not in drop
+            if any(
+                present.get(q, i) != i and present[q] not in drop
+                for q in _fd_closure(fd, p)
             ):
                 drop.add(i)
                 changed = True
     return [i for i in range(nkeys) if i not in drop], sorted(drop)
+
+
+# what a gagg program checks of its own answer, in the order of the bits
+# it returns (``okf``): the packed group key's range fits 62 bits; key
+# and integer values fit the 32-bit operands (``narrow``); the sums'
+# running prefix is monotone and does not wrap (the cummax run base);
+# the ORDER BY keys' packed ranking fits 62 bits
+_GAGG_CHECKS = ("packing", "narrowing", "run base", "ranking")
+
+
+def _gagg_late(root, orientation, agg, topk) -> set:
+    """The group keys a gagg reads at its output rows alone: those
+    ``_fd_reduce`` drops from the packed key, but for the ones an ORDER
+    BY key ranks by (they ride the sort). Bare columns all: with them
+    the builder defers its joins' gathers (``_Builder.defer``) and the
+    final follows each output row back through the joins instead."""
+    _kept, dropped = _fd_reduce(root, orientation, agg)
+    nkeys = len(agg.group_exprs)
+    ranked = {p for p, _d, _nf in topk[1] if p < nkeys}
+    return set(dropped) - ranked
 
 
 def _seg_scan(x, boundary, op, reverse: bool = False):
@@ -1255,6 +1303,91 @@ def _collect_arrays(fx, root, exchanged: dict, D: int) -> list:
     ]
 
 
+class _Deferred:
+    """A build column of a join at the probe's rows, not gathered yet:
+    ``benv[ci]`` at ``bidx``. Whoever reads it as an array gathers it at
+    the probe's width (``gather``, under the join's own scope); a final
+    that wants it at a few output rows alone follows the row through
+    the joins instead (``at_rows``) and never pays the width."""
+
+    __slots__ = ("benv", "ci", "bidx", "mode", "stage")
+
+    def __init__(self, benv, ci: int, bidx, mode, stage: str):
+        self.benv, self.ci, self.bidx = benv, ci, bidx
+        self.mode, self.stage = mode, stage
+
+    def gather(self):
+        d, v = self.benv[self.ci]
+        with scope(self.stage):
+            return (
+                jnp.take(d, self.bidx, axis=0, mode=self.mode),
+                None if v is None
+                else jnp.take(v, self.bidx, axis=0, mode=self.mode),
+            )
+
+    def at_rows(self, rows):
+        return _col_at_rows(
+            self.benv, self.ci, jnp.take(self.bidx, rows, mode="clip")
+        )
+
+
+class _LazyEnv(list):
+    """A joined row's columns where some are ``_Deferred``: reading
+    one by position gathers it once and keeps it, so compiled
+    expressions see the (data, validity) pairs they always saw.
+    ``raw`` hands the entries on untouched (to the next joined row, to
+    a projection of bare columns)."""
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return _LazyEnv(list.__getitem__(self, i))
+        e = list.__getitem__(self, i)
+        if isinstance(e, _Deferred):
+            e = e.gather()
+            self[i] = e
+        return e
+
+    def __iter__(self):
+        return (self[i] for i in range(len(self)))
+
+    def raw(self) -> list:
+        return [list.__getitem__(self, i) for i in range(len(self))]
+
+
+def _raw(env) -> list:
+    return env.raw() if isinstance(env, _LazyEnv) else list(env)
+
+
+def _col_at_rows(env, i: int, rows):
+    """Column ``i`` of ``env`` at the rows ``rows`` alone: a deferred
+    one by its build row's index, through as many joins as deferred
+    it."""
+    e = (
+        list.__getitem__(env, i) if isinstance(env, _LazyEnv) else env[i]
+    )
+    if isinstance(e, _Deferred):
+        return e.at_rows(rows)
+    d, v = e
+    k = rows.shape[0]
+    return (
+        jnp.broadcast_to(d, (k,)) if jnp.ndim(d) == 0
+        else jnp.take(d, rows, axis=0, mode="clip"),
+        None if v is None else (
+            jnp.broadcast_to(v, (k,)) if jnp.ndim(v) == 0
+            else jnp.take(v, rows, axis=0, mode="clip")
+        ),
+    )
+
+
+def _read_first(env, cols) -> None:
+    """Gather the deferred columns among ``cols`` now, each under its
+    join's scope, before the reader opens a scope of its own."""
+    if isinstance(env, _LazyEnv):
+        for i in sorted(cols):
+            if i < len(env):
+                env[i]
+
+
 @dataclasses.dataclass(frozen=True)
 class _JoinInfo:
     """What one compile decided about its inner joins — cached beside
@@ -1283,10 +1416,15 @@ class _Builder:
     def __init__(
         self, fx, comp: ExprCompiler, orientation: tuple, root,
         capture_id=None, runner=None, D: int = 1,
-        fold_off=frozenset(), window=None,
+        fold_off=frozenset(), window=None, defer: bool = False,
     ):
         self.fx = fx
         self.comp = comp
+        # a join's build columns stay ``_Deferred`` until something
+        # reads them (``_LazyEnv``): asked for by a final that wants
+        # some of them at its few output rows alone (gagg's dropped
+        # group keys). Off, every program is traced op for op as ever
+        self.defer = defer
         self.orientation = orientation
         self.leaf_index = {
             id(n): i for i, n in enumerate(_walk_leaves(root))
@@ -1536,8 +1674,11 @@ class _Builder:
                 "filter" if _contains_join(node.child) else "scan"
             ) + "/predicate"
 
+            pcols = _expr_cols(node.predicate)
+
             def run(blocks, params, snap):
                 env, mask, n, flags = child(blocks, params, snap)
+                _read_first(env, pcols)
                 with scope(stage):
                     d, v = pred(env, params)
                     keep = d if v is None else (d & v)
@@ -1565,10 +1706,35 @@ class _Builder:
                 "project" if _contains_join(node.child) else "scan"
             ) + "/project"
 
+            # bare columns a deferring build hands on as they are (no
+            # dictionary to translate between)
+            passed = {
+                o: ex.index for o, (ex, oc) in enumerate(
+                    zip(node.exprs, node.schema))
+                if self.defer and isinstance(ex, E.Col) and (
+                    not ex.type.is_text or not oc.dict_id
+                    or oc.dict_id == dids[ex.index]
+                )
+            }
+            ecols: set = set()
+            for o, ex in enumerate(node.exprs):
+                if o not in passed:
+                    _expr_cols(ex, ecols)
+
             def run(blocks, params, snap):
                 env, mask, n, flags = child(blocks, params, snap)
+                if not passed:
+                    with scope(stage):
+                        out = [_bcast(fn(env, params), n) for fn in fns]
+                    return out, mask, n, flags
+                _read_first(env, ecols)
+                raw = _raw(env)
                 with scope(stage):
-                    out = [_bcast(fn(env, params), n) for fn in fns]
+                    out = _LazyEnv(
+                        raw[passed[o]] if o in passed
+                        else _bcast(fn(env, params), n)
+                        for o, fn in enumerate(fns)
+                    )
                 return out, mask, n, flags
 
             return run
@@ -1777,12 +1943,31 @@ class _Builder:
                 for i in others
             ]
 
+        defer = self.defer and jt == "inner"
+        rescols = (
+            _expr_cols(node.residual) if node.residual is not None
+            else set()
+        )
+
         def joined(penv, gathered) -> list:
             """The joined row: left columns, then right."""
+            if defer:
+                return _LazyEnv(
+                    _raw(penv) + gathered if build_right
+                    else gathered + _raw(penv)
+                )
             return (
                 list(penv) + gathered if build_right
                 else gathered + list(penv)
             )
+
+        def deferred(benv, bidx, mode, stage: str, but=None) -> list:
+            """The build side's columns at the probe's rows, each
+            gathered when (and if) something reads it."""
+            return [
+                None if i == but else _Deferred(benv, i, bidx, mode, stage)
+                for i in range(len(benv))
+            ]
 
         def pairs_equal(env, mask, n, params, formulation: str):
             """The pairs the lookup did not see, checked where the
@@ -1845,22 +2030,32 @@ class _Builder:
                     "own" if ci is None else "carried"
                 ] += 1
                 with scope(f"{jtag}/fold/gather"):
-                    gathered = _take_rows(
-                        [c for i, c in enumerate(benv) if i != ci],
-                        bidx, "clip",
-                    )
+                    if defer:
+                        gathered = deferred(
+                            benv, bidx, "clip", f"{jtag}/fold/gather", ci
+                        )
+                    else:
+                        gathered = _take_rows(
+                            [c for i, c in enumerate(benv) if i != ci],
+                            bidx, "clip",
+                        )
                     if ci is not None:
                         cv = benv[ci][1]
-                        gathered.insert(ci, (
+                        carried = (
                             word, None if cv is None
                             else jnp.take(cv, bidx, axis=0, mode="clip"),
-                        ))
+                        )
+                        if defer:
+                            gathered[ci] = carried
+                        else:
+                            gathered.insert(ci, carried)
                 env = joined(penv, gathered)
                 mask = pmask & matched
                 n = pn
                 if others:
                     builder.fx.multi_key_joins += 1  # (at trace time)
                     mask = pairs_equal(env, mask, n, params, "fold")
+                _read_first(env, rescols)
                 if resfn is not None:
                     d, v = resfn(env, params)
                     keep = d if v is None else (d & v)
@@ -1916,13 +2111,19 @@ class _Builder:
                     builder.captured = (bidx, benv, bn)
                 note_widths(bn, pn)
                 jmode = "radix" if "radix" in traced else "merge"
-                with scope(f"{jtag}/{jmode}/gather"):
-                    gathered = _take_rows(benv, bidx)
+                if defer:
+                    gathered = deferred(
+                        benv, bidx, None, f"{jtag}/{jmode}/gather"
+                    )
+                else:
+                    with scope(f"{jtag}/{jmode}/gather"):
+                        gathered = _take_rows(benv, bidx)
                 env = joined(penv, gathered)
                 mask = pmask & matched
                 n = pn
                 if others and jmode == "radix":
                     mask = pairs_equal(env, mask, n, params, "radix")
+            _read_first(env, rescols)
             if resfn is not None:
                 d, v = resfn(env, params)
                 keep = d if v is None else (d & v)
@@ -3006,6 +3207,7 @@ class DagRunner:
                     b = _Builder(
                         self.fx, comp, orientation, root, runner=self,
                         D=D, fold_off=fo,
+                        defer=bool(_gagg_late(root, orientation, agg, tk)),
                     )
                     ev = b.build(root, exchanged, D, _agg_reads(agg))
                     return self._compile_gagg(
@@ -3049,6 +3251,10 @@ class DagRunner:
                     "groups": direct or gcap,
                     "group_keys": f"{len(agg.group_exprs)} ({ntext} text)",
                 }
+            elif mode == "gagg":
+                # what the packed sort key kept of the group keys, and
+                # whether key and values rode the sort as 32-bit words
+                gargs = dict(prog.gagg)
             outs = self._fetch(
                 self._launch(
                     prog, arrays, params, snap, mode=mode, **gargs
@@ -3099,10 +3305,22 @@ class DagRunner:
                 )
                 continue
             if okf is not None and not bool(np.asarray(okf).all()):
+                # a gagg says which of its checks went false (on any
+                # device); the ladder below is what it was, its reasons
+                # name the cause
+                why = ""
+                if mode == "gagg":
+                    bad = ~np.asarray(okf).reshape(-1, len(_GAGG_CHECKS))
+                    why = " [" + ", ".join(
+                        c for c, f in zip(_GAGG_CHECKS, bad.any(axis=0))
+                        if f
+                    ) + " went false]"
                 if mode in ("gsort", "gagg") and narrow:
                     # i32 operand range overflowed: retry the wide
                     # program before giving up on ranking entirely
-                    self._retry("i32 operands overflowed: narrow off")
+                    self._retry(
+                        f"i32 operands overflowed{why}: narrow off"
+                    )
                     self._narrow_off[skey] = True
                     while len(self._narrow_off) > 512:
                         self._narrow_off.pop(
@@ -3113,7 +3331,9 @@ class DagRunner:
                     # negative sum values (or a wrapping global prefix)
                     # broke the cumsum run base: retry with segmented
                     # add scans before giving up on ranking
-                    self._retry("cumsum run base broke: robust on")
+                    self._retry(
+                        f"cumsum run base broke{why}: robust on"
+                    )
                     self._robust_on[skey] = True
                     while len(self._robust_on) > 512:
                         self._robust_on.pop(
@@ -3123,7 +3343,7 @@ class DagRunner:
                 # ranking-key range overflowed int64 (data-dependent, so
                 # keyed by data version): remember and ship unranked
                 # (correct, just a bigger transfer)
-                self._retry("ranking key overflow: topk off")
+                self._retry(f"ranking key overflow{why}: topk off")
                 self._topk_off[(skey, tk, versions)] = True
                 while len(self._topk_off) > 512:
                     self._topk_off.pop(next(iter(self._topk_off)))
@@ -3134,6 +3354,8 @@ class DagRunner:
             # join modes here is already what ran)
             self._accept(prog)
             if mode in ("gseg", "gsort", "gagg"):
+                if mode == "gagg":
+                    self.fx.gagg_finals += 1
                 self._orientations[skey] = orientation
                 if not complete:
                     # psum/D==1: every device holds the SAME complete
@@ -3363,7 +3585,13 @@ class DagRunner:
         Sort-width minimization (the sort IS the cost on a TPU):
         - group keys functionally determined by another grouped key
           (through verified-unique joins, ``_fd_map``) stay OUT of the
-          packed key and are recovered per output row;
+          packed key and are recovered per output row: by the row id
+          that rides the sort, and where the builder deferred its
+          joins' gathers (``_gagg_late``) through the joins' own row
+          indices, so a dropped key is never gathered at the probe's
+          width only to be read at LIMIT rows (TPC-H Q10: six
+          attributes of ``c_custkey``, seven 32-bit words, 0.58 s each
+          at 67.1M rows);
         - the packed key and integer value operands narrow to i32 when
           runtime ranges fit (flag -> wide retry, like gsort);
         - when nothing was FD-dropped the row-id operand is dropped
@@ -3389,15 +3617,46 @@ class DagRunner:
         carried = sorted({
             p for p, _d, _nf in sspecs if p < nkeys and p in drop
         })
+        # dropped keys read at the output rows alone (bare columns, or
+        # ``_fd_reduce`` had kept them), and what every other reader
+        # needs of the joined row at full width
+        late = (drop - set(carried)) if b.defer else set()
+        wide_cols: set = set()
+        for i, gx in enumerate(agg.group_exprs):
+            if i not in late:
+                _expr_cols(gx, wide_cols)
+        for ag in agg.aggs:
+            if ag.arg is not None:
+                _expr_cols(ag.arg, wide_cols)
+        ntext = sum(gx.type.is_text for gx in agg.group_exprs)
+        # the launch's record: what the packing kept, whether key and
+        # values ride the sort as 32-bit words, the rows that leave
+        record = {
+            "grouping": f"gagg/{len(kept)}of{nkeys}",
+            "group_keys": f"{nkeys} ({ntext} text)",
+            "narrow": bool(narrow),
+            "rows_out": int(k),
+        }
 
         def program(arrays, params, snap):
             @_staged
             def block(blocks, st):
                 env, mask, n, flags = ev(blocks, params, snap)
+                self.fx.gagg_keys_dropped += len(drop)  # (at trace time)
+                _read_first(env, wide_cols)
                 st.to("final/gagg/pack")
                 flags = [jnp.reshape(f, (1,)) for f in flags]
-                keys = [_bcast(fn(env, params), n) for fn in gfns]
-                ok = jnp.asarray(True)
+                keys = [
+                    None if i in late else _bcast(fn(env, params), n)
+                    for i, fn in enumerate(gfns)
+                ]
+                # the four checks a refused answer may have failed, each
+                # its own bit (``_GAGG_CHECKS``): the runner's retry
+                # names the ones that went false
+                ok = jnp.asarray(True)  # packing
+                ok_narrow = jnp.asarray(True)
+                ok_base = jnp.asarray(True)
+                ok_rank = jnp.asarray(True)
 
                 # pack kept keys, remembering (mn, r, has_null) per key
                 # so values decode back out of the sorted key
@@ -3431,7 +3690,7 @@ class DagRunner:
                 ok = ok & (prod0 < jnp.float64(2**62))
 
                 if narrow:
-                    ok = ok & (prod0 < jnp.float64(2**31 - 1))
+                    ok_narrow = prod0 < jnp.float64(2**31 - 1)
                     KSENT = jnp.int32(2**31 - 1)
                     skeyop = jnp.where(
                         mask, packed, jnp.int64(2**31 - 1)
@@ -3441,9 +3700,9 @@ class DagRunner:
                     skeyop = jnp.where(mask, packed, big)
 
                 def narrow_val(dv):
-                    nonlocal ok
+                    nonlocal ok_narrow
                     if narrow and dv.dtype == jnp.int64:
-                        ok = ok & (
+                        ok_narrow = ok_narrow & (
                             jnp.max(dv) < jnp.int64(2**31 - 1)
                         ) & (jnp.min(dv) > jnp.int64(-(2**31 - 1)))
                         return dv.astype(jnp.int32)
@@ -3509,6 +3768,7 @@ class DagRunner:
                 sorted_ops = jax.lax.sort(
                     tuple(operands), num_keys=1, is_stable=False
                 )
+                st.to("final/gagg/scan")
                 salk = sorted_ops[0]
                 boundary = jnp.concatenate([
                     jnp.ones(1, jnp.bool_), salk[1:] != salk[:-1]
@@ -3580,12 +3840,12 @@ class DagRunner:
                         # cumsum+cummax base needs non-negative values
                         # and a non-wrapping global prefix; the robust
                         # retry (segmented add scan) lifts both limits
-                        ok = ok & ~(jnp.min(sval) < 0)
+                        ok_base = ok_base & ~(jnp.min(sval) < 0)
                         cs = jnp.cumsum(sval)
                         if jnp.issubdtype(cs.dtype, jnp.integer):
-                            ok = ok & (cs[-1] < jnp.int64(2**62)) & (
-                                cs[-1] >= 0
-                            )
+                            ok_base = ok_base & (
+                                cs[-1] < jnp.int64(2**62)
+                            ) & (cs[-1] >= 0)
                         sv = run_from_start(cs, sval)
                     out_vals_pos.append((sv, vvalid))
 
@@ -3600,6 +3860,7 @@ class DagRunner:
                         return d, None
                     return jnp.where(x == rng, 0, d), x != rng
 
+                st.to("final/gagg/topk")
                 stride = jnp.int64(1)
                 prod = jnp.float64(1.0)
                 packed_rank = jnp.zeros(n, dtype=jnp.int64)
@@ -3622,44 +3883,43 @@ class DagRunner:
                     packed_rank = packed_rank + x * stride
                     stride = stride * r
                     prod = prod * jnp.maximum(rf, 1.0)
-                    ok = ok & okbit
-                ok = ok & (prod < jnp.float64(2**62))
+                    ok_rank = ok_rank & okbit
+                ok_rank = ok_rank & (prod < jnp.float64(2**62))
 
-                st.to("final/gagg/topk")
                 idx, sel = _topk_idx(packed_rank, live_end, k)
                 row_k = (
                     None if rid_i is None
                     else jnp.take(sorted_ops[rid_i], idx)
                 )
                 salk_k = jnp.take(salk, idx)
-                out_keys = []
-                for i, (d, v) in enumerate(keys):
-                    if i in drop:
-                        dk = jnp.take(
-                            jnp.broadcast_to(d, (n,)), row_k
-                        )
-                        vk = (
-                            jnp.ones(k, jnp.bool_)
-                            if v is None
-                            else jnp.take(
-                                jnp.broadcast_to(v, (n,)), row_k
-                            )
-                        )
-                    else:
-                        dk, vk = decode_key(i, salk_k)
-                        dk = dk.astype(jnp.asarray(d).dtype)
-                        if vk is None:
-                            vk = jnp.ones(k, jnp.bool_)
-                    out_keys.append((dk, vk))
                 out_vals = [
                     (jnp.take(dd, idx), jnp.take(vv, idx))
                     for dd, vv in out_vals_pos
                 ]
+                if drop:
+                    # a dropped key's value at the row that stands for
+                    # its group: a late one through the joins' own row
+                    # indices, LIMIT rows wide
+                    st.to("final/gagg/recover")
+                out_keys = []
+                for i, kv in enumerate(keys):
+                    if i in late:
+                        dk, vk = _col_at_rows(
+                            env, agg.group_exprs[i].index, row_k
+                        )
+                    elif i in drop:
+                        dk, vk = _col_at_rows([kv], 0, row_k)
+                    else:
+                        dk, vk = decode_key(i, salk_k)
+                        dk = dk.astype(jnp.asarray(kv[0]).dtype)
+                    if vk is None:
+                        vk = jnp.ones(k, jnp.bool_)
+                    out_keys.append((dk, vk))
                 return (
                     jax.tree.map(lambda x: x[None], out_keys),
                     jax.tree.map(lambda x: x[None], out_vals),
                     sel[None],
-                    jnp.reshape(ok, (1,)),
+                    jnp.stack([ok, ok_narrow, ok_base, ok_rank])[None],
                     flags,
                 )
 
@@ -3676,7 +3936,9 @@ class DagRunner:
                 ),
             )(arrays)
 
-        return self._program(program, "gagg", b), comp, "gagg"
+        prog = self._program(program, "gagg", b)
+        prog.gagg = record
+        return prog, comp, "gagg"
 
     # -- windowed grouped aggregation (bigger-than-HBM probes) -----------
     def _wgagg_leaf(self, root, agg, tk):

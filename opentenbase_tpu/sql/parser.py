@@ -139,6 +139,8 @@ _PRECEDENCE = {
 }
 
 _COMPARISON = {"=", "<>", "!=", "<", "<=", ">", ">="}
+# the unit that may follow ``interval '<n>'`` (SQL's interval qualifier)
+_INTERVAL_FIELDS = {"year", "month", "week", "day", "hour", "minute", "second"}
 
 
 class Parser:
@@ -2190,6 +2192,16 @@ class Parser:
         if kw == "interval":
             self.advance()
             text = self._string_lit()
+            # the standard's ``interval '3' month`` (TPC-H's form): a
+            # bare quantity takes the unit that follows it and is then
+            # the ``interval '3 month'`` the analyzer folds
+            unit = self.cur
+            if (
+                unit.kind == Tok.IDENT and unit.value in _INTERVAL_FIELDS
+                and text.strip().lstrip("+-").isdigit()
+            ):
+                self.advance()
+                text = f"{text.strip()} {unit.value}"
             return A.FuncCall("interval", (A.Literal(text),))
         if kw in ("date", "timestamp") and self.peek(1).kind == Tok.STRING:
             self.advance()

@@ -269,25 +269,30 @@ CELLS = {
     "tpch_sf10_1chip": "join_q3",
     "ssb_sf10_1chip": "flight1_q11_q12_q13",
     "ssb_star_sf10_1chip": "star_q21_q31_q41",
+    "tpch_q10_sf10_1chip": "q10",
 }
 # kind -> digests of the programs its statement launches, in order.
-# Re-pinned ON PURPOSE by PR 38 (PR 36 before it, ROADMAP.md Design 2d),
-# for the programs that hold a fold WITH A CARRIER and for no other:
-# q21, q31, q41 (``part`` / ``customer`` and ``supplier`` / ``customer``
-# bring a dictionary code the final groups by) and the third of q3's
-# four. A fold whose dimension the plan reads an integer column of after
-# the lookup no longer gathers its match bit: rows the dimension's
-# filter drops are marked in that column at the build's width and the
-# probe reads the match off the word it gathers anyway, a probe-width
-# gather less a fold (tests/test_fold_slot_probe.py holds the count;
-# PERF.md §6 PR 38 has what it moved on the chip). q3's third program
-# is the eight-device toy's FIRST attempt, a ``gagg`` that folds
-# ``orders`` onto ``lineitem`` and reads ``o_orderdate`` (its density
-# flag refuses it on every run, as before, and the fourth answers); no
-# program the benchmark's Q3 cells time has a carried fold:
-# ``test_one_chip_q3_keeps_the_program_it_times`` below. Flight 1's
-# three, q3's count, broadcast and radix programs and SORTMERGE_DIGEST
-# hold no carried fold and did not move.
+# Re-pinned ON PURPOSE by PR 39 for q3's third and fourth programs alone,
+# the two ``gagg``s of the eight-device toy (the first folds ``orders``
+# onto ``lineitem`` and is refused by its density flag on every run, the
+# second answers): a ``gagg`` now returns which of its four checks went
+# false (one bit each, where it returned their conjunction), names its
+# prefix scans' stage, and reads the group keys it dropped from the
+# packed key (here ``o_orderdate`` and ``o_shippriority``, which
+# ``l_orderkey`` determines) at its LIMIT output rows through the join's
+# own row index, where it gathered each at the probe's width first
+# (PERF.md §6 PR 39). No cell of the benchmark times a ``gagg`` but the
+# Q10 cell that PR added: the one-chip Q3's timed program is the
+# ``gsort`` pinned by ``test_one_chip_q3_keeps_the_program_it_times``
+# below, the four-chip Q3's likewise, and neither moved. Before it PR 38
+# (PR 36 before that, ROADMAP.md Design 2d) re-pinned the programs that
+# hold a fold WITH A CARRIER: q21, q31, q41 and the third of q3's four
+# (tests/test_fold_slot_probe.py holds the gather count; PERF.md §6
+# PR 38). Flight 1's three, q3's count and broadcast programs and
+# SORTMERGE_DIGEST did not move in either. Q10 is not pinned here: on
+# this file's eight devices it takes the mesh's path (two motions and
+# the ``grouped`` final), not the one-device ``gagg`` its cell times;
+# tests/test_tpch_q10.py holds that program's shape.
 PROGRAM_DIGESTS = {
  'q11': ['program_dag_scalar:3adf94616349d696'],
  'q12': ['program_dag_scalar:5cc378cd711b20d2'],
@@ -295,8 +300,8 @@ PROGRAM_DIGESTS = {
  'q21': ['program_dag_grouped:643be789d9f7aa64'],
  'q3': ['program_dag_count:2dc092c5d0bfe138',
         'program_dag_broadcast:a1292c7f75dd922a',
-        'program_dag_gagg:e28c1f408faeb3b1',
-        'program_dag_gagg:0cd905f5a3621d3f'],
+        'program_dag_gagg:b56ff9be65b59ab1',
+        'program_dag_gagg:de6ce60808328967'],
  'q31': ['program_dag_grouped:e390f5619239e334'],
  'q41': ['program_dag_grouped:025ca4f4abcf563b']}
 # kind -> where each fold's match bit comes from (``bit=`` of the
@@ -332,6 +337,13 @@ SF1_STATS = {
         "dates": (2_556, {"d_datekey": 2_556, "d_year": 7}),
     },
 }
+SF1_STATS["tpch_q10_sf10_1chip"] = {
+    **SF1_STATS["tpch_sf10_1chip"],
+    "customer": (150_000, {
+        "c_custkey": 150_000, "c_nationkey": 25, "c_name": 150_000,
+        "c_address": 150_000, "c_phone": 150_000, "c_acctbal": 140_000,
+        "c_comment": 150_000}),
+}  # (nation keeps what ANALYZE measured: 25 rows at every scale)
 SF1_STATS["ssb_sf10_1chip"] = {
     t: SF1_STATS["ssb_star_sf10_1chip"][t] for t in ("lineorder", "dates")
 }
@@ -346,6 +358,13 @@ JOIN_ORDERS = {
  'q13': ['Join inner on lo_orderdate=d_datekey',
          'Scan on lineorder',
          'Scan on dates'],
+ 'q10': ['Scan on orders',
+         'Join inner on c_custkey=o_custkey',
+         'Join inner on n_nationkey=c_nationkey',
+         'Scan on nation',
+         'Scan on customer',
+         'Join inner on o_orderkey=l_orderkey',
+         'Scan on lineitem'],
  'q21': ['Join inner on lo_suppkey=s_suppkey',
          'Join inner on lo_partkey=p_partkey',
          'Join inner on d_datekey=lo_orderdate',
@@ -442,6 +461,7 @@ KIND_CELL = {
     "q13": "ssb_sf10_1chip",
     "q21": "ssb_star_sf10_1chip", "q31": "ssb_star_sf10_1chip",
     "q41": "ssb_star_sf10_1chip",
+    "q10": "tpch_q10_sf10_1chip",
 }
 
 
@@ -460,7 +480,7 @@ def cells():
         c.dep.close()
 
 
-@pytest.mark.parametrize("kind", sorted(KIND_CELL))
+@pytest.mark.parametrize("kind", sorted(PROGRAM_DIGESTS))
 def test_cells_programs_lower_to_the_text_they_had(cells, kind, monkeypatch):
     """Q3, flight 1's three and the star cell's three statements over
     the benchmark's own deployments at a toy scale: every program they

@@ -21,6 +21,10 @@ what a trace of the cell shows after a chip run, minus the times:
 
     python tools/compile_cell_program.py star q31
     python tools/compile_cell_program.py q5 q5 --hlo /root/scratch/q5.hlo
+    python tools/compile_cell_program.py q10 q10
+
+- every ``sort`` of at least that width, with its stage, its operands
+  and their memory space (a gagg's one sort, a sort-merge's two).
 
 It reaches into the runner (the mesh, the row estimates, the leaf
 scan's static width): a scratch instrument, to be repaired when those
@@ -45,6 +49,7 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks"),
 CELLS = {  # cell -> its configuration (the fixture's key)
     "star": "ssb_star_sf10_1chip", "flight1": "ssb_sf10_1chip",
     "join": "tpch_sf10_1chip", "q5": "tpch_q5_sf10_1chip",
+    "q10": "tpch_q10_sf10_1chip",
 }
 
 
@@ -56,6 +61,12 @@ def deployment(cell: str, kind: str):
         fix = t.Q5(fact_rows=36_000)  # (60 suppliers: the arm folds)
         fix.set_stats(10)
         return fix, fix.text("ASIA", 1994)
+    if cell == "q10":
+        import test_tpch_q10 as t
+
+        fix = t.Q10()
+        fix.set_stats(10)
+        return fix, fix.text("1993-10-01")
     import test_multikey_join as t
 
     fix = t.Cell(CELLS[cell])
@@ -92,11 +103,30 @@ def gather_fusions(text: str, min_width: int):
     return out
 
 
+def sorts(text: str, min_width: int):
+    """[(op, stage, [operand shape])] of every ``sort`` of ``text`` over
+    at least ``min_width`` rows."""
+    out = []
+    for line in text.split("\n"):
+        m = re.search(r"(%[\w.\-]+) = (\(.*?\)|\S+) sort\(", line)
+        if not m:
+            continue
+        shapes = re.findall(r"\w+\[[\d,]*\](?:\{[^}]*\})?", m.group(2))
+        width = re.search(r"\[(\d+)", m.group(2))
+        if width is None or int(width.group(1)) < min_width:
+            continue
+        stage = re.search(r'op_name="([^"]*)"', line)
+        stage = re.sub(r"jit\(\w+\)/|shard_map/|otb/|/sort$", "",
+                       stage.group(1) if stage else "")
+        out.append((m.group(1), stage, shapes))
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("cell", choices=sorted(CELLS))
     ap.add_argument("kind", help="the statement's kind in the cell's "
-                    "traffic mix: q21 | q31 | q41 | q11 | q3 | q5 ...")
+                    "traffic mix: q21 | q31 | q41 | q11 | q3 | q5 | q10 ...")
     ap.add_argument("--min-width", type=int, default=10_000,
                     help="least rows a listed gather yields")
     ap.add_argument("--hlo", help="write compiled.as_text() here")
@@ -211,6 +241,11 @@ def main() -> int:
                   f"{name} <- {producer}")
         outside += "S(1)" not in operands[0][1]
     print(f"{outside} gather table(s) outside S(1)")
+    for op, stage, shapes in sorts(text, args.min_width):
+        print(f"{op} sort [{stage}]")
+        for shape in shapes:
+            space = "S(1)" if "S(1)" in shape else "hbm"
+            print(f"    {shape.split('{')[0]:24s} @{space}")
     getattr(fix, "close", fix.dep.close)()
     return 0
 
