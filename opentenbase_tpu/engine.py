@@ -8618,6 +8618,10 @@ def _sv_fused(c: Cluster):
     # 32-bit word (their range fits) and as two, once a compiled program
     rows.append(("join_words_narrowed", str(fx.join_words["narrowed"])))
     rows.append(("join_words_wide", str(fx.join_words["wide"])))
+    # sort-merge lookups that sorted their key as one 32-bit word (both
+    # keys' ranges fit) and as int64, once a compiled program
+    rows.append(("merge_keys_narrowed", str(fx.merge_keys["narrowed"])))
+    rows.append(("merge_keys_wide", str(fx.merge_keys["wide"])))
     # accepted grouped finals of the DAG that addressed their groups by
     # the packed key, and those the key's range or the aggregates sent
     # to the sort formulation

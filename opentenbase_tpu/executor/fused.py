@@ -1172,6 +1172,11 @@ class FusedExecutor:
         # as two, once a traced program (fused_dag._build_join;
         # pg_stat_fused join_words_narrowed / join_words_wide)
         self.join_words = {"narrowed": 0, "wide": 0}
+        # sort-merge lookups by the width of the key they sort: one
+        # 32-bit word (both keys' ranges fit) or int64, once a traced
+        # program (fused_dag._build_join; pg_stat_fused
+        # merge_keys_narrowed / merge_keys_wide)
+        self.merge_keys = {"narrowed": 0, "wide": 0}
         # accepted grouped finals of the DAG by formulation: addressed
         # directly by the packed key, or sorted because the key's range
         # or the aggregates' kinds left no choice (fused_dag._run_final;

@@ -200,6 +200,9 @@ def _walk_leaves(node: L.LogicalPlan):
 # crosses a join as one 32-bit word; -2^31 stays free for a narrowed
 # carrier's mark (``_lookup_dense``'s SENT)
 I32 = 2**31 - 1
+# the key of a dead or NULL row in a sort-merge co-sort of 32-bit keys
+# (``_lookup_sortmerge``'s narrow form): a value no real key holds
+KEY_DEAD = -(2**31)
 
 
 def _leaf_arrays(fx, node, exchanged: dict, D: int, fits=None):
@@ -211,7 +214,8 @@ def _leaf_arrays(fx, node, exchanged: dict, D: int, fits=None):
     full re-upload), and the in-program visibility compare below is
     the ONLY filter those fresh rows ever pass through. ``fits``, a
     set: gets the (table, column) of every 64-bit integer column read
-    whose range over the arrays handed out fits 32 bits (``I32``)."""
+    whose range over the arrays handed out fits 32 bits (``I32``), and
+    (fragment, i) of a source's columns its producer proved so."""
     if isinstance(node, L.Scan):
         meta = fx.catalog.get(node.table)
         nodes = _scan_nodes(meta)
@@ -240,6 +244,8 @@ def _leaf_arrays(fx, node, exchanged: dict, D: int, fits=None):
     ex = exchanged.get(node.fragment)
     if ex is None:
         raise DagUnsupported("remote source order")
+    if fits is not None:  # (a key set's, where its program proved it)
+        fits.update((node.fragment, i) for i in ex.get("fits", ()))
     return (ex["cols"], ex["valids"], ex["counts"])
 
 
@@ -2282,6 +2288,17 @@ class _Builder:
             if bschema[i].type.np_dtype == np.int64
         }
         nar = {i for i in w64 if _origin(bside, i) in self.fits}
+        # a sort-merge sorts a one-pair join's key as one 32-bit word
+        # where both sides' keys are bare columns whose range fits: a
+        # table's scanned in this fragment, or a key set's whose program
+        # proved it. A key that arrived through a motion, or an
+        # expression, stays wide
+        key32 = (
+            not others
+            and _key_fits(node.left, node.left_keys[0], self.fits)
+            and _key_fits(node.right, node.right_keys[0], self.fits)
+        )
+        merge_keys = self.fx.merge_keys  # (pg_stat_fused, at trace time)
         words: dict = {}  # position -> narrowed?, as the trace reads it
         # ``keys=<pairs>:<the build side's driving key>`` on the join's
         # record, for a join with more than one pair
@@ -2296,9 +2313,10 @@ class _Builder:
             note_record()
 
         def note_record() -> None:
-            """``join<i>=<formulations>:<build>x<probe>[ bit=..][
-            narrow=..][ keys=..]``: ``narrow=`` names the 64-bit build
-            columns that crossed as one word."""
+            """``join<i>=<formulations>:<build>x<probe>[ key=i32][
+            bit=..][ narrow=..][ keys=..]``: ``key=i32``, a sort-merge
+            that sorted its key as one word; ``narrow=`` names the
+            64-bit build columns that crossed as one word."""
             names = [
                 bschema[i].name or f"col{i}"
                 for i, one in words.items() if one
@@ -2306,7 +2324,7 @@ class _Builder:
             narrow = f" narrow={'+'.join(names)}" if names else ""
             builder.joins[jtag] = (
                 f"{'+'.join(sorted(traced))}:{rec['widths']}"
-                f"{rec['bit']}{narrow}{keys_arg}"
+                f"{rec.get('key', '')}{rec['bit']}{narrow}{keys_arg}"
             )
 
         def note_word(i: int) -> None:
@@ -2316,7 +2334,7 @@ class _Builder:
             one = i in nar
             words[i] = one
             builder.fx.join_words["narrowed" if one else "wide"] += 1
-            if rec:
+            if "widths" in rec:
                 note_record()
 
         def other_pairs(lenv, ln, renv, rn, params) -> list:
@@ -2476,13 +2494,24 @@ class _Builder:
             # the sort-merge lookup over every pair: rows meet where all
             # their keys are equal, so a build side that repeats the
             # driving key but not the whole tuple is still a lookup
-            merge = lookup
+            xs = ()
             if others:
                 builder.fx.multi_key_joins += 1  # (at trace time)
                 xs = other_pairs(lenv, ln, renv, rn, params)
                 if jt == "inner" and not build_right:
-                    xs = [(r, l) for l, r in xs]
-                merge = partial(lookup, extra=xs)  # (probe, build) pairs
+                    xs = [(r, l) for l, r in xs]  # (probe, build) pairs
+
+            def merge(pk, pmask, bk, bmask, check_dup: bool):
+                """The sort-merge lookup, its key as one 32-bit word
+                where ``key32`` (``key=i32`` on the join's record,
+                pg_stat_fused merge_keys_narrowed / merge_keys_wide at
+                trace time)."""
+                rec["key"] = " key=i32" if key32 else ""
+                merge_keys["narrowed" if key32 else "wide"] += 1
+                if key32:
+                    return lookup(pk, pmask, bk, bmask, check_dup,
+                                  narrow=True)
+                return lookup(pk, pmask, bk, bmask, check_dup, extra=xs)
             if jt in ("semi", "anti"):
                 # existence probe: build-side duplicates are harmless
                 with scope(f"{jtag}/merge"):
@@ -4587,6 +4616,13 @@ class DagRunner:
                 exchanged[sm.frag] = {
                     "cols": (keys,), "valids": (valid,), "counts": count,
                     "cap": cap,
+                    # its live keys are values of a bare column whose
+                    # range fits (a dead slot is not live): the final's
+                    # semi join may sort them as one word (``fits`` of
+                    # the final's key then holds the source's column)
+                    "fits": (0,) if _key_fits(
+                        sub, sm.agg.group_exprs[0], fits
+                    ) else (),
                 }
         finally:
             self._frag = frag0
@@ -6321,12 +6357,15 @@ def _widened(env, narrow) -> list:
 
 def _origin(node, i: int):
     """(table, column) that position ``i`` of ``node``'s rows is a bare
-    copy of, through filters, projections and joins; None for anything
-    else: an expression, a column that arrived through a motion
-    (``RemoteSource``)."""
+    copy of, through filters, projections and joins; (fragment, i) for
+    a source's column (``RemoteSource``: a motion's, whose range nothing
+    proves, or a key set's, which ``_semiagg_launch`` may); None for an
+    expression."""
     while True:
         if isinstance(node, L.Scan):
             return node.table, node.columns[i]
+        if isinstance(node, RemoteSource):
+            return node.fragment, i
         if isinstance(node, L.Filter):
             node = node.child
         elif isinstance(node, L.Project):
@@ -6342,6 +6381,13 @@ def _origin(node, i: int):
                 node, i = node.right, i - nl
         else:
             return None
+
+
+def _key_fits(node, key, fits) -> bool:
+    """A join key that is a bare column (``_origin``) of a range proven
+    to fit ``[-I32, I32]`` (``fits``: the runner's, keyed with the
+    program)."""
+    return isinstance(key, E.Col) and _origin(node, key.index) in fits
 
 
 def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False,
@@ -6460,7 +6506,8 @@ def _lookup_dense(pk, pmask, bk, bvis, bfull, benv=(), presorted=False,
     return matched, sloti, flag, benv, word
 
 
-def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):
+def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=(),
+                      narrow: bool = False):
     """Equi-join primitive by double sort — the TPU formulation.
 
     ``searchsorted`` (a vectorized binary search) costs ~30s per 60M
@@ -6484,7 +6531,18 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):
     key after the first, so a run is a run of rows equal on EVERY pair,
     a NULL in any key keeps its row out, and ``dup`` means two real
     build rows equal on the whole tuple (a build side that repeats its
-    first key alone is still looked up exactly)."""
+    first key alone is still looked up exactly).
+
+    ``narrow`` (one pair; the caller's proof that every real row's key
+    on both sides lies in ``[-I32, I32]``): the key is sorted as ONE
+    32-bit word and the side byte goes. A dead or NULL row takes
+    ``KEY_DEAD`` (-2^31, which no real key holds), and real build rows
+    lead their run because their positions (``okey``) come first. The
+    TPU moves an ``int64`` as two 32-bit halves, so the wide co-sort
+    carries four operands: 509.7 ms at 83.9M rows, where the restore's
+    two 32-bit operands take 254.7 (the ledger's ``breakdown`` of the
+    Q5, Q10 and Q18 cells). ``prefix``, ``restore`` and what is
+    returned are the wide form's."""
     pd, pv = pk
     bd, bv = bk
     nb = bd.shape[0]
@@ -6496,16 +6554,23 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):
             breal = breal & xbv
         if xpv is not None:
             preal = preal & xpv
-    # two sort keys — the raw key keeps its FULL int64 range (no *2
-    # encode), the side byte orders real-build < real-probe < dead
-    # within each key run
-    key = jnp.concatenate([
-        bd.astype(jnp.int64), pd.astype(jnp.int64)
-    ])
-    side = jnp.concatenate([
-        jnp.where(breal, jnp.int8(0), jnp.int8(2)),
-        jnp.where(preal, jnp.int8(1), jnp.int8(2)),
-    ])
+    narrow = narrow and not extra
+    if narrow:
+        key = jnp.concatenate([
+            jnp.where(breal, bd.astype(jnp.int32), KEY_DEAD),
+            jnp.where(preal, pd.astype(jnp.int32), KEY_DEAD),
+        ])
+    else:
+        # two sort keys — the raw key keeps its FULL int64 range (no *2
+        # encode), the side byte orders real-build < real-probe < dead
+        # within each key run
+        key = jnp.concatenate([
+            bd.astype(jnp.int64), pd.astype(jnp.int64)
+        ])
+        side = jnp.concatenate([
+            jnp.where(breal, jnp.int8(0), jnp.int8(2)),
+            jnp.where(preal, jnp.int8(1), jnp.int8(2)),
+        ])
     okey = jnp.concatenate([
         jnp.arange(nb, dtype=jnp.int32),
         # probe original positions offset past nb so the restore sort
@@ -6517,17 +6582,28 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):
         for (xpd, _xpv), (xbd, _xbv) in extra
     )
     with jax.named_scope("sort"):
-        skey, *sxkeys, sside, sokey = jax.lax.sort(
-            (key,) + xkeys + (side, okey), num_keys=2 + len(xkeys),
-            is_stable=False,
-        )
+        if narrow:
+            skey, sokey = jax.lax.sort(
+                (key, okey), num_keys=2, is_stable=False
+            )
+            sxkeys = ()
+        else:
+            skey, *sxkeys, sside, sokey = jax.lax.sort(
+                (key,) + xkeys + (side, okey), num_keys=2 + len(xkeys),
+                is_stable=False,
+            )
     M = nb + npr
     first = jnp.ones(1, jnp.bool_)
     differs = skey[1:] != skey[:-1]
     for sx in sxkeys:
         differs = differs | (sx[1:] != sx[:-1])
     boundary = jnp.concatenate([first, differs])
-    isb = sside == 0
+    if narrow:
+        live = skey != KEY_DEAD
+        isb = (sokey < nb) & live
+        isp = (sokey >= nb) & live
+    else:
+        isb = sside == 0
     if check_dup and M > 1:
         dup = jnp.any(isb[1:] & isb[:-1] & ~boundary[1:])
     else:
@@ -6542,8 +6618,8 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):
             (runid.astype(jnp.int64) << 32) | sokey.astype(jnp.int64),
             jnp.int64(-1),
         ))
-        matched_s = (
-            ((last >> 32).astype(jnp.int32) == runid) & (sside == 1)
+        matched_s = ((last >> 32).astype(jnp.int32) == runid) & (
+            isp if narrow else sside == 1
         )
         # the restore sort carries one operand: the build row's
         # position, or -1 for 'no match'
@@ -6560,13 +6636,16 @@ def _lookup_sortmerge(pk, pmask, bk, bmask, check_dup: bool, extra=()):
     return matched, bidx, dup
 
 
-def _lookup(pk, pmask, bk, bmask, check_dup: bool, extra=()):
+def _lookup(pk, pmask, bk, bmask, check_dup: bool, extra=(),
+            narrow: bool = False):
     """Sorted-lookup equi-join primitive. Probe keys pk=(data, valid)
     [np] against build keys bk [nb]; returns (matched [np] bool,
     bidx [np] int, dup 0-d bool).
 
     A join with several key pairs (``extra``) takes the double sort on
-    every backend: a binary search has one key.
+    every backend: a binary search has one key. ``narrow`` is the
+    double sort's (a co-sort of 32-bit keys, ``_lookup_sortmerge``); the
+    binary search reads its keys as ``int64`` either way.
 
     Dead/NULL build rows participate in the sort but are flagged
     not-real; the composite stable sort (reals first within equal keys)

@@ -272,6 +272,11 @@ CELLS = {
     "tpch_q10_sf10_1chip": "q10",
 }
 # kind -> digests of the programs its statement launches, in order.
+# The sort-merge's co-sort of 32-bit keys (where both keys' ranges fit)
+# re-pinned none: on this file's CPU devices the DAG looks its joins up
+# by binary search (``_lookup``), which reads its keys as ``int64``
+# whatever the sort-merge's form; SORTMERGE_DIGEST holds that form's
+# wide text, tests/test_sortmerge_key_word.py its narrow operands.
 # PR 40 (a 64-bit build column read after a join crosses it as one
 # 32-bit word where its resident range fits) re-pinned none: no program
 # here holds such a column. The star's and flight 1's gathered columns

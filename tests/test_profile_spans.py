@@ -182,8 +182,10 @@ def test_radix_shape_rule_counter_and_bind_args(wire, monkeypatch):
     # the launch that traced the program says what the trace chose
     launch = spans(first, "fused.launch")[-1]
     assert launch.args["join_modes"] == "merge"
-    # (``o.c``, a ``bigint`` of small values, crosses as one word)
-    assert re.match(r"^join0=merge:\d+x\d+ narrow=c$", launch.args["joins"])
+    # (``o.c``, a ``bigint`` of small values, crosses as one word; the
+    # keys ``li.k`` and ``o.k`` fit too, so the sort-merge sorts one)
+    assert re.match(r"^join0=merge:\d+x\d+ key=i32 narrow=c$",
+                    launch.args["joins"])
     again = _traced(cluster, client, wide)
     assert sized_out() == (after, "merge")  # a cached re-bind
     bind = spans(again, "fused.bind")[-1]

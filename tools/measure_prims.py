@@ -25,6 +25,16 @@ gathers at either width); and the ordering as W + 1 gathers by the
 permutation against W + 1 payload operands of the build sort. Same
 inputs and widths for every form, committed to the chip; a sixteenth
 of the probe rows runs first.
+
+``--only merge_sort`` prices the sort-merge lookup's co-sort alone
+(``fused_dag._lookup_sortmerge``) at the cells' widths (``--rows``: a
+16,777,216-row build and a 67,108,864-row probe, 83,886,080 rows; and
+65,536 + 67,108,864): the wide form, ``(int64 key, int8 side, int32
+position)`` on two keys, against the narrow form's ``(int32 key, int32
+position)`` on two keys and on one key with a stable sort, beside the
+restore sort's ``(int32, int32)`` on one key. Build rows are the first
+``build`` positions, a tenth of each side dead. Same inputs for every
+form, committed to the chip; the two narrow forms must sort alike.
 """
 
 from __future__ import annotations
@@ -32,6 +42,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -149,9 +160,70 @@ def fold_probe(dev, probe_rows: int, builds, words) -> None:
         del slot, part
 
 
+def merge_sort(dev, widths) -> None:
+    """The co-sort of a sort-merge lookup by the key's width, and the
+    restore sort beside it."""
+    jax.config.update("jax_enable_x64", True)  # the wide key is int64
+    dead = -(2**31)
+
+    def wide(key, live, okey, nb):
+        side = jnp.where(live, jnp.where(okey < nb, 0, 1), 2).astype(
+            jnp.int8)
+        return jax.lax.sort((key.astype(jnp.int64), side, okey),
+                            num_keys=2, is_stable=False)
+
+    def narrow2(key, live, okey, nb):
+        return jax.lax.sort((jnp.where(live, key, dead), okey), num_keys=2,
+                            is_stable=False)
+
+    def narrow1_stable(key, live, okey, nb):
+        return jax.lax.sort((jnp.where(live, key, dead), okey), num_keys=1,
+                            is_stable=True)
+
+    def restore(key, live, okey, nb):
+        return jax.lax.sort((okey, key), num_keys=1, is_stable=False)
+
+    for nb, npr in widths:
+        rows = nb + npr
+        k = jax.random.PRNGKey(42)
+        # order keys of a 60M-row table: a build key a row, four probe
+        # rows a key (duplicates on the probe side, as a join has)
+        bkey = jax.random.randint(k, (nb,), 1, 60_000_000, jnp.int32)
+        pkey = jnp.take(bkey, jax.random.randint(
+            jax.random.fold_in(k, 1), (npr,), 0, nb, jnp.int32))
+        key = jnp.concatenate([bkey, pkey])
+        live = jax.random.uniform(jax.random.fold_in(k, 2), (rows,)) > 0.1
+        okey = jnp.arange(rows, dtype=jnp.int32)
+        key, live, okey = jax.device_put((key, live, okey), dev)
+        rec = {"case": "merge_sort", "build": nb, "probe": npr}
+        want = None
+        for form, fn in (("narrow2", narrow2), ("narrow1_stable",
+                                                narrow1_stable),
+                         ("wide", wide), ("restore", restore)):
+            f = jax.jit(partial(fn, nb=nb))
+
+            def check(out, form=form):
+                nonlocal want
+                if {d.platform for d in out[0].devices()} != {dev.platform}:
+                    raise SystemExit("timed on the host's CPU, not the chip")
+                if not form.startswith("narrow"):
+                    return
+                got = jax.device_get(out)
+                if want is None:
+                    want = got
+                elif not all(np.array_equal(a, b) for a, b in zip(got, want)):
+                    raise SystemExit(f"{form} sorts otherwise than narrow2")
+
+            bench(f"merge_sort_{form}_{rows}", f, key, live, okey,
+                  check=check, form=form, **rec)
+        del key, live, okey, want
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", choices=["fold_probe"])
+    ap.add_argument("--only", choices=["fold_probe", "merge_sort"])
+    ap.add_argument("--rows", default="16777216+67108864,65536+67108864",
+                    help="merge_sort: build+probe widths, comma-separated")
     ap.add_argument("--probe-rows", type=int, default=67_108_864)
     ap.add_argument("--builds", default="32768,524288,1048576,2097152")
     ap.add_argument("--words", default="0,1,2")
@@ -163,6 +235,11 @@ def main():
         fold_probe(dev, args.probe_rows,
                    [int(x) for x in args.builds.split(",")],
                    [int(x) for x in args.words.split(",")])
+        print(json.dumps({"name": "done"}), flush=True)
+        return
+    if args.only == "merge_sort":
+        merge_sort(dev, [tuple(int(x) for x in w.split("+"))
+                         for w in args.rows.split(",")])
         print(json.dumps({"name": "done"}), flush=True)
         return
     key = jax.random.PRNGKey(0)
