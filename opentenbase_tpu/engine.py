@@ -8618,6 +8618,9 @@ def _sv_fused(c: Cluster):
     # 32-bit word (their range fits) and as two, once a compiled program
     rows.append(("join_words_narrowed", str(fx.join_words["narrowed"])))
     rows.append(("join_words_wide", str(fx.join_words["wide"])))
+    # build words a Pallas radix probe returned from its contraction
+    # (no gather at the probe's width), once a compiled program
+    rows.append(("radix_words_in_kernel", str(fx.radix_words_in_kernel)))
     # sort-merge lookups that sorted their key as one 32-bit word (both
     # keys' ranges fit) and as int64, once a compiled program
     rows.append(("merge_keys_narrowed", str(fx.merge_keys["narrowed"])))

@@ -1172,6 +1172,11 @@ class FusedExecutor:
         # as two, once a traced program (fused_dag._build_join;
         # pg_stat_fused join_words_narrowed / join_words_wide)
         self.join_words = {"narrowed": 0, "wide": 0}
+        # build words a Pallas radix probe returned out of its own
+        # contraction instead of a gather at the probe's width, once a
+        # traced program (fused_dag._build_join; pg_stat_fused
+        # radix_words_in_kernel)
+        self.radix_words_in_kernel = 0
         # sort-merge lookups by the width of the key they sort: one
         # 32-bit word (both keys' ranges fit) or int64, once a traced
         # program (fused_dag._build_join; pg_stat_fused

@@ -991,7 +991,7 @@ def _radix_gate(
 
 def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
                   pallas_probe: bool = False, note_mode=None,
-                  tag: str = "join", forced: bool = False):
+                  tag: str = "join", forced: bool = False, words=()):
     """Equi-join primitive over the bucket-padded radix hash table
     (ops/join.py): ONE small build-side sort + a log2(bucket)-deep
     bucket search per probe row, instead of sort-merge's full
@@ -1027,11 +1027,17 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
     ``merge`` when ``fallback`` answers — ``sized_out`` where the shape
     rule, not the budget, sent it there.
 
-    Same contract as ``_lookup_sortmerge``: (matched, bidx, flag); the
-    flag is raised by duplicate build keys (in-bucket adjacency or a
-    key matching in two passes), or by bucket overflow — the runner
-    then disables the radix formulation for this join and the
-    sort-merge retry re-derives the exact dup verdict."""
+    ``_lookup_sortmerge``'s contract and the build words the kernel
+    picked: (matched, bidx, flag, picked); the flag is raised by
+    duplicate build keys (in-bucket adjacency or a key matching in two
+    passes), or by bucket overflow — the runner then disables the radix
+    formulation for this join and the sort-merge retry re-derives the
+    exact dup verdict. ``words``: 32-bit build columns the caller
+    reads, each (data [nb] 32-bit, valid [nb] bool or None); where the
+    Pallas kernel probed every pass, ``picked`` holds each at the
+    probe's rows out of its contraction, (int32, valid or None), equal
+    to ``take(data, bidx)`` on a matched row. Otherwise (the XLA probe,
+    ``fallback``, no words) it is None and the caller gathers."""
     from opentenbase_tpu.ops import pallas_join as pj
 
     pd, pv = pk
@@ -1043,6 +1049,7 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
             jnp.zeros(npr, jnp.bool_),
             jnp.zeros(npr, jnp.int32),
             jnp.asarray(False),
+            None,
         )
     plan = batchplan.plan_radix_join(nb, npr, budget)
     chunk = 0 if plan is None else -(-nb // plan.passes)
@@ -1054,7 +1061,7 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
         if note_mode is not None:
             note_mode("merge", sized_out=sized_out)
         with scope(f"{tag}/merge"):
-            return fallback(pk, pmask, bk, bmask, check_dup=True)
+            return (*fallback(pk, pmask, bk, bmask, check_dup=True), None)
     if note_mode is not None:
         note_mode("radix")
     breal = bmask if bv is None else (bmask & bv)
@@ -1063,6 +1070,7 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
     matched = jnp.zeros(npr, jnp.bool_)
     bidx = jnp.zeros(npr, jnp.int32)
     flag = jnp.asarray(False)
+    picked = None  # the kernel's words, merged over the passes
 
     for p in range(plan.passes):
         s = p * chunk
@@ -1073,14 +1081,22 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
             tkeys, tvalid, tbidx, dup, ovf = join_ops.build_radix_table(
                 bd[s:e], breal[s:e], P, B
             )
+        got = ()
         if pallas_probe and pj.eligible(e - s, P, B):
             # compiled by Mosaic with the rest of the program: a
             # lowering failure fails the program (a counted, logged
             # fused->host demotion, engine._try_fused_inner), never a
             # quiet switch to the XLA probe
             with scope(f"{tag}/pallas/probe"):
-                m, bi = pj.probe_radix_pallas(
-                    tkeys, tvalid, tbidx, pd, preal, P, B
+                # this pass's words at the table's slots (its width)
+                slot = [
+                    (jnp.take(d[s:e], tbidx, mode="clip"),
+                     None if v is None
+                     else jnp.take(v[s:e], tbidx, mode="clip"))
+                    for d, v in words
+                ]
+                m, bi, got = pj.probe_radix_pallas(
+                    tkeys, tvalid, tbidx, pd, preal, P, B, words=slot
                 )
             if note_mode is not None:
                 note_mode("pallas")
@@ -1091,9 +1107,18 @@ def _lookup_radix(pk, pmask, bk, bmask, budget, fallback,
                 )
         # a probe key matching in two passes = build dup across chunks
         flag = flag | dup | ovf | jnp.any(m & matched)
-        bidx = jnp.where(m & ~matched, bi + jnp.int32(s), bidx)
+        first = m & ~matched
+        bidx = jnp.where(first, bi + jnp.int32(s), bidx)
+        if got:
+            # (the kernel reads 0 where nothing matched: the first
+            # pass's words are the merge of it into nothing)
+            picked = got if picked is None else tuple(
+                (jnp.where(first, w, aw),
+                 None if v is None else jnp.where(first, v, av))
+                for (w, v), (aw, av) in zip(got, picked)
+            )
         matched = matched | m
-    return matched, bidx, flag
+    return matched, bidx, flag, picked
 
 
 def _agg_specs(comp, agg, dids):
@@ -2313,10 +2338,11 @@ class _Builder:
             note_record()
 
         def note_record() -> None:
-            """``join<i>=<formulations>:<build>x<probe>[ key=i32][
-            bit=..][ narrow=..][ keys=..]``: ``key=i32``, a sort-merge
-            that sorted its key as one word; ``narrow=`` names the
-            64-bit build columns that crossed as one word."""
+            """``join<i>=<formulations>:<build>x<probe>[ words=..][
+            key=i32][ bit=..][ narrow=..][ keys=..]``: ``words=``, the
+            build columns a Pallas probe returned; ``key=i32``, a
+            sort-merge that sorted its key as one word; ``narrow=``
+            names the 64-bit build columns that crossed as one word."""
             names = [
                 bschema[i].name or f"col{i}"
                 for i, one in words.items() if one
@@ -2324,7 +2350,8 @@ class _Builder:
             narrow = f" narrow={'+'.join(names)}" if names else ""
             builder.joins[jtag] = (
                 f"{'+'.join(sorted(traced))}:{rec['widths']}"
-                f"{rec.get('key', '')}{rec['bit']}{narrow}{keys_arg}"
+                f"{rec.get('words', '')}{rec.get('key', '')}{rec['bit']}"
+                f"{narrow}{keys_arg}"
             )
 
         def note_word(i: int) -> None:
@@ -2530,14 +2557,34 @@ class _Builder:
                     pk, pmask, penv, pn = rk, rmask, renv, rn
                     bk, bmask, benv = lk, lmask, lenv
                     bn = ln
+                kw: dict = {}  # position -> reads a kernel word back
+                picked = None
                 if use_radix:
+                    # the build columns read after the lookup that are one
+                    # 32-bit word ride the Pallas probe's own contraction
+                    # (``_as_word``) and are not gathered at the probe's
+                    # width; a deferring program reads each where it is
+                    # read, as before
+                    kwords = []
+                    if pallas_probe and not defer:
+                        for i in sorted(bchecked):
+                            one = _as_word(benv[i], i in nar)
+                            if one is not None:
+                                kw[i] = one[1]
+                                kwords.append((one[0], benv[i][1]))
                     # (a table is keyed on the driving pair alone; the
                     # shape rule's fallback sorts on every pair)
-                    matched, bidx, dup = _lookup_radix(
+                    matched, bidx, dup, picked = _lookup_radix(
                         pk, pmask, bk, bmask, radix_budget, merge,
                         pallas_probe=pallas_probe,
                         note_mode=note_mode, tag=jtag, forced=forced,
+                        words=kwords,
                     )
+                    if picked is not None:
+                        rec["words"] = " words=" + "+".join(
+                            bschema[i].name or f"col{i}" for i in kw
+                        )
+                        builder.fx.radix_words_in_kernel += len(kw)
                 else:
                     with scope(f"{jtag}/merge"):
                         matched, bidx, dup = merge(
@@ -2562,6 +2609,10 @@ class _Builder:
                         gathered = _take_rows(
                             benv, bidx, narrow=nar & reads
                         )
+                    # (the gathers of the words the kernel returned are
+                    # dead from here: the compiler drops them)
+                    for i, (w, v) in zip(kw, picked or ()):
+                        gathered[i] = (kw[i](w), v)
                 env = joined(penv, gathered)
                 mask = pmask & matched
                 n = pn
@@ -6345,6 +6396,28 @@ def _take_word(d, idx, mode, narrow: bool):
     return jnp.take(
         d.astype(jnp.int32), idx, axis=0, mode=mode
     ).astype(jnp.int64)
+
+
+def _as_word(col, narrow: bool):
+    """A build column's data as one 32-bit word the Pallas probe can
+    return (``pallas_join.pack_words``), and the function that reads
+    the word back as the column's dtype; None where it is no column of
+    rows or takes two words (a 64-bit column that is not ``narrow``,
+    ``_take_word``'s rule)."""
+    d, v = col
+    if jnp.ndim(d) != 1 or (v is not None and jnp.ndim(v) != 1):
+        return None
+    dt = np.dtype(d.dtype)
+    if dt == np.int32:
+        return d, lambda w: w
+    if dt == np.int64 and narrow:
+        return d.astype(jnp.int32), lambda w: w.astype(jnp.int64)
+    if dt.itemsize == 4:
+        return (jax.lax.bitcast_convert_type(d, jnp.int32),
+                lambda w: jax.lax.bitcast_convert_type(w, dt))
+    if dt.itemsize < 4 and dt.kind in "biu":
+        return d.astype(jnp.int32), lambda w: w.astype(dt)
+    return None
 
 
 def _widened(env, narrow) -> list:

@@ -20,7 +20,18 @@ trick the engine's grouped aggregation plays (ops/agg.py superblock):
   partition, so the contraction result IS the limb, not a rounded sum).
   A slot matches iff every limb plane matches. Build row indices stay
   below 2^24 (the eligibility gate enforces it), so they ride a single
-  exact f32 plane.
+  exact f32 plane;
+- build words: a 32-bit build column the joined row reads can ride the
+  contractions that gather the bucket (``pack_words``: two exact 16-bit
+  halves of its bit pattern; the first word in the limb group's two pad
+  rows, which are contracted anyway, every other word and a 0/1
+  validity plane where a word is nullable stacked under the index
+  plane), so the probe returns the column at the probe's rows and no
+  XLA gather at the probe's width reads it afterwards
+  (executor/fused_dag._build_join). The kernel's time follows the rows
+  it contracts (the star cell's ``dates`` probe: 481.8 ms a statement
+  for 400 rows, 568.5 with a word's 80 rows under the index plane, on
+  TPU v5e), so the first word costs no row.
 
 The XLA probe (ops/join.probe_radix_first) remains the reference
 semantics; this kernel is the device fast path for small dimension
@@ -103,13 +114,55 @@ def pack_table(tkeys, tvalid, tbidx, partitions: int, bucket: int):
     return limbs, valid, bidx
 
 
+def pack_words(tlimbs, tbidxf, words, partitions: int, bucket: int):
+    """(tlimbs, tbidx) of ``pack_table`` with the build words in: each
+    word's low and high 16-bit halves of its ``uint32`` bit pattern
+    (each half is f32-exact) as planes [B, P], the first word's in the
+    limb group's two pad rows, every other word's and then one 0/1
+    plane per nullable word stacked under the row index, in the order
+    ``words`` gives. ``words``: ((data [P*B+1] 32-bit, valid [P*B+1]
+    bool or None), ..) laid out like ``tbidx``, one value a slot."""
+    P, B = partitions, bucket
+
+    def plane(x):
+        return x[: P * B].astype(jnp.float32).reshape(P, B).T
+
+    halves, valids = [], []
+    for d, v in words:
+        u = jax.lax.bitcast_convert_type(d, jnp.uint32)
+        halves += [plane(u & jnp.uint32(0xFFFF)), plane(u >> jnp.uint32(16))]
+        if v is not None:
+            valids.append(plane(v))
+    if halves:
+        tlimbs = (
+            tlimbs.reshape(B, LIMB_ROWS, P)
+            .at[:, LIMBS:].set(jnp.stack(halves[:2], axis=1))
+            .reshape(B * LIMB_ROWS, P)
+        )
+    under = halves[2:] + valids
+    if under:
+        tbidxf = jnp.concatenate([tbidxf] + under, axis=0)
+    return tlimbs, tbidxf
+
+
 def build_probe(
     partitions: int, bucket: int, block: int = BLOCK,
-    interpret: bool = False,
+    interpret: bool = False, words: int = 0, valids: int = 0,
 ):
-    """fn(tlimbs [B*R, P] f32, tvalid [B, P] f32, tbidx [B, P] f32,
-    part [1, n] i32, plimbs [R, n] f32) -> (matched [n] f32,
-    bidx [n] f32), R = LIMB_ROWS.
+    """fn(tlimbs [B*R, P] f32, tvalid [B, P] f32, tbidx [B*(1+K), P]
+    f32, part [1, n] i32, plimbs [R, n] f32) -> (matched [n] f32,
+    bidx [n] f32, *planes [n] i32), R = LIMB_ROWS,
+    K = 2*(words-1)+valids (``valids`` alone without words).
+
+    ``words`` build words and ``valids`` validity planes ride the
+    contractions that gather the bucket (``pack_words``): the first
+    word's halves in each slot's limb group's two pad rows (the key
+    then compares the group's ``LIMBS`` rows alone), the rest under the
+    row index; the slot that hits selects them as it selects the row
+    index. They come back as ``words`` int32 words (the halves joined
+    into the bit pattern) and then ``valids`` 0/1 planes; on a row that
+    matched nothing each reads 0. With neither the kernel is the plain
+    probe's.
 
     Probe rows ride the LANE axis end to end (every operand and both
     outputs are lane-dense 2-D blocks — Mosaic has no cheap 1-D vectors
@@ -122,9 +175,11 @@ def build_probe(
 
     P, B = partitions, bucket
     R = LIMB_ROWS
+    K = 2 * max(words - 1, 0) + valids  # planes under the row index
     exact = jax.lax.Precision.HIGHEST  # one-hot gathers must not round
 
-    def kernel(tl_ref, tv_ref, ti_ref, part_ref, pl_ref, m_ref, b_ref):
+    def kernel(tl_ref, tv_ref, ti_ref, part_ref, pl_ref, m_ref, b_ref,
+               *w_refs):
         sub = jax.lax.broadcasted_iota(jnp.int32, (P, block), 0)
         onehot = (sub == part_ref[...]).astype(jnp.float32)  # [P, block]
         # ONE MXU contraction per plane gathers the whole bucket for the
@@ -140,14 +195,22 @@ def build_probe(
         bucket_i = jnp.dot(
             ti_ref[...], onehot, precision=exact,
             preferred_element_type=jnp.float32,
-        )  # [B, block]
+        )  # [B*(1+K), block]
         plimbs = pl_ref[...]  # [R, block]
         matched = jnp.zeros((1, block), jnp.float32)
         bidx = jnp.zeros((1, block), jnp.float32)
+        picked = [
+            jnp.zeros((1, block), jnp.float32)
+            for _ in range(2 * words + valids)
+        ]
+        if words:  # the pad rows carry the first word, not the key
+            pad = jax.lax.broadcasted_iota(jnp.int32, (R, block), 0) >= LIMBS
         for b in range(B):
-            eq = (bucket_l[b * R:(b + 1) * R, :] == plimbs).astype(
-                jnp.float32
-            )
+            group = bucket_l[b * R:(b + 1) * R, :]
+            eq = group == plimbs
+            if words:
+                eq = eq | pad
+            eq = eq.astype(jnp.float32)
             hit = jnp.min(eq, axis=0, keepdims=True) * (
                 bucket_v[b:b + 1, :] > 0.5
             ).astype(jnp.float32)
@@ -156,8 +219,23 @@ def build_probe(
             # the flagged-and-discarded duplicate run
             matched = jnp.maximum(matched, hit)
             bidx = jnp.maximum(bidx, hit * bucket_i[b:b + 1, :])
+            rows = [
+                group[LIMBS:LIMBS + 1, :], group[LIMBS + 1:LIMBS + 2, :]
+            ] if words else []
+            rows += [
+                bucket_i[B * (1 + k) + b:B * (1 + k) + b + 1, :]
+                for k in range(K)
+            ]
+            for k, row in enumerate(rows):
+                picked[k] = jnp.maximum(picked[k], hit * row)
         m_ref[...] = matched
         b_ref[...] = bidx
+        for j in range(words):
+            lo = picked[2 * j].astype(jnp.int32)
+            hi = picked[2 * j + 1].astype(jnp.int32)
+            w_refs[j][...] = (hi << 16) | lo
+        for j in range(valids):
+            w_refs[words + j][...] = picked[2 * words + j].astype(jnp.int32)
 
     def run(tlimbs, tvalid, tbidx, part, plimbs):
         n = part.shape[1]
@@ -174,42 +252,50 @@ def build_probe(
         # the engine runs in global x64 mode; this kernel is pure
         # f32/i32 (see ops/pallas_scan.py for the Mosaic i64-scalar
         # rationale)
+        planes = words + valids
         with jax.enable_x64(False), scope("kernel"):
-            matched, bidx = pl.pallas_call(
+            matched, bidx, *got = pl.pallas_call(
                 kernel,
                 grid=(grid,),
                 in_specs=[
                     pl.BlockSpec((B * R, P), lambda i: (0, 0)),
                     pl.BlockSpec((B, P), lambda i: (0, 0)),
-                    pl.BlockSpec((B, P), lambda i: (0, 0)),
+                    pl.BlockSpec((B * (1 + K), P), lambda i: (0, 0)),
                     pl.BlockSpec((1, block), lambda i: (0, i)),
                     pl.BlockSpec((R, block), lambda i: (0, i)),
                 ],
                 out_specs=[
                     pl.BlockSpec((1, block), lambda i: (0, i)),
                     pl.BlockSpec((1, block), lambda i: (0, i)),
-                ],
+                ] + [
+                    pl.BlockSpec((1, block), lambda i: (0, i))
+                ] * planes,
                 out_shape=[
                     jax.ShapeDtypeStruct((1, padded), jnp.float32, vma=vma),
                     jax.ShapeDtypeStruct((1, padded), jnp.float32, vma=vma),
-                ],
+                ] + [
+                    jax.ShapeDtypeStruct((1, padded), jnp.int32, vma=vma)
+                ] * planes,
                 compiler_params=pltpu.CompilerParams(
                     vmem_limit_bytes=VMEM_LIMIT_BYTES
                 ),
                 interpret=interpret,
             )(tlimbs, tvalid, tbidx, part, plimbs)
-        return matched[0, :n], bidx[0, :n]
+        return (matched[0, :n], bidx[0, :n], *(g[0, :n] for g in got))
 
     return run
 
 
 def probe_radix_pallas(
     tkeys, tvalid, tbidx, probe_key, probe_real, partitions: int,
-    bucket: int, interpret: bool = False,
+    bucket: int, interpret: bool = False, words=(),
 ):
-    """Drop-in for ops/join.probe_radix_first over the same radix table,
-    probing through the Pallas kernel. Same contract:
-    (matched [np] bool, bidx [np] int32)."""
+    """ops/join.probe_radix_first over the same radix table, probing
+    through the Pallas kernel: (matched [np] bool, bidx [np] int32,
+    picked). ``words``: build words laid out like ``tbidx``, each
+    (data [P*B+1] 32-bit, valid [P*B+1] bool or None); ``picked`` holds
+    each at the probe's rows as (int32 [np], bool [np] or None), bit
+    for bit ``take(data, bidx)`` on a matched row (0 on the others)."""
     from opentenbase_tpu.ops.join import radix_parts
 
     key64 = probe_key.astype(jnp.int64)
@@ -219,7 +305,18 @@ def probe_radix_pallas(
     tlimbs, tvalidf, tbidxf = pack_table(
         tkeys, tvalid, tbidx, partitions, bucket
     )
-    matched, bidx = build_probe(
-        partitions, bucket, interpret=interpret
+    if words:
+        tlimbs, tbidxf = pack_words(
+            tlimbs, tbidxf, words, partitions, bucket
+        )
+    nw = len(words)
+    nv = sum(v is not None for _d, v in words)
+    matched, bidx, *got = build_probe(
+        partitions, bucket, interpret=interpret, words=nw, valids=nv,
     )(tlimbs, tvalidf, tbidxf, part, split_limbs(key64))
-    return matched > 0.5, bidx.astype(jnp.int32)
+    valid = iter(got[nw:])
+    picked = tuple(
+        (w, None if v is None else next(valid) > 0)
+        for w, (_d, v) in zip(got[:nw], words)
+    )
+    return matched > 0.5, bidx.astype(jnp.int32), picked

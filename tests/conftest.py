@@ -67,3 +67,33 @@ def _no_orphaned_dn_processes():
     assert not leaked, (
         f"orphaned opentenbase_tpu.dn.server processes leaked: {leaked}"
     )
+
+
+@pytest.fixture
+def pallas_probe(monkeypatch):
+    """The DAG's radix joins probed by the Pallas kernel, as on a TPU
+    mesh, for the programs traced while the test runs: the join
+    builder's platform check says ``tpu`` (its sort-merge stays the
+    CPU's ``_lookup``, chosen before), the kernel runs in interpret
+    mode, and the programs' ``shard_map`` leaves out its check of
+    varying mesh axes, which the interpreter's evaluation of the kernel
+    body cannot pass (the kernel's ``iota`` varies over none)."""
+    from functools import partial
+
+    import jax
+
+    from opentenbase_tpu.executor import fused_dag
+    from opentenbase_tpu.ops import pallas_join
+
+    init = fused_dag._Builder.__init__
+
+    def on_tpu(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        self.platform = "tpu"
+
+    monkeypatch.setattr(fused_dag._Builder, "__init__", on_tpu)
+    monkeypatch.setattr(
+        pallas_join, "probe_radix_pallas",
+        partial(pallas_join.probe_radix_pallas, interpret=True))
+    monkeypatch.setattr(
+        fused_dag, "shard_map", partial(jax.shard_map, check_vma=False))

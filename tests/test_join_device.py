@@ -12,7 +12,8 @@ JAX_PLATFORMS=cpu:
   fused DAG under the join_mode GUC — every path must agree with every
   other, and EXPLAIN must say which formulation answered;
 - the Pallas MXU bucket-probe kernel (ops/pallas_join.py) in
-  interpreter mode against the XLA probe;
+  interpreter mode against the XLA probe, and the build words it
+  returns out of its contraction against a gather;
 - the spill-aware batch planner's sizing and multi-pass splitting
   (plan/batchplan.py + fused_dag._lookup_radix);
 - the shape rule of ``join_mode = auto``: a radix table only where it is
@@ -196,14 +197,230 @@ def test_pallas_probe_matches_xla_probe():
         tk, tv, ti, jnp.asarray(pk), jnp.asarray(preal),
         plan.partitions, plan.bucket,
     )
-    m_p, b_p = pj.probe_radix_pallas(
+    m_p, b_p, picked = pj.probe_radix_pallas(
         tk, tv, ti, jnp.asarray(pk), jnp.asarray(preal),
         plan.partitions, plan.bucket, interpret=True,
     )
+    assert picked == ()  # asked for no build words
     m_x = np.asarray(m_x)
     assert m_x.any(), "probe must actually hit"
     assert np.array_equal(m_x, np.asarray(m_p))
     assert np.array_equal(np.asarray(b_x)[m_x], np.asarray(b_p)[m_x])
+
+
+def _word_case(nb: int, npr: int, hit: float, seed: int):
+    """Sparse int64 build keys (a tenth of the build dead), probe keys
+    that hit a share ``hit`` of the time and miss otherwise, a tenth of
+    the probe dead; where ``hit``, probe row ``i < 8`` reads build row
+    ``i`` (where a column keeps its special values)."""
+    rng = np.random.default_rng(seed)
+    bk = (rng.permutation(np.arange(8 * nb))[:nb] * 13 - 10**11).astype(
+        np.int64)
+    breal = rng.random(nb) > 0.1
+    pk = np.where(
+        rng.random(npr) < hit, bk[rng.integers(0, nb, npr)], 10**12,
+    ).astype(np.int64)
+    preal = rng.random(npr) > 0.1
+    if hit:
+        breal[:8] = preal[:8] = True
+        pk[:8] = bk[:8]
+    return rng, bk, breal, pk, preal
+
+
+def _word_column(kind: str, rng, nb: int):
+    """(32-bit data, validity or None) of a build column of ``kind``."""
+    from opentenbase_tpu.storage.column import Dictionary
+
+    if kind == "int32_ends":
+        d = rng.integers(-(2**31), 2**31, nb, dtype=np.int64).astype(
+            np.int32)
+        d[:5] = [2**31 - 1, -(2**31 - 1), -(2**31), 0, -1]
+        return d, None
+    if kind == "dictionary_code":
+        words = Dictionary().encode(
+            [f"brand#{i % 997}" for i in rng.permutation(nb)])
+        return np.asarray(words, np.int32), None
+    if kind == "float32_bits":
+        f = rng.standard_normal(nb).astype(np.float32) * 1e30
+        f[:6] = [np.nan, -0.0, 0.0, np.inf, -np.inf, 1e-45]
+        bits = f.view(np.int32).copy()
+        bits[6] = np.int32(-4194305)  # a NaN with a payload, sign set
+        return bits, None
+    if kind == "nullable":
+        d = rng.integers(-(2**31), 2**31, nb, dtype=np.int64).astype(
+            np.int32)
+        valid = rng.random(nb) > 0.3
+        valid[:2] = [False, True]
+        return d, valid
+    raise AssertionError(kind)
+
+
+WORD_KINDS = ("int32_ends", "dictionary_code", "float32_bits", "nullable")
+
+
+@pytest.mark.parametrize("kind", WORD_KINDS)
+def test_pallas_probe_returns_build_words_bit_for_bit(kind):
+    """The words the kernel returns out of its own contraction are
+    ``take(column, bidx)`` bit for bit on every matched row: the ends of
+    ``int32``, a dictionary code, a ``float32``'s bits (NaN, ``-0.0``,
+    infinities, a denormal), a nullable column's validity."""
+    from opentenbase_tpu.ops import pallas_join as pj
+
+    nb, npr = 900, 3000
+    rng, bk, breal, pk, preal = _word_case(nb, npr, 0.7, 44)
+    d, v = _word_column(kind, rng, nb)
+    plan = batchplan.plan_radix_join(
+        nb, npr, batchplan.DEFAULT_EXCHANGE_BUDGET
+    )
+    tk, tv, ti, dup, ovf = join_ops.build_radix_table(
+        jnp.asarray(bk), jnp.asarray(breal), plan.partitions, plan.bucket
+    )
+    assert not bool(dup) and not bool(ovf)
+    slot = (jnp.take(jnp.asarray(d), ti, mode="clip"),
+            None if v is None else jnp.take(jnp.asarray(v), ti, mode="clip"))
+    m, b, ((w, wv),) = pj.probe_radix_pallas(
+        tk, tv, ti, jnp.asarray(pk), jnp.asarray(preal),
+        plan.partitions, plan.bucket, interpret=True, words=[slot],
+    )
+    m_x, b_x = join_ops.probe_radix_first(
+        tk, tv, ti, jnp.asarray(pk), jnp.asarray(preal),
+        plan.partitions, plan.bucket,
+    )
+    m, b, w = np.asarray(m), np.asarray(b), np.asarray(w)
+    assert m.any() and np.array_equal(m, np.asarray(m_x))
+    assert np.array_equal(b[m], np.asarray(b_x)[m])
+    assert w.dtype == np.int32
+    assert np.array_equal(w[m], d[b[m]])  # bit for bit
+    # (the special values are read: probe row i < 8 hits build row i)
+    assert m[:8].all() and np.array_equal(b[:8], np.arange(8))
+    if v is None:
+        assert wv is None
+    else:
+        wv = np.asarray(wv)
+        assert np.array_equal(wv[m], v[b[m]])
+        assert wv[m].any() and not wv[m].all()
+
+
+def test_pallas_probe_words_where_nothing_matches():
+    """No probe key in the build: nothing matches, and the words come
+    back at the probe's width all the same."""
+    from opentenbase_tpu.ops import pallas_join as pj
+
+    nb, npr = 300, 1000
+    rng, bk, breal, pk, preal = _word_case(nb, npr, 0.0, 45)
+    d, v = _word_column("nullable", rng, nb)
+    plan = batchplan.plan_radix_join(
+        nb, npr, batchplan.DEFAULT_EXCHANGE_BUDGET
+    )
+    tk, tv, ti, _dup, _ovf = join_ops.build_radix_table(
+        jnp.asarray(bk), jnp.asarray(breal), plan.partitions, plan.bucket
+    )
+    words = [(jnp.take(jnp.asarray(c), ti, mode="clip"),
+              jnp.take(jnp.asarray(v), ti, mode="clip")) for c in (d, d)]
+    m, _b, picked = pj.probe_radix_pallas(
+        tk, tv, ti, jnp.asarray(pk), jnp.asarray(preal),
+        plan.partitions, plan.bucket, interpret=True, words=words,
+    )
+    assert not np.asarray(m).any()
+    assert [(w.shape, w.dtype, wv.shape) for w, wv in picked] == [
+        ((npr,), jnp.int32, (npr,))] * 2
+
+
+def test_multipass_radix_lookup_returns_the_words_of_every_pass(
+        monkeypatch):
+    """``join_mode = radix`` over a budget that splits the build into
+    several tables: each pass's kernel returns its words and the merge
+    keeps the pass that matched, as it keeps ``bidx``. A pass the XLA
+    probe answers returns none (the caller gathers)."""
+    from functools import partial
+
+    from opentenbase_tpu.executor.fused_dag import _lookup, _lookup_radix
+    from opentenbase_tpu.ops import pallas_join as pj
+
+    nb, npr = 4000, 6000
+    rng, bk, breal, pk, preal = _word_case(nb, npr, 0.8, 46)
+    cols = [_word_column(k, rng, nb) for k in ("int32_ends", "nullable")]
+    plan, budget = None, 37_500
+    while plan is None:
+        budget *= 2
+        plan = batchplan.plan_radix_join(nb, npr, budget)
+    assert plan.passes > 1, plan
+    monkeypatch.setattr(
+        pj, "probe_radix_pallas",
+        partial(pj.probe_radix_pallas, interpret=True),
+    )
+    pkp = (jnp.asarray(pk), jnp.asarray(preal))
+    bkp = (jnp.asarray(bk), None)
+    bmask = jnp.asarray(breal)
+    pmask = jnp.ones(npr, jnp.bool_)
+    words = [(jnp.asarray(d), None if v is None else jnp.asarray(v))
+             for d, v in cols]
+    m, b, flag, picked = _lookup_radix(
+        pkp, pmask, bkp, bmask, budget, _lookup, pallas_probe=True,
+        forced=True, words=words,
+    )
+    want = _lookup(pkp, pmask, bkp, bmask, check_dup=True)
+    m, b = np.asarray(m), np.asarray(b)
+    assert not bool(flag) and m.any()
+    assert np.array_equal(m, np.asarray(want[0]))
+    assert np.array_equal(b[m], np.asarray(want[1])[m])
+    # matches in every pass, each pass's words read back
+    assert len({int(i) // -(-nb // plan.passes) for i in b[m]}) == (
+        plan.passes)
+    for (d, v), (w, wv) in zip(cols, picked):
+        assert np.array_equal(np.asarray(w)[m], d[b[m]])
+        assert (wv is None) == (v is None)
+        if v is not None:
+            assert np.array_equal(np.asarray(wv)[m], v[b[m]])
+    # the XLA probe returns no words
+    assert _lookup_radix(
+        pkp, pmask, bkp, bmask, budget, _lookup, forced=True, words=words,
+    )[3] is None
+
+
+# sha256 of the Pallas probe's kernel (its jaxpr and its grid), at
+# P=16, B=8 over 300 probe rows and at the star cell's P=256, B=40, as
+# the kernel was before it could return build words: called with none
+# it is that kernel, op for op
+KERNEL_DIGESTS = {
+    (16, 8, 300): (
+        "e682ae60d02f3ca1e43819cb0f5a71e6216f6e567fa1d4a2e7a1de64d5f221d3",
+        "97b0f3ce41cd9be1331350ca4e73bc0e1a58494b0a82c3285cc84cbb2dfbce20",
+    ),
+    (256, 40, 1000): (
+        "1ebccfcf91c84c9116b9586d1fe529470d58a91b09069ea7f12280805d0d2016",
+        "6c64e9cc0a8ddbb65380b4faddfc06142c82a5b303b61e0a6ea40ef9c9fbe592",
+    ),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(KERNEL_DIGESTS))
+def test_words_free_probe_keeps_its_kernel_text(shape):
+    import hashlib
+
+    import jax
+
+    from opentenbase_tpu.ops import pallas_join as pj
+
+    P, B, n = shape
+
+    def probe(tk, tv, ti, pk, pr):
+        return pj.probe_radix_pallas(tk, tv, ti, pk, pr, P, B,
+                                     interpret=True)[:2]
+
+    def arg(k, dt):
+        return jax.ShapeDtypeStruct((k,), dt)
+
+    jaxpr = jax.make_jaxpr(probe)(
+        arg(P * B + 1, jnp.int64), arg(P * B + 1, jnp.bool_),
+        arg(P * B + 1, jnp.int32), arg(n, jnp.int64), arg(n, jnp.bool_),
+    )
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert tuple(
+        hashlib.sha256(str(call.params[k]).encode()).hexdigest()
+        for k in ("jaxpr", "grid_mapping")
+    ) == KERNEL_DIGESTS[shape]
 
 
 # ---------------------------------------------------------------------------

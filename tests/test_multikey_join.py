@@ -461,6 +461,7 @@ class Cell:
         self.dep.sql(self.texts[kind])
         monkeypatch.setattr(fused.Launcher, "__call__", real)
         self.launched = [prog for prog, _built in seen]
+        self.built = seen[-1][1] if seen else None
         return [
             prog.__name__ + ":" + hashlib.sha256(
                 prog.lower(*built).as_text(debug_info=False).encode()
@@ -508,6 +509,57 @@ def test_cells_programs_lower_to_the_text_they_had(cells, kind, monkeypatch):
             j: rec.split(" bit=")[1] for j, rec in prog.joins.items()
             if rec.startswith("fold:")
         } == FOLD_BITS[kind]
+
+
+@pytest.fixture(scope="module")
+def star_at_sf10():
+    """The star cell's deployment at a toy scale with SF10's
+    statistics: ``dates`` is ``join0``, as on the chip, and under
+    ``join_mode = radix`` a radix table, as the chip's estimates make it
+    (the toy's stores hold too few ``lineorder`` rows for ``auto`` to
+    gate one). Its programs are its own (the tests above share
+    ``cells``)."""
+    cell = Cell("ssb_star_sf10_1chip")
+    cell.set_stats(10)
+    cell.dep.sql("set join_mode = radix")
+    yield cell
+    cell.dep.close()
+
+
+@pytest.mark.parametrize("kind", ["q21", "q31", "q41"])
+def test_star_reads_d_year_out_of_the_pallas_probe(
+        star_at_sf10, kind, pallas_probe, monkeypatch):
+    """A star statement with its ``dates`` join probed by the Pallas
+    kernel (interpreted here; the chip's path): the kernel returns
+    ``d_year``, the one build column read after the lookup, out of its
+    own contraction, so no gather under ``join0/radix/gather`` is left
+    in the compiled program, and the rows are the host executor's."""
+    import re
+
+    cell = star_at_sf10
+
+    def words_in_kernel() -> int:
+        return int(dict(cell.dep.sql(
+            "select event, detail from pg_stat_fused").rows).get(
+                "radix_words_in_kernel", 0))
+
+    before = words_in_kernel()
+    cell.program_digests(kind, monkeypatch)  # (runs it, keeps the launch)
+    (prog,) = cell.launched
+    assert prog.joins["join0"].startswith("pallas+radix:")
+    assert prog.joins["join0"].endswith(" words=d_year")
+    assert words_in_kernel() == before + 1
+    cell.dep.sql("set enable_fused_execution = off")
+    try:
+        host = cell.dep.sql(cell.texts[kind]).rows
+    finally:
+        cell.dep.sql("set enable_fused_execution = on")
+    assert host and cell.dep.sql(cell.texts[kind]).rows == host
+    built = cell.built
+    text = prog.lower(*built).compile().as_text()
+    assert "otb/join0/pallas/probe/" in text
+    assert not re.search(
+        r"gather\(.*?op_name=\"[^\"]*?otb/join0/radix/gather/", text)
 
 
 def test_one_chip_q3_keeps_the_program_it_times(monkeypatch):

@@ -25,6 +25,8 @@ ROOT = os.path.dirname(HERE)
 if os.path.join(ROOT, "benchmarks") not in sys.path:
     sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
+from join_crossings import crossings  # noqa: E402
+
 FACT_ROWS = 24_000
 ROWS_PER_SF = 6_000_000
 SEEDS = (2_147_483_777, 39, 3_000_000_019)
@@ -289,6 +291,46 @@ def test_one_gagg_sorts_the_customer_key_alone(q10, reductions, launched):
     assert len(crossing) == 1 and crossing[0].startswith("join2/"), gathers
     assert sum(st == "final/gagg/recover" and int(w) == 20
                for w, st in gathers) >= 6, gathers
+    # and at EVERY join's probe width: the nation fold crosses the
+    # customer rows with its carrier, the customer fold the orders' rows
+    # with ``c_custkey``'s word and its carrier alone, the top join the
+    # lineitem rows with ``c_custkey``; no other dropped key (three
+    # customer columns crossed the orders' rows once, 119.6 ms a
+    # statement each on a TPU v5e)
+    assert crossings(text, warm.args["joins"]) == CROSSINGS
+
+
+# join -> the stages of the gathers at its own probe width that move
+# build columns, in the warm Q10 program at the toy's widths
+CROSSINGS = {"join0": ["fold/probe"],
+             "join1": ["fold/gather", "fold/probe"],
+             "join2": ["radix/gather"]}
+
+
+def test_a_pallas_probe_under_a_deferring_gagg_returns_no_words(
+        pallas_probe, launched):
+    """The top join probed by the Pallas kernel, as on a chip where its
+    radix table is dimension-sized: the ``gagg`` still defers what it
+    dropped, the kernel returns no build word, and every join's probe
+    width is crossed by what it was crossed by before."""
+    q = Q10()
+    try:
+        sql = q.text(DATES[3])
+        words0 = q.stat("radix_words_in_kernel")
+        q.dep.sql(sql)
+        res, (warm,) = q.traced_launches(sql)
+        assert warm.args["program"] == "program_dag_gagg"
+        assert warm.args["grouping"] == GROUPING
+        joins = warm.args["joins"]
+        assert "join2=pallas+radix:" in joins and " words=" not in joins
+        assert q.stat("radix_words_in_kernel") == words0
+        prog, args = launched[-1]
+        text = prog.lower(*args).compile().as_text()
+        assert crossings(text, joins) == CROSSINGS
+        got = q.compare.compare_statement(res.rows, q.reference(DATES[3]))
+        assert got["wrong"] is None and got["sum_gap"] <= 1e-12, got
+    finally:
+        q.close()
 
 
 @pytest.mark.parametrize("sf", [None, 10])

@@ -31,6 +31,7 @@ ROOT = os.path.dirname(HERE)
 if os.path.join(ROOT, "benchmarks") not in sys.path:
     sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
+from join_crossings import crossings  # noqa: E402
 from test_tpch_q10 import _mesh_of  # noqa: E402
 
 FACT_ROWS = 24_000
@@ -299,6 +300,40 @@ def test_one_semiagg_then_one_gagg(q18, launched, monkeypatch):
         r"= \w+\[(\d+)[^ ]* gather\(.*?op_name=\"[^\"]*?otb/final/gagg/",
         text)]
     assert widths and max(widths) <= prefix, widths
+    # and no join gathers a dropped key at its probe width: the customer
+    # fold crosses the orders' rows with its carrier (``c_custkey``)
+    # alone, ``c_name`` waits for the output rows; the top join crosses
+    # the lineitem rows with ``o_orderkey``
+    assert crossings(text, final.args["joins"]) == CROSSINGS
+
+
+# join -> the stages of the gathers at its own probe width that move
+# build columns, in the warm final at the toy's widths
+CROSSINGS = {"join0": ["fold/probe"], "join1": ["radix/gather"]}
+
+
+def test_a_pallas_probe_under_a_deferring_gagg_returns_no_words(
+        pallas_probe, launched):
+    """The orders join probed by the Pallas kernel, as on a chip where
+    its radix table is dimension-sized: the ``gagg`` still defers
+    ``c_name``, the kernel returns no build word, and every join's probe
+    width is crossed by what it was crossed by before."""
+    q = Q18()
+    try:
+        sql = q.text(QUANTITIES[1])
+        words0 = q.stat("radix_words_in_kernel")
+        q.dep.sql(sql)
+        res, (_semi, final), _c = q.traced(sql)
+        assert final.args["program"] == "program_dag_gagg"
+        joins = final.args["joins"]
+        assert "join1=pallas+radix:" in joins and " words=" not in joins
+        assert q.stat("radix_words_in_kernel") == words0
+        prog, args = launched[-1]
+        text = prog.lower(*args).compile().as_text()
+        assert crossings(text, joins) == CROSSINGS
+        q.agrees(res.rows, QUANTITIES[1])
+    finally:
+        q.close()
 
 
 def test_live_rows_past_the_prefix_retry_once_with_the_keys_carried(

@@ -35,12 +35,23 @@ position)`` on two keys and on one key with a stable sort, beside the
 restore sort's ``(int32, int32)`` on one key. Build rows are the first
 ``build`` positions, a tenth of each side dead. Same inputs for every
 form, committed to the chip; the two narrow forms must sort alike.
+
+``--only radix_words`` prices the Pallas radix probe at the star
+cell's ``join0`` (SSB's 2,556-row ``dates`` padded to 4,096 rows, keyed
+yyyymmdd; ``--probe-rows`` probe rows a date each): the probe alone,
+the probe and an XLA gather of ``d_year`` at the probe's width (the
+form before the kernel returned build words), and the kernel returning
+one build word (in the limb group's pad rows), two (the second under
+the row index) and one nullable (its validity under the row index).
+The words must equal the gather on every matched row.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import os
+import sys
 import time
 from functools import partial
 
@@ -219,9 +230,76 @@ def merge_sort(dev, widths) -> None:
         del key, live, okey, want
 
 
+def radix_words(dev, probe_rows: int) -> None:
+    """The Pallas probe with and without build words, against the XLA
+    gather it replaces."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))  # (the checkout's engine)
+    from opentenbase_tpu.ops import join as join_ops
+    from opentenbase_tpu.ops import pallas_join as pj
+    from opentenbase_tpu.plan import batchplan
+
+    jax.config.update("jax_enable_x64", True)  # the radix keys are int64
+    nb, days = 4_096, 2_556
+    day = np.datetime64("1992-01-01") + np.arange(days)
+    ymd = day.astype("datetime64[D]").astype(str)
+    key = np.zeros(nb, np.int64)
+    key[:days] = [int(t.replace("-", "")) for t in ymd]
+    year = np.zeros(nb, np.int32)
+    year[:days] = key[:days] // 10_000
+    month = (key // 100).astype(np.int32)
+    live = np.arange(nb) < days
+    plan = batchplan.plan_radix_join(
+        nb, probe_rows, batchplan.DEFAULT_EXCHANGE_BUDGET)
+    P, B = plan.partitions, plan.bucket
+    rng = np.random.default_rng(44)
+    pkey = key[rng.integers(0, days, probe_rows)]
+    preal = rng.random(probe_rows) > 0.01
+    args = jax.device_put(tuple(jnp.asarray(a) for a in (
+        key, live, year, month, live & (year != 1994), pkey, preal)), dev)
+
+    def form(words: int, nullable: bool, gather: bool):
+        def fn(bk, bl, y, m, yv, pk, pr):
+            tk, tv, ti, _dup, _ovf = join_ops.build_radix_table(bk, bl, P, B)
+            cols = [(y, yv if nullable else None), (m, None)][:words]
+            slot = [(jnp.take(d, ti, mode="clip"),
+                     None if v is None else jnp.take(v, ti, mode="clip"))
+                    for d, v in cols]
+            hit, bidx, picked = pj.probe_radix_pallas(
+                tk, tv, ti, pk, pr, P, B, words=slot)
+            out = [hit] + [w for w, _v in picked]
+            if gather:
+                out.append(jnp.take(y, bidx, mode="clip"))
+            return out
+        return jax.jit(fn)
+
+    want = {}
+
+    def check(out, name):
+        if {d.platform for d in out[0].devices()} != {dev.platform}:
+            raise SystemExit("timed on the host's CPU, not the chip")
+        got = jax.device_get(out)
+        hit = got[0]
+        if name == "probe_gather":
+            want["year"] = got[1][hit]
+        elif len(got) > 1 and not np.array_equal(got[1][hit], want["year"]):
+            raise SystemExit(f"{name}: the kernel's word is not the gather's")
+
+    rec = {"case": "radix_words", "build": nb, "probe": probe_rows,
+           "partitions": P, "bucket": B}
+    for name, words, nullable, gather in (
+        ("probe", 0, False, False), ("probe_gather", 0, False, True),
+        ("kernel_1", 1, False, False), ("kernel_2", 2, False, False),
+        ("kernel_1_nullable", 1, True, False),
+    ):
+        bench(f"radix_words_{name}", form(words, nullable, gather), *args,
+              check=partial(check, name=name), form=name, **rec)
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", choices=["fold_probe", "merge_sort"])
+    ap.add_argument("--only", choices=["fold_probe", "merge_sort",
+                                       "radix_words"])
     ap.add_argument("--rows", default="16777216+67108864,65536+67108864",
                     help="merge_sort: build+probe widths, comma-separated")
     ap.add_argument("--probe-rows", type=int, default=67_108_864)
@@ -235,6 +313,10 @@ def main():
         fold_probe(dev, args.probe_rows,
                    [int(x) for x in args.builds.split(",")],
                    [int(x) for x in args.words.split(",")])
+        print(json.dumps({"name": "done"}), flush=True)
+        return
+    if args.only == "radix_words":
+        radix_words(dev, args.probe_rows)
         print(json.dumps({"name": "done"}), flush=True)
         return
     if args.only == "merge_sort":
